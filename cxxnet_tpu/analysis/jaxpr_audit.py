@@ -195,16 +195,7 @@ def _audit_executable(target: str, jitfn, args: Tuple,
             f"{n_alias} aliased params on a non-donating executable"
             if n_alias else ""))
 
-    consts: List = []
-    try:
-        consts = list(jitfn.trace(*args).jaxpr.consts)
-    except AttributeError:
-        # .trace needs jax >= 0.4.27; fall back to "unverifiable"
-        checks.append(_check(
-            target, "no-captured-consts", False,
-            "jit .trace() unavailable on this jax - cannot audit "
-            "captured constants"))
-        return checks
+    consts = list(jitfn.trace(*args).jaxpr.consts)
     big = [c for c in consts
            if getattr(c, "nbytes", 0) > _CONST_BYTES_MAX]
     checks.append(_check(

@@ -101,9 +101,18 @@ def net_update_iter(hid: int, iter_hid: int) -> None:
 
 def net_update_batch(hid: int, daddr: int, b: int, c: int, h: int, w: int,
                      laddr: int, lwidth: int) -> None:
+    net = _get(hid)
     data = _as_f32(daddr, b, c, h, w)
-    label = _as_f32(laddr, b, lwidth)
-    _get(hid).update(data, label)
+    # The caller owns its buffers again the moment this returns, but
+    # the step is dispatched asynchronously and device_put may alias
+    # host memory (the CPU backend does) - a caller that refills one
+    # buffer per step (native/test_driver.c) would otherwise train on
+    # half-overwritten batches. The labels and a host-cast (bf16) input
+    # are fresh buffers by the time they are staged; an input staged as
+    # f32 is the caller's memory itself, and only that case pays a copy.
+    if net._net._host_input(data[:0]).dtype == data.dtype:
+        data = data.copy()
+    net.update(data, _as_f32(laddr, b, lwidth))
 
 
 def net_evaluate(hid: int, iter_hid: int, name: str) -> str:

@@ -6,10 +6,14 @@ The native library implements the reference's two-stage decode pipeline
 records are handed back strictly in stream order. Python keeps the .lst
 parsing, label join, shuffle, augmentation, and batching.
 
-The library is searched at cxxnet_tpu/lib/libcxxnet_io.so (built by
-`make -C native`) or $CXXNET_TPU_NATIVE; when g++ is available and the
-library is missing it is built on demand. `native_available()` gates all
-use; every consumer falls back to the pure-Python decoder.
+The library lives at cxxnet_tpu/lib/libcxxnet_io.so and is built from
+native/cxxnet_io.cc of THIS checkout: the first use runs `make -C
+native` for it, so make decides staleness - a copy on disk that make
+would rebuild (older than its source) is rebuilt, never loaded as is.
+$CXXNET_TPU_NATIVE names a prebuilt library instead and is loaded
+untouched. `native_available()` gates all use; when the build or the
+load fails every consumer takes the pure-Python (PIL) decoder, and
+the failure is reported once on stderr first.
 """
 
 from __future__ import annotations
@@ -22,10 +26,16 @@ from typing import List, Optional
 
 import numpy as np
 
+from cxxnet_tpu import telemetry
+
 _LIB_NAME = "libcxxnet_io.so"
 _lib = None
 _lib_lock = threading.Lock()
-_build_attempted = False
+# one build + load attempt per process (both written under _lib_lock)
+_load_attempted = False
+
+_PKG = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_NATIVE_DIR = os.path.join(os.path.dirname(_PKG), "native")
 
 
 class CxioRecord(ctypes.Structure):
@@ -35,43 +45,48 @@ class CxioRecord(ctypes.Structure):
                 ("c", ctypes.c_int)]
 
 
-def _lib_path() -> str:
-    env = os.environ.get("CXXNET_TPU_NATIVE")
-    if env:
-        return env
-    return os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "lib", _LIB_NAME)
-
-
-def _try_build(path: str) -> bool:
-    """Build the library from native/ if the source tree is present."""
-    global _build_attempted
-    if _build_attempted:
-        return os.path.exists(path)
-    _build_attempted = True
-    native_dir = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))), "native")
-    if not os.path.exists(os.path.join(native_dir, "Makefile")):
-        return False
+def _build(path: str) -> str:
+    """Bring `path` up to date with native/cxxnet_io.cc through make
+    ("" on success, else what went wrong). Only the io target: the
+    C-ABI wrapper library needs python3-dev and is not ours to
+    require here. A checkout without native/ (an installed package)
+    has nothing to build from and keeps the copy it shipped with."""
+    if not os.path.exists(os.path.join(_NATIVE_DIR, "Makefile")):
+        return "" if os.path.exists(path) else "library not built"
+    target = os.path.relpath(path, _NATIVE_DIR)
     try:
-        subprocess.run(["make", "-C", native_dir], check=True,
-                       capture_output=True, timeout=120)
-    except (OSError, subprocess.SubprocessError):
-        return False
-    return os.path.exists(path)
+        subprocess.run(["make", "-C", _NATIVE_DIR, target], check=True,
+                       capture_output=True, text=True, timeout=120)
+    except subprocess.CalledProcessError as e:
+        return f"make failed: {(e.stderr or e.stdout).strip()[-300:]}"
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"make could not run: {e}"
+    return ""
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib
+    global _lib, _load_attempted
     with _lib_lock:
-        if _lib is not None:
+        if _load_attempted:
             return _lib
-        path = _lib_path()
-        if not os.path.exists(path) and not _try_build(path):
-            return None
-        try:
-            lib = ctypes.CDLL(path)
-        except OSError:
+        _load_attempted = True
+        path = os.environ.get("CXXNET_TPU_NATIVE", "")
+        err = ""
+        if not path:
+            path = os.path.join(_PKG, "lib", _LIB_NAME)
+            err = _build(path)
+        lib = None
+        if not err:
+            try:
+                lib = ctypes.CDLL(path)
+            except OSError as e:
+                err = f"load failed: {e}"
+        if lib is None:
+            telemetry.stderr(
+                f"native io: {path} unavailable ({err}); image "
+                "iterators decode with PIL instead\n",
+                event_kind="config", type="native_io_unavailable",
+                path=path, error=err)
             return None
         lib.cxio_open.restype = ctypes.c_void_p
         lib.cxio_open.argtypes = [ctypes.POINTER(ctypes.c_char_p),

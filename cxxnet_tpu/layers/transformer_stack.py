@@ -244,11 +244,8 @@ class TransformerStackLayer(Layer):
 
             init = (jnp.zeros((mb, s, e), xl.dtype),
                     jnp.zeros((M, mb, s, e), xl.dtype))
-            if hasattr(lax, "pcast"):
-                init = jax.tree.map(
-                    lambda a: lax.pcast(a, vary, to="varying"), init)
-            elif hasattr(lax, "pvary"):  # pre-pcast jax tier
-                init = jax.tree.map(lambda a: lax.pvary(a, vary), init)
+            init = jax.tree.map(
+                lambda a: lax.pcast(a, vary, to="varying"), init)
             (_, ys), _ = lax.scan(tick, init, jnp.arange(M + P - 1))
             # only the last stage wrote ys; broadcast it around the ring
             ys = lax.psum(ys, PIPE_AXIS)

@@ -292,7 +292,8 @@ class NetTrainer:
     # ------------------------------------------------------------------
     def set_param(self, name: str, val: str) -> None:
         if name == "dev":
-            self.mesh_spec.device_indices = parse_device_spec(val)
+            (self.mesh_spec.kind,
+             self.mesh_spec.device_indices) = parse_device_spec(val)
         if name == "mesh":
             self.mesh_spec.axes = parse_mesh_spec(val)
         if name == "batch_size":
@@ -454,17 +455,6 @@ class NetTrainer:
         if name == "dtype":
             self.compute_dtype = {"float32": jnp.float32,
                                   "bfloat16": jnp.bfloat16}[val]
-        if name == "compile_cache" and val:
-            # persistent XLA compilation cache: the first AlexNet-sized
-            # TPU compile costs 20-40 s; with this set, re-runs (resume,
-            # pred, eval-only) hit the on-disk cache instead. No
-            # reference analog (CUDA kernels are precompiled; XLA's
-            # compile-at-trace model creates the need). NOTE: the cache
-            # is PROCESS-GLOBAL jax state (one cache per process, last
-            # writer wins) - not per-trainer.
-            from cxxnet_tpu.utils.platform import \
-                set_compilation_cache_dir
-            set_compilation_cache_dir(val)
         if name.startswith("metric"):
             import re
             m = re.match(r"^metric\[([^,\]]+),([^\]]+)\]$", name)

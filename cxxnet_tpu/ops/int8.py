@@ -143,12 +143,14 @@ def _pallas_blocks(m: int, k: int, n: int):
 
 def use_pallas_int8(m: int, k: int, n: int) -> bool:
     """Kernel-route eligibility: TPU backend (or the interpret-mode
-    test hook), a single device (pallas_call has no GSPMD
-    partitioning rule - multi-device meshes take the lax path, which
-    GSPMD partitions), and clean int8 tiling."""
+    test hook), a traced step that spans one device (parallel/mesh.py
+    active_device_span; pallas_call has no GSPMD partitioning rule -
+    multi-device meshes take the lax path, which GSPMD partitions),
+    and clean int8 tiling."""
+    from cxxnet_tpu.parallel.mesh import active_device_span
     if not (jax.default_backend() == "tpu" or _FORCE_INTERPRET):
         return False
-    if jax.device_count() != 1:
+    if active_device_span() != 1:
         return False
     return _pallas_blocks(m, k, n) is not None
 
@@ -167,6 +169,7 @@ def _matmul_pallas(xq: jax.Array, wq: jax.Array) -> jax.Array:
                   pl.BlockSpec((bn, k), lambda i, j: (j, 0))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         interpret=_FORCE_INTERPRET,
+        name="int8_matmul",
     )(xq, wq)
 
 

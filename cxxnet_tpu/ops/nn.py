@@ -48,12 +48,30 @@ def softmax(x):
     return jax.nn.softmax(x, axis=-1)
 
 
+def lrn_xla(x, local_size: int, alpha: float, beta: float, knorm: float):
+    """LRN in plain XLA ops (reduce_window over channels + power): the
+    route of last resort and the reference the Pallas kernels are
+    compared against (tests, chip_smoke.py)."""
+    sq = x * x
+    pad_lo = local_size // 2
+    pad_hi = local_size - pad_lo - 1
+    window_sum = lax.reduce_window(
+        sq, 0.0, lax.add,
+        window_dimensions=(1, local_size, 1, 1),
+        window_strides=(1, 1, 1, 1),
+        padding=((0, 0), (pad_lo, pad_hi), (0, 0), (0, 0)))
+    norm = knorm + (alpha / local_size) * window_sum
+    return x * jnp.power(norm, -beta)
+
+
 def lrn(x, local_size: int, alpha: float, beta: float, knorm: float):
     """Cross-channel local response normalization on NCHW.
 
     out = x * (knorm + alpha/n * sum_{window n}(x^2)) ^ (-beta)
     (lrn_layer-inl.hpp:36-56: tmp_norm = chpool<sum>(x^2) * (alpha/n) + knorm,
-    out = x * tmp_norm^(-beta)).
+    out = x * tmp_norm^(-beta)). Routed by the mesh the step runs over
+    (parallel/mesh.py active_device_span): the Pallas kernel on one
+    device, its shard_map route over a 'data' axis, else lrn_xla.
     """
     from cxxnet_tpu.ops import pallas_lrn as pk
     if pk.use_pallas_lrn(x):
@@ -65,13 +83,4 @@ def lrn(x, local_size: int, alpha: float, beta: float, knorm: float):
             and pk.use_pallas_lrn_sharded(x, mesh):
         return pk.lrn_pallas_sharded(x, mesh, local_size, alpha, beta,
                                      knorm)
-    sq = x * x
-    pad_lo = local_size // 2
-    pad_hi = local_size - pad_lo - 1
-    window_sum = lax.reduce_window(
-        sq, 0.0, lax.add,
-        window_dimensions=(1, local_size, 1, 1),
-        window_strides=(1, 1, 1, 1),
-        padding=((0, 0), (pad_lo, pad_hi), (0, 0), (0, 0)))
-    norm = knorm + (alpha / local_size) * window_sum
-    return x * jnp.power(norm, -beta)
+    return lrn_xla(x, local_size, alpha, beta, knorm)

@@ -161,6 +161,7 @@ def _fwd(q, k, v, scale, causal, interpret) -> Tuple[jax.Array, jax.Array]:
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -280,6 +281,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, interpret):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, do, lse, delta)
 
     # swapped grid: kv outer, q inner (sequential) so dk/dv accumulate
@@ -301,6 +303,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, interpret):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -359,10 +362,13 @@ def _tile_ok(q, sk: int) -> bool:
 
 
 def use_flash(q) -> bool:
-    """Single-device eligibility: TPU backend + tileable shapes. On a
-    multi-device mesh use the shard_map route below - pallas_call alone
-    has no GSPMD partitioning rule (same split as ops/pallas_lrn.py)."""
-    return (_backend_ok() and jax.device_count() == 1
+    """Single-device eligibility: TPU backend, the traced step spans
+    one device (parallel/mesh.py active_device_span), tileable shapes.
+    On a multi-device mesh use the shard_map route below - pallas_call
+    alone has no GSPMD partitioning rule (same split as
+    ops/pallas_lrn.py)."""
+    from cxxnet_tpu.parallel.mesh import active_device_span
+    return (_backend_ok() and active_device_span() == 1
             and _tile_ok(q, q.shape[2]))
 
 

@@ -85,7 +85,7 @@ def _tile_ok(x: jax.Array) -> bool:
     return c % sub == 0 and c * _LANE_TILE * 4 * 3 < 12 * 2 ** 20
 
 
-def _call(kernel, args, x, interpret):
+def _call(kernel, name, args, x, interpret):
     b, c, h, w = x.shape
     hw = h * w
     t = min(_LANE_TILE, hw)
@@ -99,6 +99,9 @@ def _call(kernel, args, x, interpret):
         in_specs=[spec] * len(flat),
         out_specs=spec,
         interpret=interpret,
+        # stable name: trace reductions and chip_smoke.py find the
+        # kernel in the step by it
+        name=name,
     )(*flat)
     return out.reshape(b, c, h, w)
 
@@ -108,7 +111,7 @@ def lrn_pallas(x, local_size, alpha, beta, knorm, interpret=False):
     """Fused LRN; numerically identical to ops.nn.lrn (tested to 1e-5)."""
     kern = functools.partial(_fwd_kernel, n=local_size, alpha=alpha,
                              beta=beta, knorm=knorm)
-    return _call(kern, [x], x, interpret)
+    return _call(kern, "lrn_fwd", [x], x, interpret)
 
 
 def _vjp_fwd(x, local_size, alpha, beta, knorm, interpret=False):
@@ -118,17 +121,21 @@ def _vjp_fwd(x, local_size, alpha, beta, knorm, interpret=False):
 def _vjp_bwd(local_size, alpha, beta, knorm, interpret, x, g):
     kern = functools.partial(_bwd_kernel, n=local_size, alpha=alpha,
                              beta=beta, knorm=knorm)
-    return (_call(kern, [x, g], x, interpret),)
+    return (_call(kern, "lrn_bwd", [x, g], x, interpret),)
 
 
 lrn_pallas.defvjp(_vjp_fwd, _vjp_bwd)
 
 
 def use_pallas_lrn(x: jax.Array) -> bool:
-    """Single-device eligibility: TPU backend + channel dim tiles
-    cleanly. On a multi-device mesh use the shard_map route below -
-    pallas_call alone has no GSPMD partitioning rule."""
-    return (_backend_ok() and jax.device_count() == 1 and _tile_ok(x))
+    """Single-device eligibility: TPU backend, the traced step spans
+    one device (parallel/mesh.py active_device_span - the mesh, not
+    the host's device count), and the channel dim tiles cleanly. On a
+    multi-device mesh use the shard_map route below - pallas_call
+    alone has no GSPMD partitioning rule."""
+    from cxxnet_tpu.parallel.mesh import active_device_span
+    return (_backend_ok() and active_device_span() == 1
+            and _tile_ok(x))
 
 
 # test hook: force the kernel on non-TPU backends in interpret mode so

@@ -61,20 +61,10 @@ def _enable_cpu_collectives() -> None:
     the client does not exist yet or initialize itself would fail).
     Scoped to cpu platforms: TPU pods keep their native ICI
     collectives and never see this flag."""
-    platforms = os.environ.get("JAX_PLATFORMS", "")
-    if not platforms:
-        try:
-            platforms = jax.config.jax_platforms or ""
-        except AttributeError:  # very old/new jax: leave the default
-            return
-    if "cpu" in str(platforms).lower():
-        try:
-            jax.config.update(
-                "jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, ValueError):
-            # flag renamed/absent on this jax: the job either works
-            # without it or fails with the explicit runtime error
-            pass
+    platforms = (os.environ.get("JAX_PLATFORMS", "")
+                 or jax.config.jax_platforms or "")
+    if "cpu" in platforms.lower():
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def init_distributed(coordinator: Optional[str] = None,

@@ -36,8 +36,12 @@ one from the published checkpoint:
   next generation's ``continue=1`` resume re-trains from it with the
   new mesh.
 
-The supervisor is deliberately jax-free: it never imports the backend,
-so it can outlive any number of wedged generations.
+The supervisor is deliberately jax-free: it never touches a backend,
+so it holds no chip and can outlive any number of wedged generations.
+Its workers start with identical environments (_spawn), so a pod of
+N > 1 on ONE machine is a CPU pod (JAX_PLATFORMS=cpu - what the tests
+and the CI smoke run): a chip belongs to one process at a time, and on
+TPUs a member is one worker per HOST.
 
 See docs/FAULT_TOLERANCE.md "Elastic pod" for the protocol and the
 CI ``elastic-smoke`` job for the end-to-end proof.

@@ -35,11 +35,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from cxxnet_tpu.ops.attention import (
@@ -116,11 +112,8 @@ def _ring_jit(q, k, v, mesh, causal, scale):
         # stay replicated or the out_specs vma check rejects the body)
         part0 = empty_partial(q)
         axes = tuple(a for a in spec if a is not None)
-        if hasattr(lax, "pcast"):
-            part0 = jax.tree.map(
-                lambda x: lax.pcast(x, axes, to="varying"), part0)
-        elif hasattr(lax, "pvary"):
-            part0 = jax.tree.map(lambda x: lax.pvary(x, axes), part0)
+        part0 = jax.tree.map(
+            lambda x: lax.pcast(x, axes, to="varying"), part0)
         # n-1 rotate-and-accumulate steps, then the final block WITHOUT
         # the rotation (its K/V would only feed the discarded carry -
         # one whole ring pass of wasted ICI traffic per call otherwise)

@@ -35,24 +35,14 @@ DATA_AXIS = "data"
 
 
 def shard_map_manual(fn, mesh: Mesh, manual_axes, in_specs, out_specs):
-    """shard_map across the old/new jax API split: manual over
-    `manual_axes`, every OTHER mesh axis left to GSPMD (auto), value
-    replication unchecked (the zero region's in/out specs assert the
-    layouts the trainer compiles against; a varying-axes check would
-    reject the deliberately-unreduced gradients). New API
-    (jax.shard_map: axis_names/check_vma) first, the 0.4.x
-    experimental spelling (auto/check_rep) as fallback."""
-    manual = set(manual_axes)
-    try:
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=manual,
-                             check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        auto = frozenset(a for a in mesh.axis_names
-                         if a not in manual)
-        return _sm(fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False, auto=auto)
+    """shard_map manual over `manual_axes`, every OTHER mesh axis left
+    to GSPMD (auto), value replication unchecked (the zero region's
+    in/out specs assert the layouts the trainer compiles against; a
+    varying-axes check would reject the deliberately-unreduced
+    gradients)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=set(manual_axes), check_vma=False)
 
 
 def param_pspecs(net: Network, shapes=None) -> Dict[str, Dict[str, P]]:

@@ -13,6 +13,14 @@ Usage:
 Each worker gets CXN_COORDINATOR / CXN_NUM_WORKER / CXN_WORKER_RANK in
 its environment; config keys dist_num_worker/dist_worker_rank on the
 iterators pick up the worker's data shard.
+
+Where it applies: the N workers start with IDENTICAL environments, so
+on one machine they would all claim the same chips - and a chip
+belongs to one process at a time. Run N > 1 here on the CPU only
+(JAX_PLATFORMS=cpu: the multi-controller tests and smokes); on TPUs
+it is one worker per HOST (each host's launcher starts its single
+worker, which drives all of that host's chips). This parent never
+touches JAX, so it holds no chip itself.
 """
 
 from __future__ import annotations

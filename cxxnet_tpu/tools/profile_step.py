@@ -4,7 +4,7 @@ The reference exposes wall-clock timing only (cxxnet_main.cpp's elapsed
 prints); the TPU-native replacement is a real device trace:
 `jax.profiler` captures an XSpace, and this tool aggregates per-op
 device time so "where does the step go" is a committed number, not a
-guess (VERDICT r2 weak #3). Output: top-N ops by self time + total
+guess. Output: top-N ops by self time + total
 step accounting, printed and optionally written as markdown.
 
 Usage:
@@ -39,9 +39,9 @@ def capture(trace_dir: str, steps: int = 20) -> str:
             "from a source checkout root (bench/__graft_entry__ are not "
             "packaged)") from e
     from cxxnet_tpu.utils.config import parse_config_file
-    from cxxnet_tpu.utils.platform import ensure_env_platform
+    from cxxnet_tpu.utils.platform import setup_compile_cache
 
-    ensure_env_platform()
+    setup_compile_cache()
     platform = jax.devices()[0].platform
     batch = 256 if platform != "cpu" else 8
     trainer = _make_trainer(

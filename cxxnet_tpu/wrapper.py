@@ -249,6 +249,7 @@ def train(cfg: str, data, label, num_round: int,
     stderr like the CLI round loop - regression nets should evaluate
     manually. The final partial batch of each round trains too (padded
     internally)."""
+    import jax
     from cxxnet_tpu import telemetry
     net = Net(dev=dev, cfg=cfg)
     net.set_param("batch_size", batch_size)
@@ -281,7 +282,18 @@ def train(cfg: str, data, label, num_round: int,
                 k = net._net.steps_per_dispatch
                 staged = [net._net.stage_chunk(staged[i:i + k])
                           for i in range(0, len(staged), k)]
-        except Exception:  # noqa: BLE001 - staging is an optimization
+        except jax.errors.JaxRuntimeError as e:
+            # the one failure staging may absorb: the dataset passed
+            # the host-side bound but does not fit device memory next
+            # to the model - say so and stream. Anything else (a bad
+            # shape, a dead backend) is a real error and propagates
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            telemetry.stderr(
+                f"train: staging {staged_bytes} bytes on the device "
+                "ran out of memory; streaming the dataset instead\n",
+                event_kind="config", type="stage_oom",
+                bytes=staged_bytes)
             staged = None
     pf = None
     if staged is None:

@@ -34,3 +34,12 @@ def test_entry_builds_flagship():
     assert data.shape == (32, 3, 227, 227)
     # flagship net: AlexNet fc8 produces 1000-way logits
     assert params["fc8"]["wmat"].shape[0] == 1000
+
+
+def test_entry_fails_without_a_chip(monkeypatch):
+    """entry() keeps `dev = tpu`: where JAX found no TPU (and nothing
+    outside said JAX_PLATFORMS=cpu) it fails like every other caller
+    instead of building the flagship on the host."""
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(RuntimeError, match="dev = tpu"):
+        ge.entry()

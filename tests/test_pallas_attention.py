@@ -85,16 +85,27 @@ def test_custom_scale(small_blocks):
 
 
 def test_routing_gate():
+    """The single-device route follows the MESH the step runs over,
+    not the host's device count (8 here): a one-device mesh takes the
+    kernel, a multi-device mesh the shard_map route (pallas_call has
+    no GSPMD rule), the zero_stage>=2 region (None bound) declines."""
     from jax.sharding import Mesh
+    from cxxnet_tpu.parallel.mesh import active_mesh
+    assert jax.device_count() == 8
     q, _, _ = _qkv(b=8, s=32, d=16)
     assert not PA.use_flash(q)          # cpu backend, no hook
     assert not PA.use_flash_sharded(q, None)
     PA._FORCE_INTERPRET = True
     try:
-        # single-device route stays off on the 8-device test platform
-        # (pallas_call has no GSPMD rule); the shard_map route engages
-        assert not PA.use_flash(q)
         mesh = Mesh(np.asarray(jax.devices()).reshape(8), ("data",))
+        one = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+        assert PA.use_flash(q)          # direct call: one device
+        with active_mesh(one):
+            assert PA.use_flash(q)
+        with active_mesh(mesh):
+            assert not PA.use_flash(q)
+        with active_mesh(None):
+            assert not PA.use_flash(q)
         assert PA.use_flash_sharded(q, mesh)
         # untileable sublane (seq 12 -> best divisor 12 or 4, not 8-mult)
         q2, _, _ = _qkv(s=12)

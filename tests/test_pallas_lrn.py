@@ -96,3 +96,53 @@ def test_eligibility_gate():
     x_bf = jnp.zeros((1, 24, 4, 4), jnp.bfloat16)
     assert not _tile_ok(x_bf)       # 24 % 16 != 0
     assert _tile_ok(jnp.zeros((1, 32, 4, 4), jnp.bfloat16))
+
+
+_LRN_NET = """
+netconfig=start
+layer[0->1] = conv:cv1
+  kernel_size = 3
+  pad = 1
+  nchannel = 16
+layer[1->2] = lrn
+  local_size = 5
+layer[2->3] = flatten
+layer[3->4] = fullc:fc1
+  nhidden = 4
+layer[4->4] = softmax
+netconfig=end
+input_shape = 3,6,6
+batch_size = 8
+eta = 0.1
+silent = 1
+"""
+
+
+@pytest.mark.parametrize("dev,sharded", [("cpu", False),
+                                         ("cpu:0-3", True)])
+def test_train_step_route_follows_mesh_size(monkeypatch, dev, sharded):
+    """The traced train step takes the kernel route its MESH calls
+    for while the host shows 8 devices: a one-device mesh gets the
+    single-device kernel (it used to fall through to reduce_window
+    because jax.device_count() != 1), a 4-device mesh the shard_map
+    route - forward and backward kernels either way."""
+    from cxxnet_tpu.io.data import DataBatch
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+    from cxxnet_tpu.ops import pallas_lrn
+    assert jax.device_count() == 8
+    monkeypatch.setattr(pallas_lrn, "_FORCE_INTERPRET", True)
+    tr = NetTrainer(dev=dev, cfg=_LRN_NET)
+    tr.init_model()
+    assert tr.mesh.devices.size == (4 if sharded else 1)
+    rng = np.random.RandomState(0)
+    sb = tr.stage_batch(DataBatch(
+        data=rng.randn(8, 3, 6, 6).astype(np.float32),
+        label=rng.randint(0, 4, (8, 1)).astype(np.float32)))
+    txt = str(tr._train_step.trace(
+        tr.state, sb.data, sb.extras, sb.labels, sb.mask,
+        jax.random.PRNGKey(0)).jaxpr)
+    assert txt.count("name=lrn_fwd") == 1
+    assert txt.count("name=lrn_bwd") == 1
+    assert ("shard_map" in txt) == sharded
+    tr.update(sb)
+    assert np.isfinite(np.asarray(tr.state["params"]["cv1"]["wmat"])).all()

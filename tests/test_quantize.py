@@ -120,12 +120,20 @@ def test_pallas_kernel_matches_lax_fallback_interpret():
     int8_ops._FORCE_INTERPRET = True
     try:
         # the test platform is an 8-device virtual CPU mesh
-        # (conftest): the route gate must refuse - pallas_call has no
-        # GSPMD partitioning rule - while the kernel itself still
-        # runs in interpret mode
+        # (conftest), but the route gate asks the mesh the step runs
+        # over: a direct call or a one-device mesh takes the kernel,
+        # a multi-device mesh refuses (pallas_call has no GSPMD
+        # partitioning rule; the lax path partitions)
         import jax
-        assert (int8_ops.use_pallas_int8(32, 128, 128)
-                == (jax.device_count() == 1))
+        from jax.sharding import Mesh
+        from cxxnet_tpu.parallel.mesh import active_mesh
+        assert jax.device_count() == 8
+        assert int8_ops.use_pallas_int8(32, 128, 128)
+        with active_mesh(Mesh(np.asarray(jax.devices()[:1]),
+                              ("data",))):
+            assert int8_ops.use_pallas_int8(32, 128, 128)
+        with active_mesh(Mesh(np.asarray(jax.devices()), ("data",))):
+            assert not int8_ops.use_pallas_int8(32, 128, 128)
         pl_out = np.asarray(int8_ops._matmul_pallas(xq, wq))
     finally:
         int8_ops._FORCE_INTERPRET = old

@@ -422,28 +422,6 @@ metric[extra,out2] = rmse
     assert "e-error:" in out and "e-rmse[extra]:" in out
 
 
-def test_compile_cache_flag(tmp_path):
-    """compile_cache=<dir> populates XLA's persistent compilation
-    cache; the flag exists so TPU re-runs skip the first-compile cost
-    (docs/global.md). The setting is process-global jax config, so the
-    test restores it to keep later tests cache-free."""
-    import jax
-    saved = {k: getattr(jax.config, k) for k in (
-        "jax_compilation_cache_dir",
-        "jax_persistent_cache_min_compile_time_secs",
-        "jax_persistent_cache_min_entry_size_bytes")}
-    cache = tmp_path / "xlacache"
-    try:
-        t = make_trainer(extra=f"\ncompile_cache = {cache}\n")
-        for b in synth_batches(2):
-            t.update(b)
-        jax.block_until_ready(t.state)
-        assert cache.is_dir() and len(list(cache.iterdir())) > 0
-    finally:
-        for k, v in saved.items():
-            jax.config.update(k, v)
-
-
 def test_active_step_binding_end_to_end():
     """The trainer binds the traced update counter into every training
     forward (epoch*update_period + count), verified observably: a probe
