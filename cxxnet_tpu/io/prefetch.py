@@ -27,6 +27,7 @@ import time
 
 from cxxnet_tpu import telemetry
 from cxxnet_tpu.io.thread_util import drain_and_join
+from cxxnet_tpu.telemetry import spans
 
 _END = object()
 
@@ -113,31 +114,35 @@ class StagedPrefetcher:
             # queue); later gets stretch to 2 s - the timeout then
             # exists ONLY as the dead-worker sweep (a healthy worker
             # always delivers a batch, _END, or its exception)
-            timeout = 0.2
-            while True:
-                try:
-                    item = self._q.get(timeout=timeout)
-                    break
-                except queue.Empty:
-                    stalled = True
-                    timeout = 2.0
-                    if (self._thread is not None
-                            and self._thread.is_alive()):
-                        continue
-                    # worker died without delivering a batch, _END, or
-                    # an exception (e.g. killed interpreter-side): one
-                    # last race-free sweep, then fail instead of
-                    # hanging forever
+            # the wait the step felt, on a running profiler trace's own
+            # clock (telemetry/spans.py); only this branch pays for it
+            from jax.profiler import TraceAnnotation
+            with TraceAnnotation(spans.IO_WAIT):
+                timeout = 0.2
+                while True:
                     try:
-                        item = self._q.get_nowait()
+                        item = self._q.get(timeout=timeout)
                         break
                     except queue.Empty:
-                        self._exhausted = True
-                        raise RuntimeError(
-                            "staged-prefetch worker died without "
-                            "delivering a batch or an error; the data "
-                            "pipeline is gone (see stderr for the "
-                            "worker's traceback)")
+                        stalled = True
+                        timeout = 2.0
+                        if (self._thread is not None
+                                and self._thread.is_alive()):
+                            continue
+                        # worker died without delivering a batch, _END, or
+                        # an exception (e.g. killed interpreter-side): one
+                        # last race-free sweep, then fail instead of
+                        # hanging forever
+                        try:
+                            item = self._q.get_nowait()
+                            break
+                        except queue.Empty:
+                            self._exhausted = True
+                            raise RuntimeError(
+                                "staged-prefetch worker died without "
+                                "delivering a batch or an error; the data "
+                                "pipeline is gone (see stderr for the "
+                                "worker's traceback)")
         if item is _END:
             self._exhausted = True
             return False

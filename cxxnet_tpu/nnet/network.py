@@ -33,6 +33,16 @@ def param_key(cfg: NetConfig, layer_index: int) -> str:
     return info.name if info.name else f"layer_{layer_index}"
 
 
+def layer_scope(cfg: NetConfig, layer_index: int) -> str:
+    """`<type>.<key>`, the scope a layer's operations are traced under.
+    A shared layer takes its primary's type and its own index's key, so
+    that two uses of one set of weights are two names."""
+    info = cfg.layers[layer_index]
+    if info.is_shared:
+        info = cfg.layers[info.primary_layer_index]
+    return f"{info.type_name}.{param_key(cfg, layer_index)}"
+
+
 class Network:
     """Holds layer objects + inferred node shapes; provides pure forward."""
 
@@ -165,55 +175,60 @@ class Network:
                 cfg, info.primary_layer_index if info.is_shared else idx)
             p = params.get(pkey, {})
             xs = [values[j] for j in info.nindex_in]
-            if self.dtype_plan is not None:
-                want = self.dtype_plan.get(idx)
-                if want is not None:
-                    # autocast plan (nnet/passes.py): cast this
-                    # layer's inputs + params to its stamped compute
-                    # dtype; f32-stamped layers under a bf16 net thus
-                    # run their math in f32 (the next bf16 layer
-                    # casts back down)
-                    xs = [x.astype(want)
-                          if jnp.issubdtype(x.dtype, jnp.floating)
-                          else x for x in xs]
-                    p = {k: (v.astype(want)
-                             if jnp.issubdtype(v.dtype, jnp.floating)
-                             else v) for k, v in p.items()}
-            if taps is not None and idx in taps:
-                # post-cast snapshot: exactly what the layer's apply
-                # receives (the docstring's tap contract)
-                taps[idx] = xs[0]
-            layer_rng = (jax.random.fold_in(rng, idx)
-                         if rng is not None else None)
+            # the layer's name in the traced program: every device
+            # operation of this layer carries it in its `op_name`, under
+            # `jvp(...)` forward and `transpose(jvp(...))` backward
+            # (docs/OBSERVABILITY.md "Reading a device trace")
+            with jax.named_scope(layer_scope(cfg, idx)):
+                if self.dtype_plan is not None:
+                    want = self.dtype_plan.get(idx)
+                    if want is not None:
+                        # autocast plan (nnet/passes.py): cast this
+                        # layer's inputs + params to its stamped compute
+                        # dtype; f32-stamped layers under a bf16 net thus
+                        # run their math in f32 (the next bf16 layer
+                        # casts back down)
+                        xs = [x.astype(want)
+                              if jnp.issubdtype(x.dtype, jnp.floating)
+                              else x for x in xs]
+                        p = {k: (v.astype(want)
+                                 if jnp.issubdtype(v.dtype, jnp.floating)
+                                 else v) for k, v in p.items()}
+                if taps is not None and idx in taps:
+                    # post-cast snapshot: exactly what the layer's apply
+                    # receives (the docstring's tap contract)
+                    taps[idx] = xs[0]
+                layer_rng = (jax.random.fold_in(rng, idx)
+                             if rng is not None else None)
 
-            if isinstance(layer, LossLayer):
-                x = xs[0]
-                b = x.shape[0]
-                flat = x.reshape(b, -1)
-                if labels is not None:
-                    lbl = labels[layer.target]
-                    per_ex = layer.per_example_loss(flat, lbl)
-                    if mask is not None:
-                        per_ex = per_ex * mask
-                    total_loss = total_loss + layer.grad_scale * jnp.sum(
-                        per_ex)
-                out = layer.forward_transform(flat).reshape(x.shape)
-                values[info.nindex_out[0]] = out
-                continue
+                if isinstance(layer, LossLayer):
+                    x = xs[0]
+                    b = x.shape[0]
+                    flat = x.reshape(b, -1)
+                    if labels is not None:
+                        lbl = labels[layer.target]
+                        per_ex = layer.per_example_loss(flat, lbl)
+                        if mask is not None:
+                            per_ex = per_ex * mask
+                        total_loss = total_loss + layer.grad_scale * jnp.sum(
+                            per_ex)
+                    out = layer.forward_transform(flat).reshape(x.shape)
+                    values[info.nindex_out[0]] = out
+                    continue
 
-            if layer.has_aux:
-                # layers with an auxiliary loss term (e.g. the MoE
-                # load-balance loss, layers/moe.py) fold it into the
-                # same total the loss layers accumulate (contract on
-                # Layer.has_aux, layers/base.py)
-                outs, aux = layer.apply_with_aux(p, xs, train=train,
-                                                 rng=layer_rng, mask=mask)
-                if train:
-                    total_loss = total_loss + aux
-            else:
-                outs = layer.apply(p, xs, train=train, rng=layer_rng)
-            for j, o in zip(info.nindex_out, outs):
-                values[j] = o
+                if layer.has_aux:
+                    # layers with an auxiliary loss term (e.g. the MoE
+                    # load-balance loss, layers/moe.py) fold it into the
+                    # same total the loss layers accumulate (contract on
+                    # Layer.has_aux, layers/base.py)
+                    outs, aux = layer.apply_with_aux(p, xs, train=train,
+                                                     rng=layer_rng, mask=mask)
+                    if train:
+                        total_loss = total_loss + aux
+                else:
+                    outs = layer.apply(p, xs, train=train, rng=layer_rng)
+                for j, o in zip(info.nindex_out, outs):
+                    values[j] = o
 
         return values, total_loss
 

@@ -32,9 +32,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from cxxnet_tpu import telemetry
+from cxxnet_tpu.telemetry import spans
 from cxxnet_tpu.io.data import DataBatch
 from cxxnet_tpu.nnet import checkpoint
 from cxxnet_tpu.nnet.net_config import NetConfig
@@ -968,7 +970,8 @@ class NetTrainer:
         def loss_fn(params, data, extras, labels, mask, rng, step):
             cparams = self._cast(params)
             if daug is not None:
-                data = daug(data, jax.random.fold_in(rng, 0xA6), True)
+                with jax.named_scope("augment"):
+                    data = daug(data, jax.random.fold_in(rng, 0xA6), True)
             inputs = {0: self._cast(data)}
             for i, e in enumerate(extras):
                 inputs[1 + i] = self._cast(e)
@@ -1045,7 +1048,9 @@ class NetTrainer:
                 rng = jax.random.fold_in(rng, lax.axis_index("data"))
                 (loss, outs), grads = grad_inner(
                     params, data, extras, labels, mask, rng, step)
-                return (lax.psum(loss, "data"), outs), _scatter(grads)
+                with jax.named_scope("grad_reduce"):
+                    grads = _scatter(grads)
+                return (lax.psum(loss, "data"), outs), grads
 
             dspec = P("data")
             # params enter replicated-over-'data' (P()): under stage 3
@@ -1079,7 +1084,8 @@ class NetTrainer:
                 # sized zero tree through HBM every step for nothing
                 accum = grads
             else:
-                accum = jax.tree.map(jnp.add, state["accum"], grads)
+                with jax.named_scope("accum"):
+                    accum = jax.tree.map(jnp.add, state["accum"], grads)
             count = state["count"] + 1
             do_update = count >= update_period
 
@@ -1102,37 +1108,40 @@ class NetTrainer:
                             # skips the slice - params arrive sharded.
                             w = lax.with_sharding_constraint(
                                 w, zshard[lk][pn])
-                        st, w = up.apply(ustate[lk][pn], w,
-                                         accum[lk][pn], state["epoch"])
+                        with jax.named_scope(lk):
+                            st, w = up.apply(ustate[lk][pn], w,
+                                             accum[lk][pn], state["epoch"])
                         new_params[lk][pn] = w
                         new_ustate[lk][pn] = st
                 # graftlint: disable=GL007 the zero tree inherits accum's zero-stage sharding via donation/out_shardings
                 zero = jax.tree.map(jnp.zeros_like, accum)
                 return new_params, new_ustate, zero
 
-            if update_period == 1:
-                # do_update is tautologically true every step; a
-                # lax.cond here is not just dead weight - the
-                # conditional boundary blocks XLA from fusing the
-                # optimizer into the backward fusions (measured ~6% of
-                # AlexNet b256 device step time as a standalone
-                # %conditional in the round-4 on-chip profile)
-                params, ustate, accum = apply_updates(
-                    (state["params"], state["ustate"], accum))
-            else:
-                params, ustate, accum = lax.cond(
-                    do_update, apply_updates, lambda a: a,
-                    (state["params"], state["ustate"], accum))
+            with jax.named_scope("update"):
+                if update_period == 1:
+                    # do_update is tautologically true every step; a
+                    # lax.cond here is not just dead weight - the
+                    # conditional boundary blocks XLA from fusing the
+                    # optimizer into the backward fusions (measured ~6%
+                    # of AlexNet b256 device step time as a standalone
+                    # %conditional in the round-4 on-chip profile)
+                    params, ustate, accum = apply_updates(
+                        (state["params"], state["ustate"], accum))
+                else:
+                    params, ustate, accum = lax.cond(
+                        do_update, apply_updates, lambda a: a,
+                        (state["params"], state["ustate"], accum))
             tmetric = state["tmetric"]
             if eval_train:
-                rows = metric_rows(outs, labels, mask, rng, 1000)
-                # Kahan-compensated sum in column 0; plain count in 2
-                s, comp, cnt = (tmetric[:, 0], tmetric[:, 1],
-                                tmetric[:, 2])
-                y = rows[:, 0] - comp
-                t = s + y
-                tmetric = jnp.stack(
-                    [t, (t - s) - y, cnt + rows[:, 1]], axis=1)
+                with jax.named_scope("metric"):
+                    rows = metric_rows(outs, labels, mask, rng, 1000)
+                    # Kahan-compensated sum in column 0; plain count in 2
+                    s, comp, cnt = (tmetric[:, 0], tmetric[:, 1],
+                                    tmetric[:, 2])
+                    y = rows[:, 0] - comp
+                    t = s + y
+                    tmetric = jnp.stack(
+                        [t, (t - s) - y, cnt + rows[:, 1]], axis=1)
             new_state = {
                 "params": params,
                 "ustate": ustate,
@@ -1556,6 +1565,17 @@ class NetTrainer:
         return (padrows(batch.data), padrows(batch.label), mask,
                 tuple(padrows(e).astype(np.float32) for e in extras))
 
+    def step_hlo(self, batch: StagedBatch) -> str:
+        """The compiled train step for this batch (or a StagedBatch of
+        `jax.ShapeDtypeStruct`s) as HLO text: every instruction, inside
+        fused computations too, with the layer / `update` scope it was
+        traced under in its `op_name`. What to read when `/executables`
+        or a device trace names an operation (docs/OBSERVABILITY.md
+        "Reading a device trace"). One compile per call, nothing kept."""
+        return self._train_step.lower(
+            self.state, batch.data, batch.extras, batch.labels,
+            batch.mask, jax.random.PRNGKey(0)).compile().as_text()
+
     def stage_batch(self, batch: DataBatch) -> StagedBatch:
         """Stage a batch's device buffers ONCE for repeated update()
         calls (see StagedBatch). The staging runs the exact per-step
@@ -1625,98 +1645,104 @@ class NetTrainer:
         StagedChunk (fused: K microsteps in one dispatch)."""
         if isinstance(batch, StagedChunk):
             return self.update_chunk(batch)
-        track = bool(self.profile) or self._tel_steps
-        t0 = time.perf_counter() if track else 0.0
-        if not isinstance(batch, StagedBatch):
-            # the streamed path IS one stage_batch call - structural
-            # guarantee of the staged/streamed trajectory equivalence.
-            # Staging also validates; a rejected batch must raise
-            # BEFORE the step counter moves, or a caller that catches
-            # the error would silently shift the whole RNG stream
-            batch = self.stage_batch(batch)
-        rng = jax.random.fold_in(
-            jax.random.PRNGKey(self.seed + 100), self._step_counter)
-        self._step_counter += 1
-        gdata, gextras = batch.data, batch.extras
-        glabels, gmask = batch.labels, batch.mask
-        n_examples = batch.n_examples
-        data_s = 0.0
-        if track:
-            # host-side prep (padding, casting, H2D staging) vs device
-            # step, reported separately by StepProfiler.summary
-            t1 = time.perf_counter()
-            data_s = t1 - t0
-            if self.profiler is not None:
-                self.profiler.add_data(data_s)
-            t0 = t1
-        # collective-scope fault point (docs/FAULT_TOLERANCE.md
-        # "Elastic pod"): the dispatched step carries the pod-wide
-        # gradient AllReduce, so kill_rank/hang_rank/delay_collective
-        # armed here murder or wedge ONE worker at a deterministic
-        # step - every rank hits this point in the same order under
-        # SPMD, so @N names the same step on every worker
-        fault.fault_point("collective")
-        # the step is dispatched asynchronously and train metrics
-        # accumulate on device - nothing here blocks on the result, so
-        # host-side input prep for batch k+1 overlaps compute of batch
-        # k. The _flight_record wrapper spans the dispatch + guard
-        # readback (the sync a hung backend wedges in) so a stall dump
-        # names this exact executable.
-        ok = None
-        with self._flight_record(
-                "train_step", ("train_step", tuple(gdata.shape)),
-                kind="train", name=f"train_step@b{gdata.shape[0]}",
-                shape=gdata.shape, nbytes=gdata.nbytes, donated=1):
-            if self._check_nan_built:
-                # divergence guard: the per-step finite flag must be
-                # read back (a device sync - the cost of check_nan=1;
-                # staging prefetch still overlaps on its worker thread)
-                self.state, loss, finite = self._train_step(
-                    self.state, gdata, gextras, glabels, gmask, rng)
-                # graftlint: disable=GL002 the guard's documented sync: the finite flag must be read back before the next step commits
-                ok = bool(np.asarray(distributed.fetch_local(finite)))
-            else:
-                self.state, loss = self._train_step(
-                    self.state, gdata, gextras, glabels, gmask, rng)
-        if ok is not None:
-            self._guard_step(ok, self._step_counter - 1)
-        # host mirror of the device epoch counter (one update per
-        # update_period steps) - avoids forcing a device sync per step;
-        # guard-dropped steps never advanced the device counters
-        self.epoch = self._epoch_base + (
-            (self._step_counter - self._skipped_steps)
-            // self.update_period)
-        # progress beacon for the hang watchdog / absence alert rules
-        # (docs/OBSERVABILITY.md): one dict store, no device sync -
-        # the step DISPATCHED; a hung backend blocks above, in the
-        # step call or the guard readback, and the beacon goes stale
-        telemetry.beacon("train.step")
-        if track:
-            # per-step timing forces a device sync (same cost profile=1
-            # always paid; staging prefetch still overlaps on its
-            # worker thread) - the price of honest step times
-            # graftlint: disable=GL002 honest per-step timing requires the sync - profile/telemetry_steps opt-in only
-            jax.block_until_ready(self.state["epoch"])
-            step_s = time.perf_counter() - t0
-            if self.profiler is not None:
-                # distinct-instance count: wrap/pad rows in
-                # num_batch_padd would inflate images/sec
-                self.profiler.add_step(step_s, n_examples)
-            if self._tel_steps:
-                tel = telemetry.get()
-                step_idx = self._step_counter - 1
-                # graftlint: disable=GL002 loss gauge readback, gated by telemetry_steps=1
-                loss_val = float(np.asarray(
-                    distributed.fetch_local(loss)))
-                tel.observe("train.data_s", data_s)
-                tel.observe("train.step_s", step_s)
-                tel.inc("train.images", n_examples)
-                tel.set_gauge("train.loss", loss_val)
-                tel.event("span", name="train.data", secs=data_s,
-                          round=self.round, step=step_idx)
-                tel.event("span", name="train.step", secs=step_s,
-                          round=self.round, step=step_idx,
-                          loss=loss_val, examples=n_examples)
+        step_idx = self._step_counter
+        with StepTraceAnnotation(spans.TRAIN, step_num=step_idx):
+            track = bool(self.profile) or self._tel_steps
+            t0 = time.perf_counter() if track else 0.0
+            if not isinstance(batch, StagedBatch):
+                # the streamed path IS one stage_batch call - structural
+                # guarantee of the staged/streamed trajectory equivalence.
+                # Staging also validates; a rejected batch must raise
+                # BEFORE the step counter moves, or a caller that catches
+                # the error would silently shift the whole RNG stream
+                with TraceAnnotation(spans.TRAIN_STAGE):
+                    batch = self.stage_batch(batch)
+            with TraceAnnotation(spans.TRAIN_KEY):
+                rng = jax.random.fold_in(
+                    jax.random.PRNGKey(self.seed + 100), step_idx)
+            self._step_counter += 1
+            gdata, gextras = batch.data, batch.extras
+            glabels, gmask = batch.labels, batch.mask
+            n_examples = batch.n_examples
+            data_s = 0.0
+            if track:
+                # host-side prep (padding, casting, H2D staging) vs device
+                # step, reported separately by StepProfiler.summary
+                t1 = time.perf_counter()
+                data_s = t1 - t0
+                if self.profiler is not None:
+                    self.profiler.add_data(data_s)
+                t0 = t1
+            # collective-scope fault point (docs/FAULT_TOLERANCE.md
+            # "Elastic pod"): the dispatched step carries the pod-wide
+            # gradient AllReduce, so kill_rank/hang_rank/delay_collective
+            # armed here murder or wedge ONE worker at a deterministic
+            # step - every rank hits this point in the same order under
+            # SPMD, so @N names the same step on every worker
+            fault.fault_point("collective")
+            # the step is dispatched asynchronously and train metrics
+            # accumulate on device - nothing here blocks on the result, so
+            # host-side input prep for batch k+1 overlaps compute of batch
+            # k. The _flight_record wrapper spans the dispatch + guard
+            # readback (the sync a hung backend wedges in) so a stall dump
+            # names this exact executable.
+            ok = None
+            with self._flight_record(
+                    "train_step", ("train_step", tuple(gdata.shape)),
+                    kind="train", name=f"train_step@b{gdata.shape[0]}",
+                    shape=gdata.shape, nbytes=gdata.nbytes, donated=1,
+                    fields={"step": step_idx}):
+                with TraceAnnotation(spans.TRAIN_CALL):
+                    out = self._train_step(
+                        self.state, gdata, gextras, glabels, gmask, rng)
+                if self._check_nan_built:
+                    # divergence guard: the per-step finite flag must be
+                    # read back (a device sync - the cost of check_nan=1;
+                    # staging prefetch still overlaps on its worker thread)
+                    self.state, loss, finite = out
+                    with TraceAnnotation(spans.TRAIN_GUARD):
+                        # graftlint: disable=GL002 the guard's documented sync: the finite flag must be read back before the next step commits
+                        ok = bool(np.asarray(distributed.fetch_local(finite)))
+                else:
+                    self.state, loss = out
+            if ok is not None:
+                self._guard_step(ok, step_idx)
+            # host mirror of the device epoch counter (one update per
+            # update_period steps) - avoids forcing a device sync per step;
+            # guard-dropped steps never advanced the device counters
+            self.epoch = self._epoch_base + (
+                (self._step_counter - self._skipped_steps)
+                // self.update_period)
+            # progress beacon for the hang watchdog / absence alert rules
+            # (docs/OBSERVABILITY.md): one dict store, no device sync -
+            # the step DISPATCHED; a hung backend blocks above, in the
+            # step call or the guard readback, and the beacon goes stale
+            telemetry.beacon("train.step")
+            if track:
+                # per-step timing forces a device sync (same cost profile=1
+                # always paid; staging prefetch still overlaps on its
+                # worker thread) - the price of honest step times
+                # graftlint: disable=GL002 honest per-step timing requires the sync - profile/telemetry_steps opt-in only
+                jax.block_until_ready(self.state["epoch"])
+                step_s = time.perf_counter() - t0
+                if self.profiler is not None:
+                    # distinct-instance count: wrap/pad rows in
+                    # num_batch_padd would inflate images/sec
+                    self.profiler.add_step(step_s, n_examples)
+                if self._tel_steps:
+                    tel = telemetry.get()
+                    # graftlint: disable=GL002 loss gauge readback, gated by telemetry_steps=1
+                    loss_val = float(np.asarray(
+                        distributed.fetch_local(loss)))
+                    tel.observe("train.data_s", data_s)
+                    tel.observe("train.step_s", step_s)
+                    tel.inc("train.images", n_examples)
+                    tel.set_gauge("train.loss", loss_val)
+                    tel.event("span", name="train.data", secs=data_s,
+                              round=self.round, step=step_idx)
+                    tel.event("span", name="train.step", secs=step_s,
+                              round=self.round, step=step_idx,
+                              loss=loss_val, examples=n_examples)
 
     # graftlint: hot-path
     def update_chunk(self, chunk) -> None:
@@ -1729,90 +1755,96 @@ class NetTrainer:
         that a DivergenceError can surface up to K-1 microsteps after
         the fatal one (the chunk has already run on device), with the
         in-jit rollback semantics unchanged."""
-        track = bool(self.profile) or self._tel_steps
-        t0 = time.perf_counter() if track else 0.0
-        if not isinstance(chunk, StagedChunk):
-            # staging validates; a rejected batch must raise BEFORE
-            # the step counter moves (same contract as update())
-            chunk = self.stage_chunk(chunk)
-        k = chunk.n_steps
-        base_rng = jax.random.PRNGKey(self.seed + 100)
-        first_step = self._step_counter
-        step_idx = distributed.put_global(
-            np.arange(first_step, first_step + k, dtype=np.int32),
-            self._replicated)
-        self._step_counter += k
-        data_s = 0.0
-        if track:
-            t1 = time.perf_counter()
-            data_s = t1 - t0
-            if self.profiler is not None:
-                self.profiler.add_data(data_s)
-            t0 = t1
-        # same collective-scope fault point as the streamed path: one
-        # hit per DISPATCH (K microsteps), still rank-deterministic
-        fault.fault_point("collective")
-        # flight-recorder entry: one per K-step chunk dispatch, same
-        # contract as update()'s (in-flight across the guard readback)
-        fin = None
-        with self._flight_record(
-                "train_chunk",
-                ("train_chunk", k, tuple(chunk.data.shape)),
-                kind="train",
-                name=f"train_chunk@K{k}b{chunk.data.shape[1]}",
-                shape=chunk.data.shape, nbytes=chunk.data.nbytes,
-                donated=1, bucket=chunk.data.shape[1],
-                fields={"steps": k}):
-            self.state, losses, finites = self._train_chunk(
-                self.state, chunk.data, chunk.extras, chunk.labels,
-                chunk.mask, step_idx, base_rng)
-            if self._check_nan_built:
-                # ONE readback per chunk (vs one per step streamed) -
-                # the whole point of the fused dispatch; the guard then
-                # walks the per-microstep flags in order, so drop
-                # counts and consecutive-failure accounting match
-                # streaming exactly
-                # graftlint: disable=GL002 ONE guard readback per K-step chunk - the fused dispatch's whole point
-                fin = np.asarray(distributed.fetch_local(finites))
-        if fin is not None:
-            for i in range(k):
-                self._guard_step(bool(fin[i]), first_step + i)
-        self.epoch = self._epoch_base + (
-            (self._step_counter - self._skipped_steps)
-            // self.update_period)
-        # K dispatched microsteps of progress (same beacon the
-        # streamed path marks - the watchdog is dispatch-mode-blind)
-        telemetry.beacon("train.step", k)
-        if track:
-            # graftlint: disable=GL002 honest per-chunk timing requires the sync - profile/telemetry_steps opt-in only
-            jax.block_until_ready(self.state["epoch"])
-            chunk_s = time.perf_counter() - t0
-            n_examples = sum(chunk.n_examples)
-            if self.profiler is not None:
-                self.profiler.add_chunk(chunk_s, k, n_examples)
-            if self._tel_steps:
-                tel = telemetry.get()
-                # graftlint: disable=GL002 per-chunk loss readback, gated by telemetry_steps=1
-                loss_v = np.asarray(distributed.fetch_local(losses),
-                                    np.float64)
-                per_s = chunk_s / k
-                for _ in range(k):
-                    # per-step amortized cost: keeps the registry's
-                    # windowed p50/p99 on a per-STEP scale, comparable
-                    # across steps_per_dispatch settings (data_s too -
-                    # a non-prefetched chunk stages all K batches here,
-                    # and a per-chunk sample would read as a Kx staging
-                    # regression next to a K=1 run)
-                    tel.observe("train.step_s", per_s)
-                    tel.observe("train.data_s", data_s / k)
-                tel.inc("train.images", n_examples)
-                tel.set_gauge("train.loss", float(loss_v[-1]))
-                tel.event("span", name="train.data", secs=data_s,
-                          round=self.round, step=first_step)
-                tel.event("span", name="train.chunk", secs=chunk_s,
-                          round=self.round, step=first_step, steps=k,
-                          loss=[float(v) for v in loss_v],
-                          examples=n_examples)
+        with StepTraceAnnotation(spans.TRAIN,
+                                 step_num=self._step_counter):
+            track = bool(self.profile) or self._tel_steps
+            t0 = time.perf_counter() if track else 0.0
+            if not isinstance(chunk, StagedChunk):
+                # staging validates; a rejected batch must raise BEFORE
+                # the step counter moves (same contract as update())
+                with TraceAnnotation(spans.TRAIN_STAGE):
+                    chunk = self.stage_chunk(chunk)
+            k = chunk.n_steps
+            first_step = self._step_counter
+            with TraceAnnotation(spans.TRAIN_KEY):
+                base_rng = jax.random.PRNGKey(self.seed + 100)
+                step_idx = distributed.put_global(
+                    np.arange(first_step, first_step + k, dtype=np.int32),
+                    self._replicated)
+            self._step_counter += k
+            data_s = 0.0
+            if track:
+                t1 = time.perf_counter()
+                data_s = t1 - t0
+                if self.profiler is not None:
+                    self.profiler.add_data(data_s)
+                t0 = t1
+            # same collective-scope fault point as the streamed path: one
+            # hit per DISPATCH (K microsteps), still rank-deterministic
+            fault.fault_point("collective")
+            # flight-recorder entry: one per K-step chunk dispatch, same
+            # contract as update()'s (in-flight across the guard readback)
+            fin = None
+            with self._flight_record(
+                    "train_chunk",
+                    ("train_chunk", k, tuple(chunk.data.shape)),
+                    kind="train",
+                    name=f"train_chunk@K{k}b{chunk.data.shape[1]}",
+                    shape=chunk.data.shape, nbytes=chunk.data.nbytes,
+                    donated=1, bucket=chunk.data.shape[1],
+                    fields={"step": first_step, "steps": k}):
+                with TraceAnnotation(spans.TRAIN_CALL):
+                    self.state, losses, finites = self._train_chunk(
+                        self.state, chunk.data, chunk.extras,
+                        chunk.labels, chunk.mask, step_idx, base_rng)
+                if self._check_nan_built:
+                    # ONE readback per chunk (vs one per step streamed) -
+                    # the whole point of the fused dispatch; the guard then
+                    # walks the per-microstep flags in order, so drop
+                    # counts and consecutive-failure accounting match
+                    # streaming exactly
+                    with TraceAnnotation(spans.TRAIN_GUARD):
+                        # graftlint: disable=GL002 ONE guard readback per K-step chunk - the fused dispatch's whole point
+                        fin = np.asarray(distributed.fetch_local(finites))
+            if fin is not None:
+                for i in range(k):
+                    self._guard_step(bool(fin[i]), first_step + i)
+            self.epoch = self._epoch_base + (
+                (self._step_counter - self._skipped_steps)
+                // self.update_period)
+            # K dispatched microsteps of progress (same beacon the
+            # streamed path marks - the watchdog is dispatch-mode-blind)
+            telemetry.beacon("train.step", k)
+            if track:
+                # graftlint: disable=GL002 honest per-chunk timing requires the sync - profile/telemetry_steps opt-in only
+                jax.block_until_ready(self.state["epoch"])
+                chunk_s = time.perf_counter() - t0
+                n_examples = sum(chunk.n_examples)
+                if self.profiler is not None:
+                    self.profiler.add_chunk(chunk_s, k, n_examples)
+                if self._tel_steps:
+                    tel = telemetry.get()
+                    # graftlint: disable=GL002 per-chunk loss readback, gated by telemetry_steps=1
+                    loss_v = np.asarray(distributed.fetch_local(losses),
+                                        np.float64)
+                    per_s = chunk_s / k
+                    for _ in range(k):
+                        # per-step amortized cost: keeps the registry's
+                        # windowed p50/p99 on a per-STEP scale, comparable
+                        # across steps_per_dispatch settings (data_s too -
+                        # a non-prefetched chunk stages all K batches here,
+                        # and a per-chunk sample would read as a Kx staging
+                        # regression next to a K=1 run)
+                        tel.observe("train.step_s", per_s)
+                        tel.observe("train.data_s", data_s / k)
+                    tel.inc("train.images", n_examples)
+                    tel.set_gauge("train.loss", float(loss_v[-1]))
+                    tel.event("span", name="train.data", secs=data_s,
+                              round=self.round, step=first_step)
+                    tel.event("span", name="train.chunk", secs=chunk_s,
+                              round=self.round, step=first_step, steps=k,
+                              loss=[float(v) for v in loss_v],
+                              examples=n_examples)
 
     def _guard_step(self, ok: bool, step_idx: int) -> None:
         """Host half of the divergence guard: count dropped steps and
@@ -2315,7 +2347,7 @@ class NetTrainer:
                 step += 1
                 labels = self._label_fields(label.astype(np.float32))
                 gdata = self._put_data(data)
-                with self._flight_record(
+                with TraceAnnotation(spans.EVAL_STEP), self._flight_record(
                         "eval_metric",
                         ("eval_metric", tuple(gdata.shape)),
                         kind="eval",
@@ -2357,7 +2389,8 @@ class NetTrainer:
         data_iter.before_first()
         while data_iter.next():
             batch = data_iter.value()
-            nodes = self._forward_nodes(batch)
+            with TraceAnnotation(spans.EVAL_STEP):
+                nodes = self._forward_nodes(batch)
             nvalid = batch.batch_size - batch.num_batch_padd
             labels = self._label_fields(
                 batch.label.astype(np.float32)[:nvalid])

@@ -114,6 +114,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from cxxnet_tpu import telemetry
+from cxxnet_tpu.telemetry import spans
 from cxxnet_tpu.telemetry.flight import fingerprint as exec_fingerprint
 from cxxnet_tpu.utils import fault
 
@@ -1076,6 +1077,7 @@ class Server:
             return items
 
     def _run_batch(self, items: List[_WorkItem]) -> None:
+        from jax.profiler import TraceAnnotation
         from cxxnet_tpu.parallel import distributed
         total = sum(it.n for it in items)
         bucket = next(b for b in self.buckets if b >= total)
@@ -1142,9 +1144,11 @@ class Server:
                 with self._lock:
                     self._n_canary_req += routed
                 telemetry.inc("serve.canary_requests", routed)
-            gdata, gextras = self.trainer.stage_infer_rows(data, extras)
-            out = fn(params, gdata, gextras)
-            rows = distributed.fetch_local(out)
+            with TraceAnnotation(spans.SERVE_BATCH, bucket=bucket):
+                gdata, gextras = self.trainer.stage_infer_rows(data,
+                                                               extras)
+                out = fn(params, gdata, gextras)
+                rows = distributed.fetch_local(out)
         except BaseException as e:
             # a FAILED dispatch must not read as a hung one: the
             # replica recovers and keeps serving, so close the flight

@@ -43,15 +43,14 @@ from cxxnet_tpu.telemetry.flight import (
 from cxxnet_tpu.telemetry.health import HealthState
 from cxxnet_tpu.telemetry.registry import (
     BucketHistogram, Counter, Gauge, Histogram, MetricsRegistry)
-from cxxnet_tpu.telemetry.sink import LineSink, read_jsonl
+from cxxnet_tpu.telemetry.sink import LineSink
 
 __all__ = [
     "Telemetry", "Counter", "Gauge", "Histogram", "BucketHistogram",
     "MetricsRegistry", "FlightRecorder", "ExecutableRegistry",
-    "HealthState", "LineSink", "read_jsonl", "get", "configure",
-    "close", "enabled", "metrics_enabled", "counter", "gauge",
-    "histogram", "inc", "set_gauge", "observe", "span", "event",
-    "emit_metrics", "stdout", "stderr", "set_tags", "beacon",
+    "HealthState", "LineSink", "get", "configure", "close", "enabled",
+    "counter", "histogram", "inc", "set_gauge", "observe", "span",
+    "event", "emit_metrics", "stdout", "stderr", "set_tags", "beacon",
     "beacons", "recent_spans", "flight", "executables",
     "arm_observability", "disarm_observability", "health",
     "reset_for_tests",
@@ -321,16 +320,9 @@ class Telemetry:
         return (self._log is not None or self._metrics is not None
                 or self._http is not None)
 
-    @property
-    def metrics_enabled(self) -> bool:
-        return self._metrics is not None
-
     # -- registry sugar ----------------------------------------------------
     def counter(self, name: str) -> Counter:
         return self.registry.counter(name)
-
-    def gauge(self, name: str) -> Gauge:
-        return self.registry.gauge(name)
 
     def histogram(self, name: str) -> Histogram:
         return self.registry.histogram(name)
@@ -501,16 +493,8 @@ def enabled() -> bool:
     return _TEL.enabled
 
 
-def metrics_enabled() -> bool:
-    return _TEL.metrics_enabled
-
-
 def counter(name: str) -> Counter:
     return _TEL.counter(name)
-
-
-def gauge(name: str) -> Gauge:
-    return _TEL.gauge(name)
 
 
 def histogram(name: str) -> Histogram:
