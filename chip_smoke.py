@@ -288,6 +288,11 @@ def train_leg(leg: str, cfg: dict, extra, clog: CompileLog,
     check((n_fwd, n_bwd) == (2, 2),
           f"train step traces the Pallas LRN kernel {n_fwd}x forward, "
           f"{n_bwd}x backward")
+    routes = [r for r in ("route.pallas", "route.sharded", "route.xla")
+              if f"/{r}/" in hlo]
+    check(routes == ["route.sharded" if tr.mesh.devices.size > 1
+                     else "route.pallas"],
+          f"the step's text names the one LRN route taken: {routes}")
     if not dry:
         n_cc = hlo.count("tpu_custom_call")
         check(n_cc >= 4,
@@ -296,7 +301,7 @@ def train_leg(leg: str, cfg: dict, extra, clog: CompileLog,
                 hlo=hlo, staged=sb)
 
 
-def serve_leg(cfg: dict, clog: CompileLog) -> None:
+def serve_leg(cfg: dict, clog: CompileLog, dry: bool) -> None:
     """task=pred, then task=serve, on the train leg's checkpoint."""
     from cxxnet_tpu import telemetry
     with open(_CONF) as f:
@@ -324,9 +329,22 @@ def serve_leg(cfg: dict, clog: CompileLog) -> None:
     check(len(pred) == cfg["n_eval"] and len(served) == cfg["n_eval"],
           f"one prediction per eval row in both files ({len(pred)}, "
           f"{len(served)} of {cfg['n_eval']})")
+    # Same rows in the same order. Identical on the host in float32
+    # (--dry-run). On the chip a row's activations depend on the batch
+    # it is computed in: XLA's convs round differently at 256 rows and
+    # at 8 (conv1's output already differs by an ulp of bf16, before
+    # and after PR 28 alike, PERF.md section 6), and this checkpoint's
+    # classes are nearly tied, so a few rows may fall the other way.
+    # The LRN kernels add nothing to that (kernel leg, "8 rows alone").
     diff = [i for i, (a, b) in enumerate(zip(pred, served)) if a != b]
-    check(not diff, f"task=serve output identical to task=pred "
-                    f"(rows that differ: {diff[:8]})")
+    if dry:
+        check(not diff, f"task=serve output identical to task=pred "
+                        f"(rows that differ: {diff[:8]})")
+    else:
+        check(len(diff) <= len(pred) // 20,
+              f"task=serve agrees with task=pred on all rows but "
+              f"near-ties: {len(diff)} of {len(pred)} differ "
+              f"({diff[:8]})")
     say(f"  predicted classes: {sorted(set(pred))[:12]}")
 
     hit = {e["name"]: e["dispatches"] - before.get(e["fingerprint"], 0)
@@ -393,8 +411,14 @@ def kernel_leg(dry: bool) -> None:
     # in/out isolates the kernel's own arithmetic at the CPU test's
     # tolerance (tests/test_pallas_lrn.py).
     hyper = (5, 0.001, 0.75, 1.0)
-    shapes = ([(4, 16, 5, 5), (2, 32, 3, 3)] if dry else
-              [(256, 96, 27, 27), (256, 256, 13, 13)])
+    # AlexNet.conf's batch and the benchmark's (alexnet.train_resident)
+    # go batch on lanes, Server buckets 1 and 8 positions on lanes, the
+    # 5x5 and 3x3 stand-ins ragged lanes (ops/pallas_lrn.py _plan)
+    shapes = ([(4, 16, 5, 5), (2, 32, 3, 3), (128, 16, 3, 3)] if dry else
+              [(256, 96, 27, 27), (256, 256, 13, 13),
+               (2048, 96, 27, 27), (2048, 256, 13, 13),
+               (1, 96, 27, 27), (8, 96, 27, 27),
+               (1, 256, 13, 13), (8, 256, 13, 13)])
     for shp in shapes:
         for dt, rt, at, grt, gat in ((jnp.bfloat16, 8e-3, 8e-3, 2e-2,
                                       2e-2),
@@ -403,16 +427,27 @@ def kernel_leg(dry: bool) -> None:
             x = jnp.asarray(rng.randn(*shp), dt)
             g = jnp.asarray(rng.randn(*shp), jnp.float32)
             x32 = x.astype(jnp.float32)
+            # (g is an argument: closed over, its half gigabyte at
+            # the benchmark's shapes would be compiled into the program)
             fk = jax.jit(lambda x: PL.lrn_pallas(x, *hyper, interp))
-            gk = jax.jit(jax.grad(lambda x: jnp.sum(
+            gk = jax.jit(jax.grad(lambda x, g: jnp.sum(
                 PL.lrn_pallas(x, *hyper, interp).astype(jnp.float32)
                 * g)))
             fr = jax.jit(lambda x: lrn_xla(x, *hyper))
             gr = jax.jit(jax.grad(
-                lambda x: jnp.sum(lrn_xla(x, *hyper) * g)))
+                lambda x, g: jnp.sum(lrn_xla(x, *hyper) * g)))
             tag = f"lrn {shp} {jnp.dtype(dt).name}"
-            close(tag + " fwd", fk(x), fr(x32), rt, at)
-            close(tag + " grad", gk(x), gr(x32), grt, gat)
+            y = fk(x)
+            close(tag + " fwd", y, fr(x32), rt, at)
+            close(tag + " grad", gk(x, g), gr(x32, g), grt, gat)
+            if shp[0] % 128 == 0:
+                # what task=serve == task=pred rests on: a bucket of 8
+                # rows reads positions on lanes, the batch they came
+                # from batch on lanes, and a row comes out the same
+                check(np.array_equal(
+                    np.asarray(y[:8], np.float32),
+                    np.asarray(fk(x[:8]), np.float32)),
+                    tag + ": 8 rows alone == the same rows in the batch")
 
     # -- flash attention, bf16, forward + all three grads, causal and
     # not, against naive_attention in f32 at "highest" matmul
@@ -593,7 +628,7 @@ def main(argv) -> int:
         f"{'native (libcxxnet_io.so)' if native_available() else 'PIL'}")
     one_losses = res["losses"]
     del res
-    serve_leg(cfg, clog)
+    serve_leg(cfg, clog, dry)
     gc.collect()
     kernel_leg(dry)
     status = {"train": "pass", "serve": "pass", "kernel": "pass"}

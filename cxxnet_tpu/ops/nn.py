@@ -70,17 +70,23 @@ def lrn(x, local_size: int, alpha: float, beta: float, knorm: float):
     out = x * (knorm + alpha/n * sum_{window n}(x^2)) ^ (-beta)
     (lrn_layer-inl.hpp:36-56: tmp_norm = chpool<sum>(x^2) * (alpha/n) + knorm,
     out = x * tmp_norm^(-beta)). Routed by the mesh the step runs over
-    (parallel/mesh.py active_device_span): the Pallas kernel on one
-    device, its shard_map route over a 'data' axis, else lrn_xla.
+    (parallel/mesh.py active_device_span) and the channel count
+    (ops/pallas_lrn.py _tile_ok): the Pallas kernel on one device, its
+    shard_map route over a 'data' axis, else lrn_xla.
     """
     from cxxnet_tpu.ops import pallas_lrn as pk
+    # the scope says, in the step's text and in any trace, which route
+    # this layer took
     if pk.use_pallas_lrn(x):
-        return pk.lrn_pallas(x, local_size, alpha, beta, knorm,
-                             pk._FORCE_INTERPRET)
+        with jax.named_scope("route.pallas"):
+            return pk.lrn_pallas(x, local_size, alpha, beta, knorm,
+                                 pk._FORCE_INTERPRET)
     from cxxnet_tpu.parallel.mesh import get_active_mesh
     mesh = get_active_mesh()
     if mesh is not None and mesh.devices.size > 1 \
             and pk.use_pallas_lrn_sharded(x, mesh):
-        return pk.lrn_pallas_sharded(x, mesh, local_size, alpha, beta,
-                                     knorm)
-    return lrn_xla(x, local_size, alpha, beta, knorm)
+        with jax.named_scope("route.sharded"):
+            return pk.lrn_pallas_sharded(x, mesh, local_size, alpha, beta,
+                                         knorm)
+    with jax.named_scope("route.xla"):
+        return lrn_xla(x, local_size, alpha, beta, knorm)

@@ -292,6 +292,9 @@ def test_chip_smoke_dry_run_rehearses_every_leg(tmp_path):
                                "kernel": "pass", "four": "pass"}
     for proof in ("no compile in round 2",
                   "traces the Pallas LRN kernel 2x forward, 2x backward",
+                  "names the one LRN route taken: ['route.pallas']",
+                  "names the one LRN route taken: ['route.sharded']",
+                  "8 rows alone == the same rows in the batch",
                   "task=serve output identical to task=pred",
                   "no compile after warmup()",
                   "LRN takes the shard_map route",
