@@ -19,6 +19,12 @@ from a seed into the output directory:
   kernel   each Pallas kernel (LRN, flash attention, int8 matmul)
            compiled with interpret=False at shapes its layer uses and
            compared with its float32 jax.numpy reference.
+  lm       one `kda` layer at the Kimi conf's widths through both
+           routes of its chunk-local part (the Pallas kernel pair the
+           chip takes, the XLA code everything else takes): outputs
+           and gradients agree; then task=train over
+           examples/LongSeq/kimi_linear_5l.conf, three steps, a
+           falling loss.
   four     with >= 4 devices: the train leg again on `dev = tpu:0-3` -
            mesh of 4, shards on 4 distinct devices, memory in use on
            all 4, gradient all-reduce + shard_map LRN in the step,
@@ -536,6 +542,60 @@ def _lm_tiny() -> dict:
         return dict(json.load(f)["dry_run_overrides"], eta="0.01")
 
 
+def kda_routes(dry: bool) -> None:
+    """One `kda` layer at the Kimi conf's widths (2304 wide, 32 heads of
+    128, chunks of 64) over 1,024 positions in bf16, forward and the
+    gradients of every parameter and of the input, through the route
+    the chip takes (ops/pallas_kda.py's kernel pair, seen in the
+    lowered text) and through the XLA route every other case takes
+    (`ops.kda._backend_ok` held false). Both compute in float32 and
+    round the same operands to bf16, at slightly different points: the
+    flash kernel's tolerance of the kernel leg."""
+    import jax
+    import jax.numpy as jnp
+    from cxxnet_tpu.layers import create_layer
+    from cxxnet_tpu.ops import kda as KD
+    e, nh, t = (32, 1, 128) if dry else (2304, 32, 1024)
+    shape = (1, 1, t, e)
+    lay = create_layer("kda", "k")
+    for k, v in (("nhead", nh), ("head_dim", 128), ("gate_rank", 128),
+                 ("init_sigma", 0.02)):
+        lay.set_param(k, str(v))
+    lay.infer_shapes([shape])
+    p = lay.init_params(jax.random.PRNGKey(0), [shape])
+    x = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.bfloat16)
+    w = jax.random.normal(jax.random.PRNGKey(2), shape, jnp.float32)
+
+    def loss(p, x):
+        pc = jax.tree.map(lambda a: a.astype(jnp.bfloat16), p)
+        (y,) = lay.apply(pc, [x], train=True)
+        return jnp.sum(y.astype(jnp.float32) * w), y
+
+    def run(kernels: bool):
+        ok = KD._backend_ok
+        if not kernels:
+            KD._backend_ok = lambda: False
+        try:
+            fn = jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                            has_aux=True))
+            text = fn.lower(p, x).as_text(debug_info=True)
+            (_, y), (gp, gx) = fn(p, x)
+        finally:
+            KD._backend_ok = ok
+        return text, y, dict(gp, x=gx)
+
+    text_k, y_k, g_k = run(True)
+    text_x, y_x, g_x = run(False)
+    check("route.pallas" in text_k and "route.xla" not in text_k,
+          "the kda layer takes the kernel route here")
+    check("route.xla" in text_x and "route.pallas" not in text_x,
+          "with the backend test held false it takes the XLA route")
+    tag = f"kda {t} positions x {nh} heads x 128, kernel route v XLA route"
+    close(f"{tag}: out", y_k, y_x, 2e-2, 2e-2)
+    for name in sorted(g_x):
+        close(f"{tag}: d{name}", g_k[name], g_x[name], 2e-2, 2e-2)
+
+
 def lm_leg(out: str, dry: bool) -> None:
     """task=train over examples/LongSeq/kimi_linear_5l.conf as
     committed (the five Kimi-Linear layers at their published widths,
@@ -546,6 +606,7 @@ def lm_leg(out: str, dry: bool) -> None:
     point."""
     import jax
     from cxxnet_tpu.utils.config import parse_config_string
+    kda_routes(dry)
     d = os.path.join(out, "lm")
     shutil.rmtree(d, ignore_errors=True)
     os.makedirs(d)
@@ -658,10 +719,11 @@ def main(argv) -> int:
         f"{'DRY RUN (never a chip pass)' if dry else 'chip run'}")
     if dry:
         from cxxnet_tpu.ops import int8 as I8
+        from cxxnet_tpu.ops import kda as KD
         from cxxnet_tpu.ops import pallas_attention as PA
         from cxxnet_tpu.ops import pallas_lrn as PL
         PL._FORCE_INTERPRET = PA._FORCE_INTERPRET = True
-        I8._FORCE_INTERPRET = True
+        I8._FORCE_INTERPRET = KD._FORCE_INTERPRET = True
 
     out = os.path.abspath(args.out)
     data_dir = os.path.join(out, "data")

@@ -33,6 +33,19 @@ differences are taken pair by pair (SUB x SUB x d_k terms).
 The decay g, its sums, every exp and the triangular solve are float32;
 the matrix products take their operands in the activations' dtype
 (bf16 in a bf16 step) and accumulate in float32.
+
+What runs where. The chunk-local part - G, A and P, all a chunk
+computes from its own q, k, g and beta - has two routes, chosen by
+what `kda_chunked` can see and by no key (`_kernel_route`): the two
+Pallas kernels of ops/pallas_kda.py on a TPU, where the traced step
+spans one device (no mesh axis over heads or sequence), the chunk is
+64 and d_k and d_v are multiples of 128; `local_xla` below everywhere
+else - the CPU, a mesh, other shapes - which is also what the kernels
+are compared against. The same sub-blocks and precisions on both; on
+the kernel route the SUB x SUB x d_k pairwise tensors live in VMEM and
+never in HBM. `route.pallas` / `route.xla` in the step's text says
+which ran. The solve, W, U0 and the scan over chunks are XLA on either
+route.
 """
 
 from __future__ import annotations
@@ -86,6 +99,58 @@ def _pair_matrices(q, k, g_cum, dt):
     return kk.reshape(lead + (c, c)), qk.reshape(lead + (c, c))
 
 
+def local_xla(q, k, g, beta):
+    """The chunk-local part in plain XLA ops: the CPU route, and what
+    the Pallas kernels (ops/pallas_kda.py) are compared against (tests,
+    chip_smoke.py). q, k, g (..., C, d), beta (..., C, 1), g and beta
+    float32 -> A (beta applied, zero from the diagonal up), P (zero
+    above the diagonal), both (..., C, C), and G (..., C, d), float32."""
+    chunk = k.shape[-2]
+    # the running sum of g inside a chunk, as a product with a lower
+    # triangle of ones at full float32 precision (`jnp.cumsum` is a
+    # `reduce_window` on the chip: 8 ms a layer and direction at 8,192
+    # positions, against 0.3 for this)
+    g_cum = jnp.einsum(
+        "ti,...id->...td", jnp.tril(jnp.ones((chunk, chunk), jnp.float32)),
+        g, precision=lax.Precision.HIGHEST)
+    kk, qk = _pair_matrices(q, k, g_cum, k.dtype)
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    a = jnp.where(strict, beta * kk, 0.0)
+    p = jnp.where(strict | jnp.eye(chunk, dtype=bool), qk, 0.0)
+    return a, p, g_cum
+
+
+# test hook: take the kernel route off the TPU, the kernels in interpret
+# mode
+_FORCE_INTERPRET = False
+
+_KERNEL_CHUNK = 64      # the chunk ops/pallas_kda.py's blocks are sized for
+_LANES = 128
+
+
+def _backend_ok() -> bool:
+    return jax.default_backend() == "tpu" or _FORCE_INTERPRET
+
+
+def _kernel_route(d_k: int, d_v: int, chunk: int):
+    """ops/pallas_kda.py where its kernels run, else None: the chunk
+    they are sized for and whole lane tiles of channels, a TPU, and a
+    traced step that spans one device (parallel/mesh.py
+    active_device_span: pallas_call has no GSPMD partitioning rule).
+    The module, and Pallas with it (1.3-2 s of every process that
+    imports it), is imported only once all of that holds: a net
+    without a `kda` layer, or one off the TPU, never pays for it."""
+    if chunk != _KERNEL_CHUNK or d_k % _LANES or d_v % _LANES:
+        return None
+    if not _backend_ok():
+        return None
+    from cxxnet_tpu.parallel.mesh import active_device_span
+    if active_device_span() != 1:
+        return None
+    from cxxnet_tpu.ops import pallas_kda
+    return pallas_kda
+
+
 def kda_chunked(q, k, v, g, beta, chunk: int = 64):
     """q, k, g (b, T, H, d_k); v (b, T, H, d_v); beta (b, T, H);
     g = log alpha <= 0 in float32 -> o (b, T, H, d_v) in q's dtype.
@@ -105,19 +170,14 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64):
         return jnp.moveaxis(a.reshape(b, n, chunk, h, a.shape[-1]), 3, 1)
 
     q, k, v = chunks(q), chunks(k), chunks(v)
-    # the running sum of g inside a chunk, as a product with a lower
-    # triangle of ones at full float32 precision (`jnp.cumsum` is a
-    # `reduce_window` on the chip: 8 ms a layer and direction at 8,192
-    # positions, against 0.3 for this)
-    g_cum = jnp.einsum(
-        "ti,...id->...td", jnp.tril(jnp.ones((chunk, chunk), jnp.float32)),
-        chunks(g.astype(jnp.float32)), precision=lax.Precision.HIGHEST)
+    g = chunks(g.astype(jnp.float32))
     beta = chunks(beta.astype(jnp.float32)[..., None])      # (b,H,n,C,1)
-
-    kk, qk = _pair_matrices(q, k, g_cum, dt)
-    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-    a = jnp.where(strict, beta * kk, 0.0)
-    p = jnp.where(strict | jnp.eye(chunk, dtype=bool), qk, 0.0)
+    # the scope says, in the step's text and in any trace, which route
+    # the chunk-local part took
+    pk = _kernel_route(dk, dv, chunk)
+    with jax.named_scope("route.pallas" if pk else "route.xla"):
+        a, p, g_cum = (pk.local_pallas(q, k, g, beta, _FORCE_INTERPRET)
+                       if pk else local_xla(q, k, g, beta))
     eye = jnp.broadcast_to(jnp.eye(chunk, dtype=jnp.float32), a.shape)
     t_inv = lax.linalg.triangular_solve(
         a + eye, eye, left_side=True, lower=True, unit_diagonal=True)

@@ -88,18 +88,25 @@ def test_flash_kernels_compile_for_the_chip_at_mla_head_size(one_chip):
         assert name in text
 
 
-def test_kda_chunk_scan_compiles_for_the_chip_at_the_cells_size(one_chip):
+@pytest.mark.parametrize("route,dtype", [
+    ("xla", "bfloat16"), ("pallas", "bfloat16"), ("pallas", "float32")])
+def test_kda_chunk_scan_compiles_for_the_chip_at_the_cells_size(
+        one_chip, monkeypatch, route, dtype):
     """`ops/kda.py` at the Kimi cell's sizes (8,192 positions, 32 heads
-    of 128, chunks of 64), forward and gradients of all five inputs:
-    plain XLA, so what could be refused is its memory (the pairwise
-    decay tensors are 2.1 GB if ever written out) and the triangular
-    solve."""
+    of 128, chunks of 64), forward and gradients of all five inputs.
+    `route.xla`: what could be refused is its memory (the pairwise
+    decay tensors are 2.1 GB each when written out) and the triangular
+    solve. `route.pallas` (what the chip takes; `ops.kda._backend_ok`
+    is patched, since the process sees the CPU): the two kernels of
+    ops/pallas_kda.py lower for the chip, and the temporaries shrink
+    to a fraction."""
     from cxxnet_tpu.ops import kda
+    monkeypatch.setattr(kda, "_backend_ok", lambda: route == "pallas")
 
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    x = sds((1, 8192, 32, 128), jnp.bfloat16)
+    x = sds((1, 8192, 32, 128), jnp.dtype(dtype))
     g = sds((1, 8192, 32, 128), jnp.float32)
     beta = sds((1, 8192, 32), jnp.float32)
 
@@ -109,6 +116,13 @@ def test_kda_chunk_scan_compiles_for_the_chip_at_the_cells_size(one_chip):
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         x, x, x, g, beta).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 6 << 30
-    assert "triangular" in compiled.as_text().lower() \
-        or "custom-call" in compiled.as_text()
+    text = compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert "triangular" in text.lower() or "custom-call" in text
+    if route == "pallas":
+        assert "route.pallas" in text and "route.xla" not in text
+        assert "kda_local_fwd" in text and "kda_local_bwd" in text
+        assert temp < 3 << 30
+    else:
+        assert "route.xla" in text and "kda_local" not in text
+        assert temp < 6 << 30
