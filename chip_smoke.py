@@ -505,9 +505,9 @@ def kernel_leg(dry: bool) -> None:
           "seq_mnist's shape is outside the flash kernel's own tile "
           "rule (its layer takes the blockwise XLA route)")
 
-    # -- int8 matmul: AlexNet fc7 at a serving batch and the 2048-wide
-    # fullc the int8 bench uses; int32 accumulation is exact, so the
-    # kernel must EQUAL lax.dot_general (tests/test_quantize.py)
+    # -- int8 matmul: AlexNet fc7 at a serving batch and a 2048-wide
+    # fullc; int32 accumulation is exact, so the kernel must EQUAL
+    # lax.dot_general (tests/test_quantize.py)
     for m, kk, n in ([(32, 128, 128)] if dry else
                      [(32, 4096, 4096), (32, 2048, 2048)]):
         check(I8._pallas_blocks(m, kk, n) is not None,

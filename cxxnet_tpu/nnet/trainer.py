@@ -839,7 +839,8 @@ class NetTrainer:
         _cast do it on DEVICE, fused into the first conv - wins when
         the host CPU, not the link, is the staging bottleneck (an
         AlexNet b256 host cast is ~40M elements, tens of ms
-        single-threaded; bench.py measures both as e2e variants)."""
+        single-threaded; no benchmark cell streams input yet, so
+        neither side has a chip number)."""
         if self.net.integer_input:
             # token ids stay integers from the batch to the `embed`
             # layer: a bf16 cast cannot hold an id over 256

@@ -5,8 +5,7 @@ The reference augments every image on the HOST (image_augmenter-inl.hpp
 + the crop/mirror/mean pipeline of iter_img_proc). That is the right
 call for GPUs with idle host cores; on a TPU host where a single b256
 AlexNet batch costs tens of ms of numpy arithmetic per step, the host
-becomes the bottleneck while the MXU idles (bench.py's
-host_prep/device split measures exactly this). `device_augment = 1`
+becomes the bottleneck while the MXU idles. `device_augment = 1`
 moves the per-pixel work onto the device, TPU-style:
 
 - the iterator stages RAW decoded images (io/augment.py passthrough

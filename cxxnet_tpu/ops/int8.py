@@ -29,12 +29,13 @@ execution vocabulary of that pass:
   directly; no space-to-depth rewrite on the int8 path).
 
 Cost model (docs/PERFORMANCE.md): the int8 win is weight-bandwidth +
-MXU rate. Measured on XLA:CPU (bench.py `int8_over_fold`), the
-small-batch weight-bound serving regime wins ~1.4x on the bench's
-2048-wide fullc MLP at batch 16, while large batches (>= 64 rows)
-and CPU convolutions LOSE - which is exactly what the per-layer
-``layer_quant`` tuning axis exists to pin per platform
-(docs/GRAPH_PASSES.md "when int8 loses").
+MXU rate, so the small-batch weight-bound serving regime (a wide
+fullc at batch 16) is where it should pay, while large batches
+(>= 64 rows, compute-bound) and CPU convolutions are where it should
+not - which is what the per-layer ``layer_quant`` tuning axis exists
+to pin per platform (docs/GRAPH_PASSES.md "when int8 loses"). No
+benchmark cell holds the kernel yet: its rate on the chip is not
+measured.
 """
 
 from __future__ import annotations

@@ -43,8 +43,8 @@ from cxxnet_tpu.ops.attention import _scale
 
 _NEG = -1e30
 
-# default tile sizes, set by an on-chip sweep (tools/bench_attn, v5e,
-# b4 h8 s4096 d128 fwd+grads): (1024, 1024) runs 56.7 TFLOP/s
+# default tile sizes, set by an on-chip sweep (v5e, b4 h8 s4096 d128
+# fwd+grads): (1024, 1024) runs 56.7 TFLOP/s
 # non-causal = 4.04x the XLA blockwise path, where the old MXU-exact
 # (128, 128) managed only 0.93x - at 128 the (b, h, s/bq, s/bk) grid
 # is 32k programs whose per-program overhead dominates; 1024-tiles

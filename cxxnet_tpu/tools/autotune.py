@@ -79,7 +79,7 @@ seed = 11
 # bounded candidate grids: the cache is a default, not a proof - a
 # coarse grid that always finishes beats an exhaustive one that
 # blows the budget (per-cell step counts are sized from a timed
-# probe step, bench.py _warm_and_size style)
+# probe step)
 _K_GRID = (1, 2, 4)
 _PREFETCH_GRID = (0, 1, 2)
 _SERVE_GRID = (8, 16, 32)

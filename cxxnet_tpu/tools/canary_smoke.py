@@ -96,10 +96,7 @@ silent = 1
 
 
 def _run_cli(out_dir: str, *overrides: str) -> subprocess.CompletedProcess:
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
-                   + " --xla_cpu_use_thunk_runtime=false").strip())
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     return subprocess.run(
         [sys.executable, "-m", "cxxnet_tpu.main",
          os.path.join(out_dir, "canary_smoke.conf"), *overrides],

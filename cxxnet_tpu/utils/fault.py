@@ -12,8 +12,8 @@ builds durability from:
   total deadline.
 - a process-wide **fault-injection registry** driven by the
   ``CXXNET_FAULT`` env var (``point:mode@N`` specs) or the ``inject``
-  API, so tests and bench.py can kill / delay / corrupt named fault
-  points deterministically.
+  API, so tests and the smoke tools can kill / delay / corrupt named
+  fault points deterministically.
 - ``atomic_writer``: tmp-file + fsync + ``os.replace`` so a file either
   appears complete or not at all - a crash can leave a ``*.tmp`` but
   never a truncated final artifact.

@@ -7,8 +7,7 @@ cache goes where the environment says, a pruned mesh / a failed native
 build / a failed staging says so, and `chip_smoke.py` refuses to start
 without a TPU while its dry run rehearses every leg and can never
 print a chip pass. (Kernel routing by mesh size lives beside the
-kernels: test_pallas_lrn / test_pallas_attention / test_quantize;
-bench.py's exits in test_bench.)
+kernels: test_pallas_lrn / test_pallas_attention / test_quantize.)
 """
 
 import json

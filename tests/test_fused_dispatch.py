@@ -5,16 +5,14 @@ A fused chunk must reproduce K streamed updates - same RNG stream
 (folded on device from the same (seed, step_counter) pairs), same
 divergence-guard decisions, same on-device train-metric accumulator.
 
-Two rigor levels, split by XLA:CPU backend determinism: the default
-thunk runtime's codegen picks different float contractions per
-PROGRAM SHAPE (~1 ULP drift between the per-step executable and the
-fused scan of the same math - backend noise, not a property of the
-dispatch path). So the in-process tests assert trajectory equality to
-tight tolerance plus EXACT guard/metric/counter semantics, and the
-bitwise proof runs in subprocesses pinned to the legacy runtime
-(--xla_cpu_use_thunk_runtime=false), where both executables compile
-identically. The CI fused-smoke job (tools/fused_smoke.py) runs the
-same way.
+Two rigor levels, split by what XLA:CPU promises: it compiles a
+contraction per PROGRAM SHAPE, so the per-step executable and the
+fused scan of the same math may differ ~1 ULP - backend noise, not a
+property of the dispatch path. So the in-process tests assert
+trajectory equality to tight tolerance plus EXACT guard/metric/counter
+semantics, and the bitwise proof runs in subprocesses of their own on
+this MLP, where both executables compile the same contractions. The
+CI fused-smoke job (tools/fused_smoke.py) runs the same way.
 """
 
 import os
@@ -91,14 +89,13 @@ class ListIter:
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# the deterministic-codegen env for the bitwise subprocesses (see
-# module docstring): legacy CPU runtime + the suite's device count
+# the env of the bitwise subprocesses (see module docstring): the
+# suite's device count
 PARITY_ENV = dict(
     os.environ,
     JAX_PLATFORMS="cpu",
     PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""),
-    XLA_FLAGS="--xla_force_host_platform_device_count=8 "
-              "--xla_cpu_use_thunk_runtime=false")
+    XLA_FLAGS="--xla_force_host_platform_device_count=8")
 
 
 def params_of(t):
@@ -107,9 +104,9 @@ def params_of(t):
 
 def assert_traj_close(a, b, msg=""):
     """In-process equality bar: identical dtypes/shapes, values equal
-    to well under any training-visible scale (the residual is the
-    thunk runtime's per-program-shape contraction noise; the bitwise
-    bar lives in the legacy-runtime subprocess tests)."""
+    to well under any training-visible scale (the residual is
+    XLA:CPU's per-program-shape contraction noise; the bitwise bar
+    lives in the subprocess tests)."""
     for x, y in zip(a, b):
         assert x.dtype == y.dtype and x.shape == y.shape
         np.testing.assert_allclose(x, y, rtol=5e-6, atol=1e-7,
@@ -269,8 +266,8 @@ def test_prefetcher_chunk_restart_and_close():
 
 
 BITWISE_MATRIX_SCRIPT = r"""
-# Bitwise trajectory-equality matrix, run under the legacy XLA:CPU
-# runtime (see test module docstring). Raises on the first mismatch.
+# Bitwise trajectory-equality matrix (see test module docstring).
+# Raises on the first mismatch.
 import numpy as np, jax
 from cxxnet_tpu.io.data import DataBatch
 from cxxnet_tpu.nnet.trainer import NetTrainer
@@ -375,10 +372,10 @@ print("BITWISE-OK")
 
 
 def test_fused_trajectory_bitwise_exact():
-    """THE acceptance proof: under deterministic codegen the fused
-    trajectory is bit-for-bit the streamed one - K in {1,2,4}, grad
-    accumulation, NaN-guard mid-chunk, short final chunks, and
-    worker-assembled (prefetched) chunks."""
+    """THE acceptance proof: the fused trajectory is bit-for-bit the
+    streamed one - K in {1,2,4}, grad accumulation, NaN-guard
+    mid-chunk, short final chunks, and worker-assembled (prefetched)
+    chunks."""
     r = subprocess.run(
         [sys.executable, "-c", BITWISE_MATRIX_SCRIPT], env=PARITY_ENV,
         cwd=REPO, capture_output=True, text=True, timeout=560)

@@ -514,10 +514,7 @@ def test_checkpoint_bytes_and_resume_across_passes(tmp_path):
     write_synth_mnist(d, 96, 1, "test")
     with open(os.path.join(d, "t.conf"), "w") as f:
         f.write(CONF.format(d=d))
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
-                   + " --xla_cpu_use_thunk_runtime=false").strip())
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     passes_arg = "graph_passes=fold_conv_bn,dead_layer_elim"
     first = [] if direction == "off_then_on" else [passes_arg]
     second = [passes_arg] if direction == "off_then_on" else []

@@ -330,10 +330,7 @@ def test_resume_across_quant_flag_both_directions(tmp_path):
     write_synth_mnist(d, 96, 1, "test")
     with open(os.path.join(d, "t.conf"), "w") as f:
         f.write(CONF.format(d=d))
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
-                   + " --xla_cpu_use_thunk_runtime=false").strip())
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     passes_arg = ("graph_passes=fold_conv_bn,dead_layer_elim,"
                   "quantize_int8")
 

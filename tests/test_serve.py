@@ -1,15 +1,15 @@
 """Continuous-batching serving layer (serve/server.py, docs/SERVING.md).
 
-Acceptance story, at the two rigor levels the fused-dispatch and ZeRO
-suites use: in-process tests assert tight-tolerance parity with the
-batch-at-a-time predict path plus exact padding / admission / compile-
-count semantics on the default XLA:CPU thunk runtime (whose codegen
-drifts ~1 ULP per program shape - a bucket and the full predict batch
-are different shapes), and the BITWISE ragged-stream-vs-unbatched-
-predict matrix (incl. `mesh = data:4` and `zero_stage = 3` sharded
-params) runs in subprocesses pinned to the legacy runtime, where every
-program shape compiles the same contractions. Padding-row isolation
-(pad contents must never leak into real rows) is bitwise IN-process:
+Acceptance story: in-process tests assert tight-tolerance parity with
+the batch-at-a-time predict path plus exact padding / admission /
+compile-count semantics (XLA:CPU compiles a contraction per program
+shape, and a bucket and the full predict batch are different shapes,
+so answers may drift ~1 ULP between them), and the ragged-stream-vs-
+unbatched-predict matrix runs in subprocesses on the virtual 8-device
+platform: BITWISE on one device, a few ULP of float32 with equal
+argmax on every row on the sharded legs (`mesh = data:4`, and
+`zero_stage = 3` sharded params). Padding-row isolation (pad contents
+must never leak into real rows) is bitwise IN-process:
 both sides run the identical bucket executable.
 """
 
@@ -49,14 +49,12 @@ seed = 7
 """
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# bitwise legs: legacy XLA:CPU runtime (deterministic codegen across
-# program shapes - the PR 3 finding) on the virtual 8-device platform
+# parity legs run on the virtual 8-device platform
 PARITY_ENV = dict(
     os.environ,
     JAX_PLATFORMS="cpu",
     PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""),
-    XLA_FLAGS="--xla_force_host_platform_device_count=8 "
-              "--xla_cpu_use_thunk_runtime=false")
+    XLA_FLAGS="--xla_force_host_platform_device_count=8")
 
 
 def make_trainer(extra=""):
@@ -112,8 +110,8 @@ def test_serve_rejects_uninitialized_trainer():
 # ---------------------------------------------------------------------------
 def test_ragged_stream_matches_predict(trainer):
     """A ragged request stream through the server equals per-request
-    predict_dist (tight tolerance in-process; the bitwise version runs
-    in the pinned-runtime subprocess matrix below)."""
+    predict_dist (tight tolerance in-process; the single-device leg of
+    the subprocess matrix below is bitwise)."""
     rng = np.random.RandomState(3)
     sizes = [1, 3, 8, 2, 5, 7, 4, 6, 1, 2] * 2
     datas = [req(rng, s) for s in sizes]
@@ -484,9 +482,9 @@ def test_cli_serve_requires_pred_iterator(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# bitwise parity matrix: ragged serve == unbatched predict, pinned
-# legacy runtime (subprocess), incl. data-parallel mesh and ZeRO-3
-# sharded params consumed directly
+# parity matrix: ragged serve == unbatched predict (subprocess) -
+# bitwise on one device, a few ULP on the data-parallel mesh and on
+# ZeRO-3 sharded params consumed directly
 # ---------------------------------------------------------------------------
 _PARITY_SCRIPT = r"""
 import sys
@@ -525,29 +523,32 @@ stats = srv.stop()
 assert stats["errors"] == 0, stats
 assert srv.executable_cache_size() == n_warm, "steady-state recompile"
 dsize = tr.mesh.shape.get("data", 1)
-n_bitwise = 0
+ATOL = 4 * float(np.finfo(np.float32).eps)  # outputs are softmax rows
 for d, o in zip(datas, outs):
     ref = tr.predict_dist(DataBatch(
         data=d, label=np.zeros((d.shape[0], 1), np.float32)))
     bucket = next(b for b in srv.buckets if b >= d.shape[0])
-    if bucket // dsize >= 2 or dsize == 1:
-        # bitwise wherever the per-device row count is >= 2: at
-        # exactly 1 row/device XLA:CPU emits a gemv whose contraction
-        # differs ~1 ULP from the gemm every other shape uses (even
-        # on the legacy runtime) - a backend codegen artifact, not a
-        # serving-layer property (test_padding_rows_never_leak proves
-        # the layer itself adds zero numeric difference); the
-        # single-device leg covers EVERY bucket bitwise
-        n_bitwise += 1
+    if dsize == 1:
+        # one device: the bucket and the unbatched predict compile
+        # the same contraction, and every bucket answers bitwise
         assert np.array_equal(o, ref), (
             "bitwise mismatch for a %%d-row request (bucket %%d): "
             "max|d|=%%g" %% (d.shape[0], bucket, np.abs(o - ref).max()))
     else:
-        assert np.allclose(o, ref, rtol=0, atol=1e-6)
+        # sharded legs: XLA:CPU's only runtime compiles a contraction
+        # per program shape (a gemv at 1 row/device, gemms of other
+        # tilings above it), so a bucket's per-device slice and the
+        # reference's differ by an ULP or so - a backend codegen
+        # artifact, not a serving-layer property
+        # (test_padding_rows_never_leak proves the layer itself adds
+        # zero numeric difference). docs/SERVING.md "Numerics fine
+        # print": a few ULP of float32 and the same class on every row
+        assert np.allclose(o, ref, rtol=0, atol=ATOL), (
+            "mismatch for a %%d-row request (bucket %%d): max|d|=%%g"
+            %% (d.shape[0], bucket, np.abs(o - ref).max()))
         assert np.array_equal(np.argmax(o, 1), np.argmax(ref, 1))
-assert n_bitwise > 0
-print("SERVE_PARITY=OK buckets=%%s bitwise=%%d/%%d"
-      %% (list(srv.buckets), n_bitwise, len(datas)))
+print("SERVE_PARITY=OK buckets=%%s bitwise=%%s"
+      %% (list(srv.buckets), dsize == 1))
 """ % MLP_CFG
 
 

@@ -4,10 +4,9 @@ Trajectory equality vs the replicated stage-0 update is the
 acceptance proof, at the same two rigor levels the fused-dispatch
 suite uses (its module docstring has the full story): in-process
 tests assert tight-tolerance equality plus exact metric/counter/
-guard semantics on the default XLA:CPU thunk runtime (whose codegen
-drifts ~1 ULP per program shape), and the bitwise matrix runs in a
-subprocess pinned to the legacy runtime, where the replicated and
-zero-region executables compile identically.
+guard semantics, and the bitwise matrix runs in a subprocess of its
+own on the virtual 8-device platform, where the replicated and
+zero-region executables of this MLP compile the same contractions.
 
 The suite's virtual 8-device platform (conftest.py) makes
 `mesh = data:8` a real mesh, so the reduce-scatter / sharded update /
@@ -56,8 +55,7 @@ PARITY_ENV = dict(
     os.environ,
     JAX_PLATFORMS="cpu",
     PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""),
-    XLA_FLAGS="--xla_force_host_platform_device_count=8 "
-              "--xla_cpu_use_thunk_runtime=false")
+    XLA_FLAGS="--xla_force_host_platform_device_count=8")
 
 
 def make_trainer(extra=""):
@@ -488,10 +486,9 @@ print("ZERO-BITWISE-OK")
 
 
 def test_zero_trajectory_bitwise_exact():
-    """Under deterministic codegen the zero-stage trajectories are
-    bit-for-bit the replicated one - stages 1/2/3, grad accumulation,
-    fused chunks with a short tail, checkpoint byte equality, and
-    resume across stages."""
+    """The zero-stage trajectories are bit-for-bit the replicated
+    one - stages 1/2/3, grad accumulation, fused chunks with a short
+    tail, checkpoint byte equality, and resume across stages."""
     r = subprocess.run(
         [sys.executable, "-c", BITWISE_MATRIX_SCRIPT], env=PARITY_ENV,
         cwd=REPO, capture_output=True, text=True, timeout=560)
