@@ -104,7 +104,9 @@ class _TableLayer(Layer):
     vectors `bias`."""
 
     #: one checkpoint around this layer under `remat = 1`
-    #: (nnet/network.py): only its inputs are kept for the backward
+    #: (nnet/network.py): only its inputs are kept for the backward,
+    #: which runs the forward again. A kind says True where that buys
+    #: enough memory for the time (at 8,192 positions, docs/global.md)
     remat_worthy = False
 
     def table(self, in_shapes: List[Shape]) -> Table:
@@ -183,6 +185,8 @@ class RMSNormLayer(_TableLayer):
 @register_layer
 class GluFFNLayer(_TableLayer):
     type_name = "glu_ffn"
+    #: gate, up and their product, each nhidden wide: 0.45 GB unsaved
+    #: for a second forward of 5.5 ms, about 80 MB a ms
     remat_worthy = True
 
     def shapes(self, in_shapes):
@@ -215,6 +219,8 @@ def short_conv(x, w):
 @register_layer
 class KDALayer(_TableLayer):
     type_name = "kda"
+    #: the chunk maps of every head: 1.5 GB unsaved for a second forward
+    #: of 17-20 ms, about 80 MB a ms (what `remat` is there for)
     remat_worthy = True
 
     def __init__(self, name: str = ""):
@@ -302,7 +308,10 @@ class KDALayer(_TableLayer):
 @register_layer
 class MLALayer(_TableLayer):
     type_name = "mla"
-    remat_worthy = True
+    #: q, k, padded v, o, the latents and `lse`: 0.55 GB unsaved would
+    #: cost a second flash forward and the projections again, 21 ms,
+    #: about 25 MB a ms: kept, the backward uses what the forward left
+    remat_worthy = False
 
     def __init__(self, name: str = ""):
         super().__init__(name)

@@ -198,8 +198,12 @@ class MoELayer(Layer):
     """moe: top-k routed mixture-of-experts FFN on sequence nodes."""
 
     type_name = "moe"
-    #: one checkpoint around this layer under `remat = 1`
-    remat_worthy = True
+    #: no checkpoint around this layer under `remat = 1`
+    #: (nnet/network.py): `dropless_apply` keeps only its inputs either
+    #: way (its backward runs each tile's expert again), so a checkpoint
+    #: would leave 0.1 GB unsaved (shared expert, router) for a second
+    #: forward of 6.8 ms, about 15 MB a ms
+    remat_worthy = False
 
     def __init__(self, name: str = ""):
         super().__init__(name)

@@ -56,8 +56,10 @@ class Network:
         # (the trainer casts wholesale to its compute dtype)
         self.dtype_plan: Optional[Dict[int, jnp.dtype]] = None
         # `remat = 1` (trainer): one `jax.checkpoint` around each layer
-        # whose class says `remat_worthy` (kda, mla, glu_ffn, moe): the
-        # backward keeps that layer's inputs and recomputes the rest
+        # whose class says `remat_worthy` (kda, glu_ffn: the kinds whose
+        # second forward buys the most memory a millisecond): the
+        # backward keeps that layer's inputs and recomputes the rest.
+        # `checkpointed` lists them
         self.remat = False
 
         # node 0 is the data input; in_1..in_k are extra data
@@ -246,6 +248,18 @@ class Network:
 
         return values, total_loss
 
+    def _checkpoints(self, layer) -> bool:
+        return self.remat and getattr(layer, "remat_worthy", False)
+
+    @property
+    def checkpointed(self) -> List[str]:
+        """The scopes (`<type>.<key>`, declaration order) of the layers
+        a training forward runs under a checkpoint; empty without
+        `remat`."""
+        return [layer_scope(self.cfg, i)
+                for i, layer in enumerate(self.layer_objs)
+                if self._checkpoints(layer)]
+
     def _run_layer(self, layer, p, xs, train, rng, mask):
         """(outputs, aux loss term or None, counters) of one layer,
         under one checkpoint where `remat` asks and the layer is worth
@@ -259,7 +273,7 @@ class Network:
                                             mask=mask) + ({},)
             return layer.apply(p, xs, train=train, rng=rng), None, {}
 
-        if self.remat and train and getattr(layer, "remat_worthy", False):
+        if train and self._checkpoints(layer):
             run = jax.checkpoint(run)
         return run(p, xs)
 

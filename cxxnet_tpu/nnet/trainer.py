@@ -22,6 +22,7 @@ forward-only executable.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import os
 import time
@@ -1018,14 +1019,23 @@ class NetTrainer:
             return loss.astype(jnp.float32) * scale, outs
 
         # remat=1: one `jax.checkpoint` around each layer whose class
-        # says it is worth it (kda, mla, glu_ffn, moe -
-        # Network._run_layer): the backward keeps those layers' inputs
-        # and recomputes what is inside them - trades FLOPs for memory,
-        # the standard lever for long sequences on TPU. (One checkpoint
-        # round the whole loss, which this key used to mean, saved
-        # nothing: the backward's recomputation held every activation
-        # again.)
+        # says it is worth it (kda, glu_ffn - Network._run_layer): the
+        # backward keeps those layers' inputs and recomputes what is
+        # inside them - trades FLOPs for memory, the standard lever for
+        # long sequences on TPU. mla and moe keep their activations:
+        # their second forward bought a third of the memory a
+        # millisecond. (One checkpoint round the whole loss, which this
+        # key used to mean, saved nothing: the backward's recomputation
+        # held every activation again.)
         net.remat = bool(self.remat)
+        if net.remat and not self.silent:
+            held = net.checkpointed
+            kinds = collections.Counter(s.split(".")[0] for s in held)
+            telemetry.stdout(
+                f"remat: {len(held)} of {len(net.layer_objs)} "
+                "layers checkpointed ("
+                + (", ".join(f"{k} x{n}" for k, n in kinds.items())
+                   or "no layer of a kind worth it") + ")")
 
         # ZeRO-2/3 sharding trees (parallel/sharding.py): the per-weight
         # 'data' cut shared by optimizer state, gradients/accumulator

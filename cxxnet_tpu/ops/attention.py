@@ -130,7 +130,8 @@ def blockwise_attention(q, k, v, *, causal: bool = False,
     (Sq, kv_block) instead of (Sq, Sk). Semantics == naive_attention.
 
     The scan carries f32 (acc, m, l); XLA keeps the whole loop on-chip.
-    Wrap in jax.checkpoint (remat=1) for the O(S) memory backward."""
+    The backward keeps each block's scores: wrap the call in
+    jax.checkpoint where the O(S) memory has to hold there too."""
     sk = k.shape[2]
     kv_block = min(kv_block, sk)
     if nblk_pad := (-sk) % kv_block:

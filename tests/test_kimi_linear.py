@@ -371,9 +371,8 @@ def test_ids_over_256_under_bfloat16():
 
 
 def test_remat_changes_no_number():
-    """`remat = 1` puts one checkpoint round each kda / mla / glu_ffn /
-    moe layer: the same losses and parameters, to the last bit on the
-    CPU."""
+    """`remat = 1` puts one checkpoint round each kda / glu_ffn layer:
+    the same losses and parameters, to the last bit on the CPU."""
     tok = tokens()
     runs = []
     for remat in ("0", "1"):
@@ -385,6 +384,79 @@ def test_remat_changes_no_number():
     assert runs[0][0] == runs[1][0]
     for a, b in zip(jax.tree.leaves(runs[0][1]), jax.tree.leaves(runs[1][1])):
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
+
+
+def test_remat_checkpoints_the_kinds_that_pay(capsys):
+    """Under `remat = 1` the network's list of checkpointed layers holds
+    every kda and glu_ffn layer of the conf and no other (an mla or moe
+    layer's second forward buys a third of the memory a millisecond:
+    docs/global.md); the trainer says so once. Under `remat = 0` the
+    list is empty and nothing is said."""
+    t = build(conf_text(), dict(TINY, remat="1", silent="0"))
+    kinds = {l.type_name for l in t.net.layer_objs}
+    assert {"kda", "glu_ffn", "mla", "moe", "rms_norm", "embed",
+            "lm_head"} <= kinds
+    want = [f"{l.type_name}.{t.net.cfg.layers[i].name}"
+            for i, l in enumerate(t.net.layer_objs)
+            if l.type_name in ("kda", "glu_ffn")]
+    assert len(want) == 5 and t.net.checkpointed == want
+    said = [line for line in capsys.readouterr().out.splitlines()
+            if line.startswith("remat:")]
+    assert said == ["remat: 5 of 33 layers checkpointed "
+                    "(kda x4, glu_ffn x1)"]
+    t = build(conf_text(), dict(TINY, remat="0", silent="0"))
+    assert t.net.checkpointed == []
+    assert "remat:" not in capsys.readouterr().out
+
+
+def _step_eqns(trainer, tok):
+    """(primitive name, name stack) of every equation of the train
+    step's jaxpr, those of inner jaxprs (loops, checkpoints, calls)
+    too."""
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            yield e.primitive.name, str(e.source_info.name_stack)
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from walk(sub)
+
+    st = trainer.stage_batch(batch_of(tok))
+    return list(walk(trainer._train_step.trace(
+        trainer.state, st.data, st.extras, st.labels, st.mask,
+        jax.random.PRNGKey(0)).jaxpr.jaxpr))
+
+
+@pytest.mark.parametrize("remat", ["0", "1"])
+def test_remat_runs_the_attention_core_once(remat, monkeypatch):
+    """One `mla` layer, the flash kernel in interpret mode: the step
+    calls `flash_fwd` once, in the layer's forward, and the backward
+    kernels once each on what that call left (q, k, v, o, lse), with
+    `remat` as without. A checkpoint round the layer would call it a
+    second time under `rematted_computation`."""
+    from cxxnet_tpu.ops import pallas_attention
+    monkeypatch.setattr(pallas_attention, "_FORCE_INTERPRET", True)
+    t = build(ONE_LAYER.format(layer=LAYERS["mla"]),
+              {"input_shape": "1,64,1", "remat": remat})
+    calls = sorted(stack for prim, stack in _step_eqns(t, tokens(seq=64))
+                   if prim == "pallas_call")
+    assert calls == ["jvp(mla.m1)/scores/flash_fwd",
+                     "transpose(jvp(mla.m1))/scores/flash_dkv",
+                     "transpose(jvp(mla.m1))/scores/flash_dq"]
+
+
+@pytest.mark.parametrize("remat", ["0", "1"])
+def test_remat_runs_the_expert_loop_once_a_direction(remat):
+    """One `moe` layer: `_dropless_fwd`'s tile loop once in the forward,
+    `_dropless_bwd`'s once in the backward, with `remat` as without. A
+    checkpoint round the layer would hold a third, outside both
+    scopes."""
+    t = build(ONE_LAYER.format(layer=LAYERS["moe"]), {"remat": remat})
+    loops = sorted(stack for prim, stack in _step_eqns(t, tokens())
+                   if prim == "while" and "experts" in stack)
+    assert loops == ["jvp(moe.e1)/experts",
+                     "transpose(jvp(moe.e1))/experts"]
 
 
 def test_token_iterator_reads_a_file_and_a_seed(tmp_path):
