@@ -463,17 +463,17 @@ def kernel_leg(dry: bool) -> None:
     # 7). Tolerance: the CPU suite's bf16 bound
     # (tests/test_pallas_attention.py test_bf16_forward, 0.05), here
     # relative to each tensor's scale.
-    def flash_case(b, h, s, dh, causal):
-        q, k, v = (jnp.asarray(rng.randn(b, h, s, dh), jnp.bfloat16)
-                   for _ in range(3))
+    def flash_case(b, h, s, dh, causal, hkv=None, window=0):
+        q, k, v = (jnp.asarray(rng.randn(b, n, s, dh), jnp.bfloat16)
+                   for n in (h, hkv or h, hkv or h))
         w = jnp.asarray(rng.randn(b, h, s, dh), jnp.float32)
 
         def loss_k(q, k, v, w):
-            o = PA.flash_attention(q, k, v, causal, None, interp)
+            o = PA.flash_attention(q, k, v, causal, None, interp, window)
             return jnp.sum(o.astype(jnp.float32) * w), o
 
         def loss_r(q, k, v, w):
-            o = naive_attention(q, k, v, causal=causal)
+            o = naive_attention(q, k, v, causal=causal, window=window)
             return jnp.sum(o * w), o
 
         (_, ok), gk = jax.jit(jax.value_and_grad(
@@ -489,7 +489,8 @@ def kernel_leg(dry: bool) -> None:
                 outs.append(np.asarray(o))
                 for acc, gi in zip(grads, g3):
                     acc.append(np.asarray(gi))
-        tag = f"flash b{b} h{h} s{s} d{dh} bf16 causal={int(causal)}"
+        tag = (f"flash b{b} h{h}/{hkv or h} s{s} d{dh} bf16 "
+               f"causal={int(causal)} window={window}")
         close(tag + " fwd", ok, np.concatenate(outs), 0.05, 0.05)
         for nm, got, acc in zip("qkv", gk, grads):
             close(f"{tag} d{nm}", got, np.concatenate(acc), 0.05, 0.05)
@@ -498,6 +499,13 @@ def kernel_leg(dry: bool) -> None:
         flash_case(*((2, 2, 32, 16) if dry else (4, 8, 4096, 128)),
                    causal)
         flash_case(4 if dry else 100, 4, 28, 7, causal)
+    # the `gqa` layer's kernels: 7 query heads on each key/value head
+    # (dk and dv sum over their group), without a window and with one a
+    # quarter of the sequence long, so that whole tiles lie left of it
+    # and are never walked (flash_win_fwd / flash_win_dq / flash_win_dkv)
+    for window in (0, 8 if dry else 1024):
+        flash_case(*((2, 14, 32, 16) if dry else (1, 14, 4096, 128)),
+                   True, hkv=2, window=window)
     # the compiler takes the seq_mnist shape, but that example's layer
     # never sends it: _tile_ok declines d < 8 and a 28-row bf16 tile,
     # and AttentionLayer._core routes it to blockwise XLA

@@ -1,7 +1,7 @@
 """Language-model layers over sequence nodes (batch, 1, seq, embed):
 token embedding, RMS norm, gated FFN, the two token mixers of the
-Kimi-Linear family (`kda`, `mla`) and the untied head with its
-next-token loss.
+Kimi-Linear family (`kda`, `mla`), grouped-query attention with rotary
+and a window (`gqa`) and the untied head with its next-token loss.
 
 embed     node of token ids (batch, 1, seq, 1), INTEGER, -> (batch, 1,
           seq, nhidden). Keys: nvocab, nhidden. Ids stay integers from
@@ -25,6 +25,19 @@ mla       multi-head latent attention WITHOUT rotary (`mla_use_nope`):
           kernel where it runs (ops/pallas_attention.py), else through
           `blockwise_attention`; both take one head size, so the values
           are padded to the query width and cut back.
+gqa       grouped-query causal attention: `nhead` query heads read the
+          `nkvhead` key/value heads, `nhead // nkvhead` to one, all
+          `head_dim` wide, no bias. `rope_theta` > 0 turns q and k by a
+          rotary embedding (the halves of a head paired, over the whole
+          head, positions 0..seq-1); 0 = no positional encoding.
+          `window` > 0: a query sees the `window` positions up to its
+          own; 0 = full causal. The core runs through the flash kernel
+          where it runs - a window layer walks only the score tiles of
+          its band, the shared heads are read through the index map -
+          else through `blockwise_attention`, which masks. Counter
+          `tiles`: the score tiles the core computes over those of a
+          full causal layer (1 on the XLA route, which skips none).
+          Keys: nhead, nkvhead, head_dim, window (0), rope_theta (0).
 lm_head   inputs: the final hidden node and the token node. Output: the
           logits (batch, 1, seq, nvocab) - what `task = pred` and
           `extract` read. In training its loss term is the mean over
@@ -391,6 +404,116 @@ class MLALayer(_TableLayer):
             o = jnp.moveaxis(o[..., :dv], 1, 2).reshape(b, t, nh * dv)
             out = _lin(o, p["wo"])
         return [out.reshape(b, 1, t, e)]
+
+
+def rotary(x, theta: float):
+    """(b, h, T, d) turned by position: the halves of a head are paired,
+    pair i by the angle t * theta^(-i / (d/2)). Angles and the turn in
+    float32 inside a bf16 step."""
+    t, half = x.shape[2], x.shape[3] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                           axis=-1).astype(x.dtype)
+
+
+@register_layer
+class GQALayer(_TableLayer):
+    type_name = "gqa"
+    #: q and o at nhead x head_dim, k, v and `lse`: at 16,384 positions
+    #: 0.28 GB unsaved a layer for the projections and the flash forward
+    #: again (PERF.md section 6, PR 35, has the milliseconds): under the
+    #: rate `mla` is kept at, but eight such layers' maps do not fit
+    #: beside the state of the cell that runs them, so `remat` takes them
+    remat_worthy = True
+    stat_names = ("tiles",)
+
+    def __init__(self, name: str = ""):
+        super().__init__(name)
+        self.nhead = 0
+        self.nkvhead = 0
+        self.head_dim = 0
+        self.window = 0
+        self.rope_theta = 0.0
+        self.kv_block = 512
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == "nhead":
+            self.nhead = int(val)
+        if name == "nkvhead":
+            self.nkvhead = int(val)
+        if name == "head_dim":
+            self.head_dim = int(val)
+        if name == "window":
+            self.window = int(val)
+        if name == "rope_theta":
+            self.rope_theta = float(val)
+
+    def shapes(self, in_shapes):
+        self.check_one_to_one(in_shapes)
+        _seq(in_shapes[0], "gqa")
+        if min(self.nhead, self.nkvhead, self.head_dim) <= 0:
+            raise ValueError("gqa: must set nhead, nkvhead and head_dim")
+        if self.nhead % self.nkvhead:
+            raise ValueError("gqa: nhead must be a multiple of nkvhead")
+        if self.window < 0 or self.rope_theta < 0 or (
+                self.rope_theta and self.head_dim % 2):
+            raise ValueError("gqa: window and rope_theta are 0 or more, "
+                             "and a turned head has an even width")
+        return [in_shapes[0]]
+
+    def table(self, in_shapes):
+        e, d = in_shapes[0][3], self.head_dim
+        return [("wq", (e, self.nhead * d), "normal"),
+                ("wk", (e, self.nkvhead * d), "normal"),
+                ("wv", (e, self.nkvhead * d), "normal"),
+                ("wo", (self.nhead * d, e), "normal")]
+
+    def apply_with_stats(self, params, inputs, *, train, rng=None,
+                         mask=None):
+        p = params
+        b, _, t, e = inputs[0].shape
+        x = inputs[0].reshape(b, t, e)
+        d = self.head_dim
+
+        def heads(w, n):                                   # -> BHSD
+            return jnp.einsum("bte,ehd->bhtd", x,
+                              w.astype(x.dtype).reshape(e, n, d))
+
+        with jax.named_scope("proj"):
+            q = heads(p["wq"], self.nhead)
+            k = heads(p["wk"], self.nkvhead)
+            v = heads(p["wv"], self.nkvhead)
+        if self.rope_theta:
+            with jax.named_scope("rope"):
+                q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
+        with jax.named_scope("scores"):
+            # imported here, as `mla` does: Pallas costs every process
+            # that imports it 1.3-2 s of start-up
+            from cxxnet_tpu.ops import pallas_attention
+            if pallas_attention.use_flash(q):
+                o = pallas_attention.flash_attention(
+                    q, k, v, True, None, pallas_attention._FORCE_INTERPRET,
+                    self.window)
+                tiles = pallas_attention.tile_share(q, self.window)
+            else:
+                o = ops_attn.blockwise_attention(
+                    q, k, v, causal=True, kv_block=self.kv_block,
+                    window=self.window)
+                tiles = 1.0
+        with jax.named_scope("out"):
+            out = jnp.einsum("bhtd,hde->bte", o, p["wo"].astype(o.dtype)
+                             .reshape(self.nhead, d, e))
+        return ([out.reshape(b, 1, t, e)], None,
+                {"tiles": jnp.asarray(tiles, jnp.float32)})
+
+    def apply(self, params, inputs, *, train, rng=None):
+        return self.apply_with_stats(params, inputs, train=train,
+                                     rng=rng)[0]
 
 
 @register_layer

@@ -6,17 +6,23 @@ top-k routed FFN over (batch, 1, seq, embed) sequence nodes.
 
 - every expert's FFN weights live in stacked tensors with a leading
   expert dim (w1 (G, H, e), w2 (G, e, H), with `moe_glu = 1` also w3
-  (G, H, e): the expert is (SiLU(x w1^T) * (x w3^T)) w2^T, else
-  relu(x w1^T + b1) w2^T + b2); `expert_shard_dims` shards that dim
+  (G, H, e): the expert is (act(x w1^T) * (x w3^T)) w2^T, act being
+  SiLU or, with `moe_act = relu`, ReLU; else relu(x w1^T + b1) w2^T +
+  b2); `expert_shard_dims` shards that dim
   over an 'expert' mesh axis the same way `model_shard_dims` drives
   tensor parallelism (parallel/sharding.py).
 - the router. `moe_score = softmax` (default): probabilities over the
   experts, the top-k weighted by their probability (the
-  Switch-Transformer estimator). `moe_score = sigmoid`: scores
+  Switch-Transformer estimator), or with `moe_norm_topk = 1` by the
+  softmax over the chosen logits alone (the probabilities renormalised
+  over the chosen: the same numbers). `moe_score = sigmoid`: scores
   s = sigmoid(x Wr^T) in float32, the top-k of s + b chosen, b being the
   selection bias `sbias`, a leaf that takes NO gradient step (seeded
   with `moe_bias_sigma` x N(0, 1), fixed); the chosen are weighted
   `moe_scale` x s_e / sum over the chosen of s.
+- a second input (`layer[b,a->f] = moe`): the experts read the first
+  node, the router the second (a model whose router reads the token
+  mixer's input, not the FFN's). One input is both.
 - `moe_shared = n`: a shared expert of n x nhidden beside the routed
   ones (s1, s3, s2; gated as `moe_glu` says), run on every token.
 - `moe_held = first,count`: this device holds experts
@@ -53,8 +59,8 @@ top-k routed FFN over (batch, 1, seq, embed) sequence nodes.
   `dropped` held assignments that found no row (0 by construction).
 
 Config keys: nexpert, nhidden (per-expert FFN hidden), moe_top_k
-(default 1), moe_aux (default 0.01), moe_score, moe_scale, moe_glu,
-moe_shared, moe_held, moe_bias_sigma, no_bias.
+(default 1), moe_aux (default 0.01), moe_score, moe_norm_topk, moe_scale,
+moe_glu, moe_act, moe_shared, moe_held, moe_bias_sigma, no_bias.
 """
 
 from __future__ import annotations
@@ -213,6 +219,8 @@ class MoELayer(Layer):
         self.score = "softmax"
         self.scale = 1.0
         self.glu = 0
+        self.act = "silu"         # the gate of a `moe_glu` expert
+        self.norm_topk = 0
         self.shared = 0
         self.held = None          # (first, count), None = all
         self.bias_sigma = 0.0
@@ -238,8 +246,14 @@ class MoELayer(Layer):
             self.score = val
         if name == "moe_scale":
             self.scale = float(val)
+        if name == "moe_norm_topk":
+            self.norm_topk = int(val)
         if name == "moe_glu":
             self.glu = int(val)
+        if name == "moe_act":
+            if val not in ("silu", "relu"):
+                raise ValueError("moe_act must be silu or relu")
+            self.act = val
         if name == "moe_shared":
             self.shared = int(val)
         if name == "moe_held":
@@ -253,10 +267,13 @@ class MoELayer(Layer):
         return self.held if self.held is not None else (0, self.nexpert)
 
     def infer_shapes(self, in_shapes: List[Shape]) -> List[Shape]:
-        self.check_one_to_one(in_shapes)
+        if len(in_shapes) not in (1, 2):
+            raise ValueError("moe: one input, or the experts' and the "
+                             "router's")
         b, c, s, e = in_shapes[0]
-        if c != 1:
-            raise ValueError("moe: input must be a sequence node")
+        if c != 1 or tuple(in_shapes[-1][:3]) != (b, 1, s):
+            raise ValueError("moe: inputs must be sequence nodes of one "
+                             "length")
         if self.nexpert < 2:
             raise ValueError("moe: must set nexpert >= 2")
         if self.param.num_hidden <= 0:
@@ -274,8 +291,9 @@ class MoELayer(Layer):
         h, g = self.param.num_hidden, self._first_count[1]
         kg, k1, k2 = jax.random.split(key, 3)
         rand = self.param.rand_init_weight
+        er = in_shapes[-1][3]
         params = {
-            "gate": rand(kg, (self.nexpert, e), in_num=e,
+            "gate": rand(kg, (self.nexpert, er), in_num=er,
                          out_num=self.nexpert),
             "w1": rand(k1, (g, h, e), in_num=e, out_num=h),
             "w2": rand(k2, (g, e, h), in_num=h, out_num=e),
@@ -329,6 +347,9 @@ class MoELayer(Layer):
         else:
             probs = jax.nn.softmax(logits, axis=-1)
             weights, chosen = lax.top_k(probs, self.top_k)
+            if self.norm_topk:
+                weights = jax.nn.softmax(
+                    jnp.take_along_axis(logits, chosen, axis=-1), axis=-1)
             weights = self.scale * weights
         if not self.aux_scale:
             return weights, chosen, jnp.zeros((), jnp.float32)
@@ -357,8 +378,9 @@ class MoELayer(Layer):
         dt = rows.dtype
         a = jnp.einsum("ne,he->nh", rows, w["w1"].astype(dt))
         if self.glu:
-            h1 = jax.nn.silu(a) * jnp.einsum("ne,he->nh", rows,
-                                             w["w3"].astype(dt))
+            gate = jnp.maximum(a, 0.0) if self.act == "relu" \
+                else jax.nn.silu(a)
+            h1 = gate * jnp.einsum("ne,he->nh", rows, w["w3"].astype(dt))
         else:
             if "b1" in w:
                 a = a + w["b1"].astype(dt)
@@ -431,7 +453,9 @@ class MoELayer(Layer):
         b, _, s, e = x.shape
         xs = x.reshape(b, s, e)
         with jax.named_scope("route"):
-            weights, chosen, aux = self._route(params, xs, mask)
+            weights, chosen, aux = self._route(
+                params, xs if len(inputs) == 1
+                else inputs[1].reshape(b, s, -1), mask)
         if self._dropless():
             out, stats = self._dropless_compute(params, xs, weights, chosen)
         else:

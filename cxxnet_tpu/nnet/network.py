@@ -57,7 +57,8 @@ class Network:
         self.dtype_plan: Optional[Dict[int, jnp.dtype]] = None
         # `remat = 1` (trainer): one `jax.checkpoint` around each layer
         # whose class says `remat_worthy` (kda, glu_ffn: the kinds whose
-        # second forward buys the most memory a millisecond): the
+        # second forward buys the most memory a millisecond; gqa: the
+        # kind whose maps do not fit at the length it is run at): the
         # backward keeps that layer's inputs and recomputes the rest.
         # `checkpointed` lists them
         self.remat = False

@@ -1019,7 +1019,7 @@ class NetTrainer:
             return loss.astype(jnp.float32) * scale, outs
 
         # remat=1: one `jax.checkpoint` around each layer whose class
-        # says it is worth it (kda, glu_ffn - Network._run_layer): the
+        # says it is worth it (kda, glu_ffn, gqa - Network._run_layer): the
         # backward keeps those layers' inputs and recomputes what is
         # inside them - trades FLOPs for memory, the standard lever for
         # long sequences on TPU. mla and moe keep their activations:

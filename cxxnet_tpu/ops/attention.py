@@ -39,25 +39,55 @@ def _scale(q, scale: Optional[float]) -> float:
     return (1.0 / (q.shape[-1] ** 0.5)) if scale is None else scale
 
 
-def _causal_bias(sq: int, sk: int, q_offset, kv_offset) -> jax.Array:
+def _causal_bias(sq: int, sk: int, q_offset, kv_offset,
+                 window: int = 0) -> jax.Array:
     """(sq, sk) additive bias: 0 where key position <= query position in
-    GLOBAL coordinates, _NEG elsewhere. Offsets may be traced values
+    GLOBAL coordinates (and, with a `window`, less than `window`
+    positions behind it), _NEG elsewhere. Offsets may be traced values
     (ring attention passes lax.axis_index-derived block offsets)."""
     qpos = q_offset + jnp.arange(sq)[:, None]
     kpos = kv_offset + jnp.arange(sk)[None, :]
-    return jnp.where(kpos <= qpos, 0.0, _NEG)
+    seen = kpos <= qpos
+    if window:
+        seen = seen & (qpos - kpos < window)
+    return jnp.where(seen, 0.0, _NEG)
+
+
+def _check_window(window: int, causal: bool) -> None:
+    if window and not causal:
+        raise ValueError("a window needs causal attention")
+
+
+def _per_query_head(q, k, v):
+    """Grouped-query heads: k and v hold `q.shape[1] // k.shape[1]`
+    times fewer heads than q, and query head h reads head h // group.
+    Written out here (the XLA routes are the ground truth, not the fast
+    path; the flash kernel takes the shared head through its index
+    map)."""
+    group, rest = divmod(q.shape[1], k.shape[1])
+    if rest:
+        raise ValueError(f"{q.shape[1]} query heads over {k.shape[1]} "
+                         "key/value heads")
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
 
 
 def naive_attention(q, k, v, *, causal: bool = False,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None, window: int = 0):
     """Reference semantics: softmax(q.k^T * scale [+ causal mask]).v with
     the full (sq, sk) score matrix materialized. Ground truth for the
-    blockwise/ring variants' differential tests."""
+    blockwise/ring variants' differential tests. `window` (causal only):
+    a query sees the `window` positions up to its own. k and v may hold
+    fewer heads than q (`_per_query_head`)."""
+    _check_window(window, causal)
+    k, v = _per_query_head(q, k, v)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32)
     s = s.astype(jnp.float32) * _scale(q, scale)
     if causal:
-        s = s + _causal_bias(q.shape[2], k.shape[2], 0, 0)[None, None]
+        s = s + _causal_bias(q.shape[2], k.shape[2], 0, 0,
+                             window)[None, None]
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
                    preferred_element_type=jnp.float32)
@@ -66,7 +96,7 @@ def naive_attention(q, k, v, *, causal: bool = False,
 
 def attention_partial(q, k, v, *, scale: Optional[float] = None,
                       causal: bool = False, q_offset=0, kv_offset=0,
-                      kv_valid: Optional[int] = None,
+                      kv_valid: Optional[int] = None, window: int = 0,
                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One K/V block's contribution as an online-softmax partial.
 
@@ -80,7 +110,7 @@ def attention_partial(q, k, v, *, scale: Optional[float] = None,
     s = s.astype(jnp.float32) * _scale(q, scale)
     if causal:
         s = s + _causal_bias(q.shape[2], k.shape[2],
-                             q_offset, kv_offset)[None, None]
+                             q_offset, kv_offset, window)[None, None]
     if kv_valid is not None:
         kpos = kv_offset + jnp.arange(k.shape[2])[None, :]
         s = jnp.where((kpos < kv_valid)[None, None], s, _NEG)
@@ -124,14 +154,18 @@ def empty_partial(q) -> Tuple[jax.Array, jax.Array, jax.Array]:
 
 def blockwise_attention(q, k, v, *, causal: bool = False,
                         scale: Optional[float] = None,
-                        kv_block: int = 512):
+                        kv_block: int = 512, window: int = 0):
     """Flash-style memory-efficient attention: lax.scan over K/V blocks
     with the online-softmax recurrence; peak score memory is
     (Sq, kv_block) instead of (Sq, Sk). Semantics == naive_attention.
 
     The scan carries f32 (acc, m, l); XLA keeps the whole loop on-chip.
     The backward keeps each block's scores: wrap the call in
-    jax.checkpoint where the O(S) memory has to hold there too."""
+    jax.checkpoint where the O(S) memory has to hold there too.
+    `window` and grouped heads as `naive_attention`; the window is
+    masked here, block by block, not skipped."""
+    _check_window(window, causal)
+    k, v = _per_query_head(q, k, v)
     sk = k.shape[2]
     kv_block = min(kv_block, sk)
     if nblk_pad := (-sk) % kv_block:
@@ -146,7 +180,7 @@ def blockwise_attention(q, k, v, *, causal: bool = False,
     nblk = k.shape[2] // kv_block
     if nblk == 1:
         acc, m, l = attention_partial(q, k, v, scale=scale, causal=causal,
-                                      kv_valid=kv_valid)
+                                      kv_valid=kv_valid, window=window)
         return finalize_partial(acc, l, q.dtype)
 
     kb = k.reshape(k.shape[0], k.shape[1], nblk, kv_block, k.shape[3])
@@ -158,7 +192,7 @@ def blockwise_attention(q, k, v, *, causal: bool = False,
         kv_i, k_i, v_i = xs
         part = attention_partial(q, k_i, v_i, scale=scale, causal=causal,
                                  q_offset=0, kv_offset=kv_i * kv_block,
-                                 kv_valid=kv_valid)
+                                 kv_valid=kv_valid, window=window)
         return merge_partials(carry, part), None
 
     init = empty_partial(q)

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from cxxnet_tpu.ops.attention import _scale
+from cxxnet_tpu.ops.attention import _check_window, _scale
 
 _NEG = -1e30
 
@@ -93,20 +93,122 @@ def _blocks(s: int, block: int, sub: int = 1) -> int:
 
 
 # ---------------------------------------------------------------------------
+# which tiles run
+# ---------------------------------------------------------------------------
+# A causal layer with a `window` (a query sees the `window` positions up
+# to its own) needs the score tiles of a band, not of the whole lower
+# triangle. The grids below do not walk the tiles outside it: the inner
+# grid dimension counts STEPS, as many as a band row (or column) can
+# hold tiles, and step j of query tile qi reads key tile
+# `_kv_tile(qi, j)` (the backward's dk/dv pass: step j of key tile ki
+# reads query tile `_q_tile(ki, j)`). A step that lands outside the
+# sequence is skipped and its block index clamped, so no tile is fetched
+# for it. Without a window a step is a tile, as it always was, and a
+# wholly future tile is fetched and skipped.
+
+def _tile_live(q_off, kv_off, bq: int, bk: int, window: int):
+    """Whether the tile holds a (query, key) pair that is causal and
+    inside the window. Python ints or traced scalars."""
+    live = kv_off <= q_off + bq - 1
+    if window:
+        live = live & (kv_off + bk - 1 > q_off - window)
+    return live
+
+
+def _run_live(tile, causal: bool, window: int, in_range, q_off, kv_off,
+              bq: int, bk: int) -> None:
+    """Run a kernel's `tile` where it holds a pair the mask keeps;
+    `in_range`: the step of a window's grid landed inside the
+    sequence."""
+    if window:
+        pl.when(in_range & _tile_live(q_off, kv_off, bq, bk, window))(tile)
+    elif causal:
+        pl.when(_tile_live(q_off, kv_off, bq, bk, 0))(tile)
+    else:
+        tile()
+
+
+def _kv_steps(nq: int, nkv: int, bq: int, bk: int, window: int) -> int:
+    if not window:
+        return nkv
+    return max((qi * bq + bq - 1) // bk
+               - max((qi * bq - window + 1) // bk, 0) + 1
+               for qi in range(nq))
+
+
+def _kv_tile(qi, j, bq: int, bk: int, steps: int, window: int):
+    """The key tile of step j: the band row ends at the diagonal."""
+    if not window:
+        return j
+    return (qi * bq + bq - 1) // bk - (steps - 1) + j
+
+
+def _q_steps(nq: int, nkv: int, bq: int, bk: int, window: int) -> int:
+    if not window:
+        return nq
+    return max(min((ki * bk + bk + window - 2) // bq, nq - 1)
+               - (ki * bk) // bq + 1 for ki in range(nkv))
+
+
+def _q_tile(ki, j, bq: int, bk: int, window: int):
+    """The query tile of step j: the band column starts at the
+    diagonal."""
+    if not window:
+        return j
+    return (ki * bk) // bq + j
+
+
+def _tiles_of(q, sk: int) -> Tuple[int, int]:
+    sub = _sublane(q.dtype)
+    lq, lk = _block_limits(q.shape[3])
+    return _blocks(q.shape[2], lq, sub), _blocks(sk, lk, sub)
+
+
+def tile_share(q, window: int) -> float:
+    """The score tiles the forward kernel runs for `q` (b, h, s, d)
+    under causal attention with this window, over those it runs without
+    one: 1 for a full layer. Static: what a layer's `tiles` counter
+    says (layers/lm.py)."""
+    bq, bk = _tiles_of(q, q.shape[2])
+    n = q.shape[2]
+
+    def run(w):
+        return sum(bool(_tile_live(qo, ko, bq, bk, w))
+                   for qo in range(0, n, bq) for ko in range(0, n, bk))
+
+    return run(window) / run(0)
+
+
+def _name(kernel: str, window: int) -> str:
+    """The device event's name: a window kernel is told apart."""
+    return f"flash_win_{kernel}" if window else f"flash_{kernel}"
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
-                scale, causal, bq, bk, nkv):
-    ki = pl.program_id(3)
+def _mask(s, q_off, kv_off, bq: int, bk: int, window: int):
+    qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    kpos = kv_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    seen = kpos <= qpos
+    if window:
+        seen = seen & (qpos - kpos < window)
+    return jnp.where(seen, s, _NEG)
 
-    @pl.when(ki == 0)
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
+                scale, causal, bq, bk, steps, window):
+    j = pl.program_id(3)
+
+    @pl.when(j == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m[:] = jnp.full_like(m, _NEG)
         l[:] = jnp.zeros_like(l)
 
     qi = pl.program_id(2)
+    ki = _kv_tile(qi, j, bq, bk, steps, window)
     q_off = qi * bq
     kv_off = ki * bk
 
@@ -117,9 +219,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
-            qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = kv_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, _NEG)
+            s = _mask(s, q_off, kv_off, bq, bk, window)
         m_new = jnp.maximum(m[:], jnp.max(s, axis=1))
         p = jnp.exp(s - m_new[:, None])
         if causal:
@@ -131,12 +231,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
             preferred_element_type=jnp.float32)
         m[:] = m_new
 
-    if causal:
-        pl.when(kv_off <= q_off + bq - 1)(_tile)
-    else:
-        _tile()
+    _run_live(_tile, causal, window, ki >= 0, q_off, kv_off, bq, bk)
 
-    @pl.when(ki == nkv - 1)
+    @pl.when(j == steps - 1)
     def _out():
         safe = jnp.where(l[:] > 0, l[:], 1.0)
         o_ref[0, 0] = (acc[:] / safe[:, None]).astype(o_ref.dtype)
@@ -144,34 +241,60 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
             (m[:] + jnp.log(safe))[:, None], (bq, _STAT_LANES))
 
 
-def _fwd(q, k, v, scale, causal, interpret) -> Tuple[jax.Array, jax.Array]:
+def _specs(q, k, window: int, tiles: Tuple[int, int]):
+    """The steps of the inner grid dimension and the block specs of a
+    (b, h, nq, steps) grid: query-side blocks follow the query tile,
+    key-side blocks the step's key tile in the head that the query
+    head's group shares."""
+    d = q.shape[3]
+    bq, bk = tiles
+    nq, nkv = q.shape[2] // bq, k.shape[2] // bk
+    group = q.shape[1] // k.shape[1]
+    steps = _kv_steps(nq, nkv, bq, bk, window)
+
+    def kv_at(b, h, qi, j):
+        ki = _kv_tile(qi, j, bq, bk, steps, window)
+        if window:
+            ki = jnp.maximum(ki, 0)
+        return (b, h // group if group > 1 else h, ki, 0)
+
+    qspec = pl.BlockSpec((1, 1, bq, d), lambda b, h, qi, j: (b, h, qi, 0))
+    kspec = pl.BlockSpec((1, 1, bk, d), kv_at)
+    rspec = pl.BlockSpec((1, 1, bq, _STAT_LANES),
+                         lambda b, h, qi, j: (b, h, qi, 0))
+    return bq, bk, nq, nkv, group, steps, qspec, kspec, rspec
+
+
+_BY_QUERY = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+# ONE jitted function a direction (as ops/pallas_kda.py): the layers of
+# a step that call it with one shape, one window and the same tiles
+# share one lowered kernel body, which is set-up no compile cache saves
+_STATIC = ("scale", "causal", "interpret", "window", "tiles")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _fwd(q, k, v, scale, causal, interpret, window, tiles
+         ) -> Tuple[jax.Array, jax.Array]:
     b, h, s, d = q.shape
-    sub = _sublane(q.dtype)
-    lq, lk = _block_limits(d)
-    bq, bk = _blocks(s, lq, sub), _blocks(k.shape[2], lk, sub)
-    nq, nkv = s // bq, k.shape[2] // bk
+    bq, bk, nq, _, _, steps, qspec, kspec, rspec = _specs(q, k, window,
+                                                          tiles)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                             bq=bq, bk=bk, nkv=nkv)
-    qspec = pl.BlockSpec((1, 1, bq, d), lambda b, h, qi, ki: (b, h, qi, 0))
-    kspec = pl.BlockSpec((1, 1, bk, d), lambda b, h, qi, ki: (b, h, ki, 0))
+                             bq=bq, bk=bk, steps=steps, window=window)
     o, lse = pl.pallas_call(
         kern,
-        grid=(b, h, nq, nkv),
+        grid=(b, h, nq, steps),
         in_specs=[qspec, kspec, kspec],
-        out_specs=[qspec,
-                   pl.BlockSpec((1, 1, bq, _STAT_LANES),
-                                lambda b, h, qi, ki: (b, h, qi, 0))],
+        out_specs=[qspec, rspec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((b, h, s, _STAT_LANES),
                                         jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=_BY_QUERY,
         interpret=interpret,
-        name="flash_fwd",
+        name=_name("fwd", window),
     )(q, k, v)
     return o, lse
 
@@ -181,15 +304,17 @@ def _fwd(q, k, v, scale, causal, interpret) -> Tuple[jax.Array, jax.Array]:
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc, *, scale, causal, bq, bk, nkv):
-    ki = pl.program_id(3)
+               acc, *, scale, causal, bq, bk, steps, window):
+    j = pl.program_id(3)
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
 
     qi = pl.program_id(2)
+    ki = _kv_tile(qi, j, bq, bk, steps, window)
     q_off, kv_off = qi * bq, ki * bk
+
     def _tile():
         q = q_ref[0, 0]
         k = k_ref[0, 0]
@@ -197,9 +322,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
-            qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = kv_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, _NEG)
+            s = _mask(s, q_off, kv_off, bq, bk, window)
         p = jnp.exp(s - lse_ref[0, 0][:, :1])
         dov = jax.lax.dot_general(
             do_ref[0, 0], v_ref[0, 0], (((1,), (1,)), ((), ())),
@@ -209,27 +332,30 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(kv_off <= q_off + bq - 1)(_tile)
-    else:
-        _tile()
+    _run_live(_tile, causal, window, ki >= 0, q_off, kv_off, bq, bk)
 
-    @pl.when(ki == nkv - 1)
+    @pl.when(j == steps - 1)
     def _out():
         dq_ref[0, 0] = acc[:].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, acck, accv, *, scale, causal, bq, bk, nq):
-    qi = pl.program_id(3)
+                dk_ref, dv_ref, acck, accv, *, scale, causal, bq, bk, nq,
+                steps, group, window):
+    """One key tile of one key/value head: the inner dimension walks the
+    query tiles of its band, once for each query head of the group, and
+    sums."""
+    t = pl.program_id(3)
 
-    @pl.when(qi == 0)
+    @pl.when(t == 0)
     def _init():
         acck[:] = jnp.zeros_like(acck)
         accv[:] = jnp.zeros_like(accv)
 
     ki = pl.program_id(2)
+    qi = _q_tile(ki, t % steps if group > 1 else t, bq, bk, window)
     q_off, kv_off = qi * bq, ki * bk
+
     def _tile():
         q = q_ref[0, 0]
         k = k_ref[0, 0]
@@ -237,9 +363,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
-            qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = kv_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, _NEG)
+            s = _mask(s, q_off, kv_off, bq, bk, window)
         p = jnp.exp(s - lse_ref[0, 0][:, :1])            # (bq, bk)
         do = do_ref[0, 0]
         accv[:] += jax.lax.dot_general(
@@ -253,68 +377,65 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # (bk, d)
 
-    if causal:
-        pl.when(kv_off <= q_off + bq - 1)(_tile)
-    else:
-        _tile()
+    _run_live(_tile, causal, window, qi < nq, q_off, kv_off, bq, bk)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(t == group * steps - 1)
     def _out():
         dk_ref[0, 0] = acck[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = accv[:].astype(dv_ref.dtype)
 
 
-def _bwd_impl(q, k, v, o, lse, do, scale, causal, interpret):
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _bwd_impl(q, k, v, o, lse, do, scale, causal, interpret, window, tiles):
     b, h, s, d = q.shape
-    sk = k.shape[2]
-    sub = _sublane(q.dtype)
-    lq, lk = _block_limits(d)
-    bq, bk = _blocks(s, lq, sub), _blocks(sk, lk, sub)
-    nq, nkv = s // bq, sk // bk
+    bq, bk, nq, nkv, group, steps, qspec, kspec, rspec = _specs(
+        q, k, window, tiles)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)  # (b, h, s)
     delta = jnp.broadcast_to(delta[..., None],
                              (*delta.shape, _STAT_LANES))
 
-    qspec = pl.BlockSpec((1, 1, bq, d), lambda b, h, qi, ki: (b, h, qi, 0))
-    kspec = pl.BlockSpec((1, 1, bk, d), lambda b, h, qi, ki: (b, h, ki, 0))
-    rspec = pl.BlockSpec((1, 1, bq, _STAT_LANES),
-                         lambda b, h, qi, ki: (b, h, qi, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nkv=nkv),
-        grid=(b, h, nq, nkv),
+                          bq=bq, bk=bk, steps=steps, window=window),
+        grid=(b, h, nq, steps),
         in_specs=[qspec, kspec, kspec, qspec, rspec, rspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=_BY_QUERY,
         interpret=interpret,
-        name="flash_dq",
+        name=_name("dq", window),
     )(q, k, v, do, lse, delta)
 
-    # swapped grid: kv outer, q inner (sequential) so dk/dv accumulate
-    qspec2 = pl.BlockSpec((1, 1, bq, d), lambda b, h, ki, qi: (b, h, qi, 0))
-    kspec2 = pl.BlockSpec((1, 1, bk, d), lambda b, h, ki, qi: (b, h, ki, 0))
-    rspec2 = pl.BlockSpec((1, 1, bq, _STAT_LANES),
-                          lambda b, h, ki, qi: (b, h, qi, 0))
+    # swapped grid: a key/value head's key tiles outer, the query tiles
+    # of their band inner (sequential, once a query head of the group)
+    # so dk/dv accumulate
+    qsteps = _q_steps(nq, nkv, bq, bk, window)
+
+    def q_at(b, hk, ki, t):
+        qi = _q_tile(ki, t % qsteps if group > 1 else t, bq, bk, window)
+        if window:
+            qi = jnp.minimum(qi, nq - 1)
+        return (b, hk * group + t // qsteps if group > 1 else hk, qi, 0)
+
+    qspec2 = pl.BlockSpec((1, 1, bq, d), q_at)
+    kspec2 = pl.BlockSpec((1, 1, bk, d), lambda b, hk, ki, t: (b, hk, ki, 0))
+    rspec2 = pl.BlockSpec((1, 1, bq, _STAT_LANES), q_at)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nq=nq),
-        grid=(b, h, nkv, nq),
+                          bq=bq, bk=bk, nq=nq, steps=qsteps, group=group,
+                          window=window),
+        grid=(b, k.shape[1], nkv, group * qsteps),
         in_specs=[qspec2, kspec2, kspec2, qspec2, rspec2, rspec2],
         out_specs=[kspec2, kspec2],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=_BY_QUERY,
         interpret=interpret,
-        name="flash_dkv",
+        name=_name("dkv", window),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -323,27 +444,39 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, interpret):
 # public entry: custom_vjp
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
-                    interpret: bool = False):
+                    interpret: bool = False, window: int = 0):
     """Fused TPU attention; semantics == ops.attention.naive_attention.
-    [B, H, S, D] in/out; O(S) memory; causal skips future tiles."""
-    sc = _scale(q, scale)
-    o, _ = _fwd(q, k, v, sc, causal, interpret)
-    return o
+    [B, H, S, D] in/out; O(S) memory; causal skips future tiles, and
+    with a `window` (causal only) the tiles left of it are never
+    walked. k and v may hold fewer heads than q: query head h reads
+    head h // (H // Hkv) through the index map (no repeated copy), and
+    the backward sums a group's query heads into its dk and dv."""
+    return _vjp_fwd(q, k, v, causal, scale, interpret, window)[0]
 
 
-def _vjp_fwd(q, k, v, causal, scale, interpret):
-    sc = _scale(q, scale)
-    o, lse = _fwd(q, k, v, sc, causal, interpret)
+def _check(q, k, causal: bool, window: int) -> None:
+    _check_window(window, causal)
+    if window and q.shape[2] != k.shape[2]:
+        raise ValueError("a window needs queries and keys of one length")
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads over {k.shape[1]} "
+                         "key/value heads")
+
+
+def _vjp_fwd(q, k, v, causal, scale, interpret, window):
+    _check(q, k, causal, window)
+    o, lse = _fwd(q, k, v, _scale(q, scale), causal, interpret, window,
+                  _tiles_of(q, k.shape[2]))
     return o, (q, k, v, o, lse)
 
 
-def _vjp_bwd(causal, scale, interpret, res, do):
+def _vjp_bwd(causal, scale, interpret, window, res, do):
     q, k, v, o, lse = res
-    sc = _scale(q, scale)
-    return _bwd_impl(q, k, v, o, lse, do, sc, causal, interpret)
+    return _bwd_impl(q, k, v, o, lse, do, _scale(q, scale), causal,
+                     interpret, window, _tiles_of(q, k.shape[2]))
 
 
 flash_attention.defvjp(_vjp_fwd, _vjp_bwd)

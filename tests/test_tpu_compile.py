@@ -88,6 +88,31 @@ def test_flash_kernels_compile_for_the_chip_at_mla_head_size(one_chip):
         assert name in text
 
 
+@pytest.mark.parametrize("window", [0, 4096])
+def test_gqa_flash_kernels_compile_for_the_chip_at_the_cells_size(
+        one_chip, window):
+    """The `gqa` layer's core in the SmallThinker cell: 28 query heads
+    on 4 key/value heads, 128 wide, 16,384 positions, 1,024 x 1,024
+    tiles; with the window the three kernels carry their own names and
+    a band row is 5 tiles."""
+    from cxxnet_tpu.ops import pallas_attention as pa
+    q = jax.ShapeDtypeStruct((1, 28, 16384, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 16384, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    assert pa._tiles_of(q, 16384) == (1024, 1024)
+
+    def loss(q, k, v):
+        return jnp.sum(pa.flash_attention(q, k, v, True, None, False,
+                                          window).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    for kernel in ("fwd", "dq", "dkv"):
+        assert ("flash_win_" + kernel in text) == bool(window)
+        assert ("flash_" + kernel in text) == (not window)
+
+
 @pytest.mark.parametrize("route,dtype", [
     ("xla", "bfloat16"), ("pallas", "bfloat16"), ("pallas", "float32")])
 def test_kda_chunk_scan_compiles_for_the_chip_at_the_cells_size(
