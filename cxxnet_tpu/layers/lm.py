@@ -424,10 +424,15 @@ def rotary(x, theta: float):
 class GQALayer(_TableLayer):
     type_name = "gqa"
     #: q and o at nhead x head_dim, k, v and `lse`: at 16,384 positions
-    #: 0.28 GB unsaved a layer for the projections and the flash forward
-    #: again (PERF.md section 6, PR 35, has the milliseconds): under the
-    #: rate `mla` is kept at, but eight such layers' maps do not fit
-    #: beside the state of the cell that runs them, so `remat` takes them
+    #: 0.28 GB unsaved a layer for the projections, rotary and the flash
+    #: forward again, 18-30 ms: 5-9 MB a ms, far under the 25 at which
+    #: `mla` is kept. Memory no longer asks for it (with `lse` unpadded,
+    #: ops/pallas_attention.py `_LANE`, eight kept layers compile and
+    #: run beside 7.7 GB of state, 22.6% faster). It stays because on
+    #: the chip a kept layer's wo and wv gradients read 0.3-0.5% short
+    #: of the float32 reference's norms where a checkpointed layer's
+    #: read 0.1-0.2%, and the cell's limit lies between: PERF.md
+    #: section 6, PR 37, has the readings and what was ruled out
     remat_worthy = True
     stat_names = ("tiles",)
 

@@ -63,16 +63,17 @@ def _block_limits(d: int) -> Tuple[int, int]:
     heads take 512-wide tiles."""
     return (BLOCK_Q, BLOCK_K) if d <= 128 else (BLOCK_Q // 2, BLOCK_K // 2)
 
-# Mosaic requires the last two dims of every block shape to be
-# (sublane, lane)-tileable: divisible by (8, 128) or equal to the
-# array dims. A per-row stat laid out as (b, h, s) with block
-# (1, 1, bq) violates that (second-to-last block dim 1 vs array dim
-# h), so lse/delta ride a trailing broadcast dim of 8 - block
-# (1, 1, bq, 8) is (128, 8)-tiled, and 8 == the array dim satisfies
-# the lane rule (same trick as jax's reference flash kernel, which
-# uses a trailing MIN_BLOCK_SIZE=128; 8 costs 16x less HBM for the
-# saved residual).
-_STAT_LANES = 8
+# The row statistics (lse, delta) are (b, h, 1, s) float32, the
+# positions on lanes: the chip tiles the last two dims of an array by
+# (8, 128), so a short trailing dim is padded to 128 lanes ((b, h, s, 8)
+# was 224 MiB a `gqa` layer of 28 heads at 16,384 positions for
+# 1.75 MiB of data, and eight such layers did not fit unless they were
+# checkpointed: PERF.md section 6, PR 37), while XLA keeps this shape
+# unpadded (tiled T(1,128)). Mosaic wants the last two dims of a block
+# divisible by (8, 128) or equal to the array's: the block is
+# (1, 1, 1, bq), the 1 the array's and the query tile a multiple of 128
+# or the whole sequence (`_q_block`).
+_LANE = 128
 
 
 def _sublane(dtype) -> int:
@@ -158,10 +159,18 @@ def _q_tile(ki, j, bq: int, bk: int, window: int):
     return (ki * bk) // bq + j
 
 
+def _q_block(s: int, block: int, sub: int) -> int:
+    """The query tile: it is also the lane dim of a row statistic's
+    block, so the whole sequence or a multiple of 128."""
+    if s <= block and s % sub == 0:
+        return s
+    return _blocks(s, block, _LANE)
+
+
 def _tiles_of(q, sk: int) -> Tuple[int, int]:
     sub = _sublane(q.dtype)
     lq, lk = _block_limits(q.shape[3])
-    return _blocks(q.shape[2], lq, sub), _blocks(sk, lk, sub)
+    return _q_block(q.shape[2], lq, sub), _blocks(sk, lk, sub)
 
 
 def tile_share(q, window: int) -> float:
@@ -188,9 +197,13 @@ def _name(kernel: str, window: int) -> str:
 # forward
 # ---------------------------------------------------------------------------
 
-def _mask(s, q_off, kv_off, bq: int, bk: int, window: int):
-    qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    kpos = kv_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+def _mask(s, q_off, kv_off, bq: int, bk: int, window: int,
+          by_key: bool = False):
+    """Scores (bq, bk), or `by_key` their transpose (bk, bq), with the
+    pairs a query does not see at _NEG."""
+    shape, qdim = ((bk, bq), 1) if by_key else ((bq, bk), 0)
+    qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, shape, qdim)
+    kpos = kv_off + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - qdim)
     seen = kpos <= qpos
     if window:
         seen = seen & (qpos - kpos < window)
@@ -237,8 +250,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
     def _out():
         safe = jnp.where(l[:] > 0, l[:], 1.0)
         o_ref[0, 0] = (acc[:] / safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.broadcast_to(
-            (m[:] + jnp.log(safe))[:, None], (bq, _STAT_LANES))
+        lse_ref[0, 0] = (m[:] + jnp.log(safe))[None, :]
 
 
 def _specs(q, k, window: int, tiles: Tuple[int, int]):
@@ -260,8 +272,8 @@ def _specs(q, k, window: int, tiles: Tuple[int, int]):
 
     qspec = pl.BlockSpec((1, 1, bq, d), lambda b, h, qi, j: (b, h, qi, 0))
     kspec = pl.BlockSpec((1, 1, bk, d), kv_at)
-    rspec = pl.BlockSpec((1, 1, bq, _STAT_LANES),
-                         lambda b, h, qi, j: (b, h, qi, 0))
+    rspec = pl.BlockSpec((1, 1, 1, bq),
+                         lambda b, h, qi, j: (b, h, 0, qi))
     return bq, bk, nq, nkv, group, steps, qspec, kspec, rspec
 
 
@@ -287,8 +299,7 @@ def _fwd(q, k, v, scale, causal, interpret, window, tiles
         in_specs=[qspec, kspec, kspec],
         out_specs=[qspec, rspec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((b, h, s, _STAT_LANES),
-                                        jnp.float32)],
+                   jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32)],
@@ -304,12 +315,16 @@ def _fwd(q, k, v, scale, causal, interpret, window, tiles
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc, *, scale, causal, bq, bk, steps, window):
+               acc, lse, delta, *, scale, causal, bq, bk, steps, window):
     j = pl.program_id(3)
 
     @pl.when(j == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
+        # the tile's rows of statistics, turned once into the columns
+        # that every step of j subtracts from its (bq, bk) scores
+        lse[:] = jnp.expand_dims(lse_ref[0, 0, 0], -1)
+        delta[:] = jnp.expand_dims(delta_ref[0, 0, 0], -1)
 
     qi = pl.program_id(2)
     ki = _kv_tile(qi, j, bq, bk, steps, window)
@@ -323,11 +338,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             preferred_element_type=jnp.float32) * scale
         if causal:
             s = _mask(s, q_off, kv_off, bq, bk, window)
-        p = jnp.exp(s - lse_ref[0, 0][:, :1])
+        p = jnp.exp(s - lse[:])
         dov = jax.lax.dot_general(
             do_ref[0, 0], v_ref[0, 0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dov - delta_ref[0, 0][:, :1])
+        ds = p * (dov - delta[:])
         acc[:] += scale * jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -344,7 +359,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 steps, group, window):
     """One key tile of one key/value head: the inner dimension walks the
     query tiles of its band, once for each query head of the group, and
-    sums."""
+    sums. The scores are computed transposed, (bk, bq): a row of
+    statistics then broadcasts over the sublanes as it lies, and dk and
+    dv are plain products."""
     t = pl.program_id(3)
 
     @pl.when(t == 0)
@@ -360,21 +377,21 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q = q_ref[0, 0]
         k = k_ref[0, 0]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (bk, bq)
         if causal:
-            s = _mask(s, q_off, kv_off, bq, bk, window)
-        p = jnp.exp(s - lse_ref[0, 0][:, :1])            # (bq, bk)
+            s = _mask(s, q_off, kv_off, bq, bk, window, by_key=True)
+        p = jnp.exp(s - lse_ref[0, 0])
         do = do_ref[0, 0]
         accv[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # (bk, d)
         dov = jax.lax.dot_general(
-            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
+            v_ref[0, 0], do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dov - delta_ref[0, 0][:, :1])          # (bq, bk)
+        ds = p * (dov - delta_ref[0, 0])                 # (bk, bq)
         acck[:] += scale * jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # (bk, d)
 
     _run_live(_tile, causal, window, qi < nq, q_off, kv_off, bq, bk)
@@ -391,9 +408,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, interpret, window, tiles):
     bq, bk, nq, nkv, group, steps, qspec, kspec, rspec = _specs(
         q, k, window, tiles)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)  # (b, h, s)
-    delta = jnp.broadcast_to(delta[..., None],
-                             (*delta.shape, _STAT_LANES))
+                    axis=-1)[:, :, None, :]  # (b, h, 1, s), as lse
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -402,7 +417,9 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, interpret, window, tiles):
         in_specs=[qspec, kspec, kspec, qspec, rspec, rspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32)],
         compiler_params=_BY_QUERY,
         interpret=interpret,
         name=_name("dq", window),
@@ -417,11 +434,15 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, interpret, window, tiles):
         qi = _q_tile(ki, t % qsteps if group > 1 else t, bq, bk, window)
         if window:
             qi = jnp.minimum(qi, nq - 1)
-        return (b, hk * group + t // qsteps if group > 1 else hk, qi, 0)
+        return b, hk * group + t // qsteps if group > 1 else hk, qi
 
-    qspec2 = pl.BlockSpec((1, 1, bq, d), q_at)
+    def stat_at(*at):
+        b, h, qi = q_at(*at)
+        return b, h, 0, qi
+
+    qspec2 = pl.BlockSpec((1, 1, bq, d), lambda *at: q_at(*at) + (0,))
     kspec2 = pl.BlockSpec((1, 1, bk, d), lambda b, hk, ki, t: (b, hk, ki, 0))
-    rspec2 = pl.BlockSpec((1, 1, bq, _STAT_LANES), q_at)
+    rspec2 = pl.BlockSpec((1, 1, 1, bq), stat_at)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq, steps=qsteps, group=group,
@@ -497,13 +518,15 @@ def _backend_ok() -> bool:
 
 def _tile_ok(q, sk: int) -> bool:
     """Mosaic tileability: both score-tile dims must land on sublane
-    multiples (the fallback-divisor path is for interpret mode only)
-    and tiny dims would underfill the MXU for no win."""
+    multiples and the query tile, a row statistic's lanes, on the whole
+    sequence or a multiple of 128 (the fallback-divisor path is for
+    interpret mode only); tiny dims would underfill the MXU for no
+    win."""
     sub = _sublane(q.dtype)
+    bq, bk = _tiles_of(q, sk)
     return (q.shape[2] >= sub and sk >= sub and q.shape[3] >= 8
-            and _blocks(q.shape[2], _block_limits(q.shape[3])[0], sub)
-            % sub == 0
-            and _blocks(sk, _block_limits(q.shape[3])[1], sub) % sub == 0)
+            and bq % sub == 0 and bk % sub == 0
+            and (bq == q.shape[2] or bq % _LANE == 0))
 
 
 def use_flash(q) -> bool:

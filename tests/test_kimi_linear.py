@@ -453,6 +453,34 @@ def test_remat_runs_the_attention_core_once(remat, monkeypatch):
         "jvp(mla.m1)/scores", "transpose(jvp(mla.m1))/scores"]
 
 
+def test_mla_core_at_192_wide_heads_keeps_a_row_of_statistics(monkeypatch):
+    """The flash kernels as the `mla` layer runs them (192-wide heads,
+    so half-size tiles; equal head counts, no window), in interpret
+    mode against `naive_attention`, forward and the three gradients;
+    the custom vjp keeps q, k, v, o and `lse` as (b, h, 1, s), the
+    positions last, with no trailing dim for the chip to pad."""
+    from cxxnet_tpu.ops import attention as ops_attn
+    from cxxnet_tpu.ops import pallas_attention as pa
+    monkeypatch.setattr(pa, "BLOCK_Q", 32)
+    monkeypatch.setattr(pa, "BLOCK_K", 32)
+    r = np.random.RandomState(2)
+    q, k, v = (jnp.asarray(r.randn(1, 4, 64, 192), jnp.float32)
+               for _ in range(3))
+    assert pa._tiles_of(q, 64) == (16, 16)
+    out, res = pa._vjp_fwd(q, k, v, True, None, True, 0)
+    assert [x.shape for x in res] == 4 * [(1, 4, 64, 192)] + [(1, 4, 1, 64)]
+    np.testing.assert_allclose(
+        out, ops_attn.naive_attention(q, k, v, causal=True),
+        rtol=1e-5, atol=1e-5)
+    gk = jax.grad(lambda *a: jnp.sum(jnp.cos(pa.flash_attention(
+        *a, True, None, True))), (0, 1, 2))(q, k, v)
+    gn = jax.grad(lambda *a: jnp.sum(jnp.cos(ops_attn.naive_attention(
+        *a, causal=True))), (0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", gk, gn):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5,
+                                   err_msg=f"d{name}")
+
+
 @pytest.mark.parametrize("remat", ["0", "1"])
 def test_remat_runs_the_expert_loop_once_a_direction(remat):
     """One `moe` layer: `_dropless_fwd`'s tile loop once in the forward,

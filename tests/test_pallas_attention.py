@@ -66,6 +66,67 @@ def test_gradients_match_naive(causal, small_blocks):
             err_msg=f"d{name} mismatch")
 
 
+@pytest.mark.parametrize("h,hkv,s,window", [
+    (2, 2, 32, 0),        # full causal attention
+    (4, 4, 64, 24),       # a window: whole tiles lie left of it
+    (14, 2, 32, 0),       # 7:1 grouped heads, as the `gqa` layers run
+    (7, 1, 40, 16),       # ... under a window
+])
+def test_kernels_with_statistics_on_lanes_match_naive(h, hkv, s, window,
+                                                      small_blocks):
+    """Forward, dq, dk and dv against `naive_attention`, and what the
+    custom vjp keeps for the backward: the row statistic is (b, h, 1,
+    s), the positions last (on the chip: on lanes), never a trailing
+    dim of 8 that the chip's tiling would pad to 128."""
+    r = np.random.RandomState(1)
+    q = jnp.asarray(r.randn(2, h, s, 8), jnp.float32)
+    k = jnp.asarray(r.randn(2, hkv, s, 8), jnp.float32)
+    v = jnp.asarray(r.randn(2, hkv, s, 8), jnp.float32)
+
+    def kern(q, k, v):
+        return PA.flash_attention(q, k, v, True, None, True, window)
+
+    def naive(q, k, v):
+        return A.naive_attention(q, k, v, causal=True, window=window)
+
+    out, res = PA._vjp_fwd(q, k, v, True, None, True, window)
+    assert [x.shape for x in res] == [q.shape, k.shape, v.shape, q.shape,
+                                      (2, h, 1, s)]
+    np.testing.assert_allclose(out, naive(q, k, v), rtol=1e-5, atol=1e-5)
+    # lse is the log of the row's sum of exponentials, row by row
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q,
+                        jnp.repeat(k, h // hkv, axis=1)) / np.sqrt(8.0)
+    qi, ki = np.arange(s)[:, None], np.arange(s)[None, :]
+    seen = (ki <= qi) & ((qi - ki < window) if window else True)
+    np.testing.assert_allclose(
+        res[4][:, :, 0], jax.nn.logsumexp(
+            jnp.where(seen, scores, -jnp.inf), axis=-1),
+        rtol=1e-5, atol=1e-5)
+    gk = jax.grad(lambda *a: jnp.sum(jnp.cos(kern(*a))), (0, 1, 2))(q, k, v)
+    gn = jax.grad(lambda *a: jnp.sum(jnp.cos(naive(*a))), (0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", gk, gn):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5,
+                                   err_msg=f"d{name}")
+
+
+def test_query_tile_is_the_statistics_lane_dim():
+    """A row statistic's block is (1, 1, 1, bq): Mosaic takes a last
+    block dim that is a multiple of 128 or the whole array's, so the
+    query tile is chosen so, and a length that has no such divisor
+    goes the XLA route."""
+    def tiles(s, d=128, dtype=jnp.bfloat16):
+        q = jax.ShapeDtypeStruct((1, 4, s, d), dtype)
+        return PA._tiles_of(q, s), PA._tile_ok(q, s)
+
+    assert tiles(16384) == ((1024, 1024), True)      # the `gqa` cell
+    assert tiles(8192, 192) == ((512, 512), True)    # the `mla` cell
+    assert tiles(784, dtype=jnp.float32) == ((784, 784), True)   # whole
+    # 1,536 = 12 x 128: 768 both ways; 1,200 has no divisor that is a
+    # multiple of 128 and is too long for one tile
+    assert tiles(1536) == ((768, 768), True)
+    assert tiles(1200) == ((600, 400), False)
+
+
 def test_bf16_forward(small_blocks):
     q, k, v = _qkv(s=16)
     ref = A.naive_attention(q, k, v, causal=True)

@@ -51,10 +51,12 @@ def conf_text() -> str:
 
 @pytest.fixture
 def kernels(monkeypatch):
-    """The flash kernels in interpret mode, 8 x 8 tiles."""
+    """The flash kernels in interpret mode, 8 x 8 tiles (a query tile
+    is a row statistic's lanes: eight to the lane here, for `_tile_ok`)."""
     monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
     monkeypatch.setattr(pa, "BLOCK_Q", 8)
     monkeypatch.setattr(pa, "BLOCK_K", 8)
+    monkeypatch.setattr(pa, "_LANE", 8)
 
 
 @pytest.fixture
@@ -144,6 +146,10 @@ def test_window_kernels_are_naive_attention_with_the_same_mask(
 
     np.testing.assert_allclose(kern(q, k, v), naive(q, k, v),
                                rtol=1e-5, atol=1e-5)
+    # what the backward kernels read besides q, k, v, o: a row of
+    # positions a head, (b, h, 1, s)
+    assert pa._vjp_fwd(q, k, v, True, None, True, window)[1][4].shape == (
+        2, h, 1, s)
     np.testing.assert_allclose(
         ops_attn.blockwise_attention(q, k, v, causal=True, window=window,
                                      kv_block=8),
@@ -324,6 +330,8 @@ def test_eight_layers_lower_one_full_and_one_window_set_of_kernels(
     assert bodies == {"flash_fwd": 2, "flash_dq": 1, "flash_dkv": 1,
                       "flash_win_fwd": 2, "flash_win_dq": 1,
                       "flash_win_dkv": 1}
+    # the kernels' row statistics: positions last, one row a head
+    assert "2x4x1x64xf32" in text and "2x4x64x8xf32" not in text
     # and the scopes a reader of the trace finds the layers' parts by
     stacks = {stack for _, stack in _step_eqns(t, np.zeros(
         (2, 1, 64, 1), np.int32))}

@@ -113,6 +113,68 @@ def test_gqa_flash_kernels_compile_for_the_chip_at_the_cells_size(
         assert ("flash_" + kernel in text) == (not window)
 
 
+@pytest.mark.parametrize("checkpointed", [8, 0])
+def test_smallthinker_step_fits_the_chip(one_chip, monkeypatch,
+                                         checkpointed):
+    """The whole train step of `smallthinker_21b_a3b.train_seq16k` as
+    the cell states it (`remat = 1`; 643,852,800 parameters under Adam,
+    one row of 16,384 positions), compiled from abstract state: the
+    chip's compiler takes it, and the kernels' row statistics in it are
+    (1, 28, 1, 16384), 1.75 MiB a layer. As the (1, 28, 16384, 8) they
+    were, each padded to 224 MiB, the eight `gqa` layers had to be
+    checkpointed: without (`checkpointed = 0`: `GQALayer.remat_worthy`
+    turned off here) the compiler refused the step, `Used 16.12G of
+    15.75G hbm`. Now it fits either way; why the layers are checkpointed
+    all the same is in PERF.md section 6, PR 37."""
+    from benchmark import run as bench
+    from cxxnet_tpu.layers import lm
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+    from cxxnet_tpu.ops import pallas_attention as pa
+    from cxxnet_tpu.utils.config import parse_config_string
+    monkeypatch.setattr(pa, "_backend_ok", lambda: True)
+    monkeypatch.setattr(lm.GQALayer, "remat_worthy", bool(checkpointed))
+    cell = bench.load_cell("smallthinker_21b_a3b.train_seq16k")
+    overrides = dict(cell.cfg["overrides"], dev="cpu", silent="1")
+    t = NetTrainer()
+    for k, v in parse_config_string(cell.cfg["conf_text"]):
+        if k not in overrides:
+            t.set_param(k, v)
+    for k, v in overrides.items():
+        t.set_param(k, v)
+    t.net_cfg.configure(t.cfg_pairs)
+    t._build_net()
+    assert t.net.remat and len(t.net.checkpointed) == checkpointed
+
+    def state():
+        t._init_state(t.net.init_params(jax.random.PRNGKey(0)))
+        return t.state
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    seq = int(cell.traffic["seq_len"])
+    args = jax.tree.map(on_chip, (
+        jax.eval_shape(state),
+        jax.ShapeDtypeStruct((1, 1, seq, 1), jnp.int32), (),
+        {f: jax.ShapeDtypeStruct((1, seq), jnp.float32)
+         for f in t.net_cfg.label_name_map},
+        jax.ShapeDtypeStruct((1,), jnp.float32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    t.state = None                       # (tracers, left by eval_shape)
+    compiled = jax.jit(t._train_step.__wrapped__,
+                       donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    assert "f32[1,28,16384,8]" not in text
+    assert "f32[1,28,1,16384]" in text
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv", "flash_win_fwd",
+                   "flash_win_dq", "flash_win_dkv"):
+        assert f"/{kernel}/" in text
+    assert ("rematted_computation/scores" in text) == bool(checkpointed)
+
+
 @pytest.mark.parametrize("route,dtype", [
     ("xla", "bfloat16"), ("pallas", "bfloat16"), ("pallas", "float32")])
 def test_kda_chunk_scan_compiles_for_the_chip_at_the_cells_size(
