@@ -22,7 +22,8 @@ from a seed into the output directory:
   lm       one `kda` layer at the Kimi conf's widths through both
            routes of its chunk-local part (the Pallas kernel pair the
            chip takes, the XLA code everything else takes): outputs
-           and gradients agree; then task=train over
+           and gradients agree; one `gconv` layer at the LFM2 conf's
+           widths in bf16 against float32; then task=train over
            examples/LongSeq/kimi_linear_5l.conf, three steps, a
            falling loss.
   four     with >= 4 devices: the train leg again on `dev = tpu:0-3` -
@@ -506,6 +507,12 @@ def kernel_leg(dry: bool) -> None:
     for window in (0, 8 if dry else 1024):
         flash_case(*((2, 14, 32, 16) if dry else (1, 14, 4096, 128)),
                    True, hkv=2, window=window)
+    # a head of 64, half a lane tile, four query heads a key/value head
+    # (examples/LongSeq/lfm2_5l.conf: the score product contracts over
+    # 64 of the MXU's 128 rows, the 64-wide minor dimension is padded to
+    # 128 lanes in HBM)
+    flash_case(*((2, 8, 32, 64) if dry else (1, 16, 4096, 64)), True,
+               hkv=2 if dry else 4)
     # the compiler takes the seq_mnist shape, but that example's layer
     # never sends it: _tile_ok declines d < 8 and a 28-row bf16 tile,
     # and AttentionLayer._core routes it to blockwise XLA
@@ -604,6 +611,42 @@ def kda_routes(dry: bool) -> None:
         close(f"{tag}: d{name}", g_k[name], g_x[name], 2e-2, 2e-2)
 
 
+def gconv_check(dry: bool) -> None:
+    """One `gconv` layer at the LFM2 conf's widths (2048 wide, 3 taps)
+    over 4,096 positions: bf16 as a step runs it, forward and the
+    gradients of every parameter and of the input, against the same
+    layer in float32 at "highest" matmul precision; the kernel leg's
+    tolerance."""
+    import jax
+    import jax.numpy as jnp
+    from cxxnet_tpu.layers import create_layer
+    e, t = (32, 64) if dry else (2048, 4096)
+    shape = (1, 1, t, e)
+    lay = create_layer("gconv", "g")
+    lay.set_param("init_sigma", "0.02")
+    lay.infer_shapes([shape])
+    p = lay.init_params(jax.random.PRNGKey(0), [shape])
+    x = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(2), shape, jnp.float32)
+
+    def run(dtype):
+        def loss(p, x):
+            pc = jax.tree.map(lambda a: a.astype(dtype), p)
+            (y,) = lay.apply(pc, [x.astype(dtype)], train=True)
+            return jnp.sum(y.astype(jnp.float32) * w), y
+        (_, y), (gp, gx) = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(p, x)
+        return y, dict(gp, x=gx)
+
+    y_b, g_b = run(jnp.bfloat16)
+    with jax.default_matmul_precision("highest"):
+        y_f, g_f = run(jnp.float32)
+    tag = f"gconv {t} positions x {e}, bf16 v float32"
+    close(f"{tag}: out", y_b, y_f, 2e-2, 2e-2)
+    for name in sorted(g_f):
+        close(f"{tag}: d{name}", g_b[name], g_f[name], 2e-2, 2e-2)
+
+
 def lm_leg(out: str, dry: bool) -> None:
     """task=train over examples/LongSeq/kimi_linear_5l.conf as
     committed (the five Kimi-Linear layers at their published widths,
@@ -615,6 +658,7 @@ def lm_leg(out: str, dry: bool) -> None:
     import jax
     from cxxnet_tpu.utils.config import parse_config_string
     kda_routes(dry)
+    gconv_check(dry)
     d = os.path.join(out, "lm")
     shutil.rmtree(d, ignore_errors=True)
     os.makedirs(d)

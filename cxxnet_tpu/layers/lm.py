@@ -1,7 +1,8 @@
 """Language-model layers over sequence nodes (batch, 1, seq, embed):
 token embedding, RMS norm, gated FFN, the two token mixers of the
-Kimi-Linear family (`kda`, `mla`), grouped-query attention with rotary
-and a window (`gqa`) and the untied head with its next-token loss.
+Kimi-Linear family (`kda`, `mla`), the double-gated short convolution
+of the LFM2 family (`gconv`), grouped-query attention with rotary, a
+window and QK-norm (`gqa`) and the untied head with its next-token loss.
 
 embed     node of token ids (batch, 1, seq, 1), INTEGER, -> (batch, 1,
           seq, nhidden). Keys: nvocab, nhidden. Ids stay integers from
@@ -16,6 +17,11 @@ kda       Kimi Delta Attention (ops/kda.py): q, k, v through a causal
           chunks, a per-head RMS norm and a low-rank sigmoid output
           gate. Keys: nhead, head_dim, conv_size (4), gate_rank,
           kda_chunk (64), eps.
+gconv     the double-gated short convolution: (B, C, z) = the three
+          thirds of x Win; u = B * z; c = the causal depthwise conv of
+          `conv_size` taps over time of u (zeros before the start, no
+          bias); out = (C * c) Wout. NO activation anywhere. Key:
+          conv_size (3).
 mla       multi-head latent attention WITHOUT rotary (`mla_use_nope`):
           keys and values come from a `kv_rank`-wide normalised latent,
           a `qk_rope_dim`-wide key part is shared by all heads, queries
@@ -27,7 +33,11 @@ mla       multi-head latent attention WITHOUT rotary (`mla_use_nope`):
           are padded to the query width and cut back.
 gqa       grouped-query causal attention: `nhead` query heads read the
           `nkvhead` key/value heads, `nhead // nkvhead` to one, all
-          `head_dim` wide, no bias. `rope_theta` > 0 turns q and k by a
+          `head_dim` wide, no bias. `qk_norm = 1`: every query head and
+          every key head is RMS-normed over its `head_dim` entries (one
+          slope vector for all query heads, `qnorm`, one for all key
+          heads, `knorm`; `eps`) BEFORE the rotary embedding.
+          `rope_theta` > 0 turns q and k by a
           rotary embedding (the halves of a head paired, over the whole
           head, positions 0..seq-1); 0 = no positional encoding.
           `window` > 0: a query sees the `window` positions up to its
@@ -37,7 +47,8 @@ gqa       grouped-query causal attention: `nhead` query heads read the
           else through `blockwise_attention`, which masks. Counter
           `tiles`: the score tiles the core computes over those of a
           full causal layer (1 on the XLA route, which skips none).
-          Keys: nhead, nkvhead, head_dim, window (0), rope_theta (0).
+          Keys: nhead, nkvhead, head_dim, window (0), rope_theta (0),
+          qk_norm (0), eps (1e-5).
 lm_head   inputs: the final hidden node and the token node. Output: the
           logits (batch, 1, seq, nvocab) - what `task = pred` and
           `extract` read. In training its loss term is the mean over
@@ -319,6 +330,56 @@ class KDALayer(_TableLayer):
 
 
 @register_layer
+class GConvLayer(_TableLayer):
+    type_name = "gconv"
+    #: x Win (three thirds), B * z, the conv and C * c: at 32,768
+    #: positions of 2,048 0.8 GB unsaved a layer for a second forward
+    #: of 7.0 ms (one 2048 x 6144 product and three elementwise
+    #: passes; my chip runs, PR 39): about 115 MB a ms, more than `kda`
+    #: or `glu_ffn` buy. (The one conf that runs it fits with every
+    #: layer kept and says `remat = 0`.)
+    remat_worthy = True
+
+    def __init__(self, name: str = ""):
+        super().__init__(name)
+        self.conv_size = 3
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == "conv_size":
+            self.conv_size = int(val)
+
+    def shapes(self, in_shapes):
+        self.check_one_to_one(in_shapes)
+        _seq(in_shapes[0], "gconv")
+        if self.conv_size <= 0:
+            raise ValueError("gconv: conv_size must be 1 or more")
+        return [in_shapes[0]]
+
+    def table(self, in_shapes):
+        e = in_shapes[0][3]
+        return [("win", (e, 3 * e), "normal"),
+                ("conv", (self.conv_size, e), "conv"),
+                ("wout", (e, e), "normal")]
+
+    def apply(self, params, inputs, *, train, rng=None):
+        p = params
+        b, _, t, e = inputs[0].shape
+        x = inputs[0].reshape(b, t, e)
+        with jax.named_scope("proj"):
+            bcz = _lin(x, p["win"])
+        with jax.named_scope("gate"):
+            u = bcz[..., :e] * bcz[..., 2 * e:]
+        with jax.named_scope("conv"):
+            c = short_conv(u, p["conv"])
+        with jax.named_scope("gate"):
+            y = bcz[..., e:2 * e] * c
+        with jax.named_scope("out"):
+            out = _lin(y, p["wout"])
+        return [out.reshape(b, 1, t, e)]
+
+
+@register_layer
 class MLALayer(_TableLayer):
     type_name = "mla"
     #: q, k, padded v, o, the latents and `lse`: 0.55 GB unsaved would
@@ -443,6 +504,8 @@ class GQALayer(_TableLayer):
         self.head_dim = 0
         self.window = 0
         self.rope_theta = 0.0
+        self.qk_norm = 0
+        self.eps = 1e-5
         self.kv_block = 512
 
     def set_param(self, name, val):
@@ -457,6 +520,10 @@ class GQALayer(_TableLayer):
             self.window = int(val)
         if name == "rope_theta":
             self.rope_theta = float(val)
+        if name == "qk_norm":
+            self.qk_norm = int(val)
+        if name == "eps":
+            self.eps = float(val)
 
     def shapes(self, in_shapes):
         self.check_one_to_one(in_shapes)
@@ -473,10 +540,13 @@ class GQALayer(_TableLayer):
 
     def table(self, in_shapes):
         e, d = in_shapes[0][3], self.head_dim
-        return [("wq", (e, self.nhead * d), "normal"),
-                ("wk", (e, self.nkvhead * d), "normal"),
-                ("wv", (e, self.nkvhead * d), "normal"),
-                ("wo", (self.nhead * d, e), "normal")]
+        table = [("wq", (e, self.nhead * d), "normal"),
+                 ("wk", (e, self.nkvhead * d), "normal"),
+                 ("wv", (e, self.nkvhead * d), "normal"),
+                 ("wo", (self.nhead * d, e), "normal")]
+        if self.qk_norm:
+            table += [("qnorm", (d,), "ones"), ("knorm", (d,), "ones")]
+        return table
 
     def apply_with_stats(self, params, inputs, *, train, rng=None,
                          mask=None):
@@ -493,6 +563,10 @@ class GQALayer(_TableLayer):
             q = heads(p["wq"], self.nhead)
             k = heads(p["wk"], self.nkvhead)
             v = heads(p["wv"], self.nkvhead)
+        if self.qk_norm:
+            with jax.named_scope("qknorm"):
+                q = rms_norm(q, p["qnorm"], self.eps)
+                k = rms_norm(k, p["knorm"], self.eps)
         if self.rope_theta:
             with jax.named_scope("rope"):
                 q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)

@@ -177,14 +177,13 @@ def test_layer_alone_matches_the_reference(kind):
     program_against_reference(text, {}, tokens())
 
 
-def test_five_layer_stack_matches_the_reference():
-    """The example conf at tiny widths: loss and gradients of all 33
-    conf layers' leaves, then three Adam steps against the reference's
-    (the parameters' change, to 5e-3 of its norm, leaf by leaf:
-    Adam divides by sqrt(m2) + 1e-8, which turns the last digits of a
-    gradient entry near 1e-8 into that entry's step)."""
-    tok = tokens()
-    trainer, ref, params = program_against_reference(conf_text(), TINY, tok)
+def adam_steps_against_reference(trainer, ref, params, tok):
+    """Three Adam steps of the program (one is behind it:
+    `program_against_reference` read its gradient from it) against the
+    reference's, leaf by leaf: the parameters' change to 5e-3 of its
+    norm (Adam divides by sqrt(m2) + 1e-8, which turns the last digits
+    of a gradient entry near 1e-8 into that entry's step).
+    tests/test_smallthinker.py and tests/test_lfm2.py use it too."""
     mom = jax.tree.map(lambda a: {"m1": jnp.zeros_like(a),
                                   "m2": jnp.zeros_like(a)}, params)
     p = params
@@ -207,6 +206,17 @@ def test_five_layer_stack_matches_the_reference():
             room = 5e-3 * np.linalg.norm(dr) + np.sqrt(dr.size) * np.spacing(
                 np.abs(np.asarray(w)).max())
             assert np.linalg.norm(dp - dr) <= room, (lk, pn)
+
+
+def test_five_layer_stack_matches_the_reference():
+    """The example conf at tiny widths: loss and gradients of all 33
+    conf layers' leaves, then three Adam steps against the reference's
+    (the parameters' change, to 5e-3 of its norm, leaf by leaf:
+    Adam divides by sqrt(m2) + 1e-8, which turns the last digits of a
+    gradient entry near 1e-8 into that entry's step)."""
+    tok = tokens()
+    trainer, ref, params = program_against_reference(conf_text(), TINY, tok)
+    adam_steps_against_reference(trainer, ref, params, tok)
     counted = trainer.fetch_counters()
     assert {k.split(".")[1] for k in counted} == {"held", "load", "dropped"}
     assert all(v == 0 for k, v in counted.items() if k.endswith("dropped"))

@@ -30,8 +30,9 @@ from cxxnet_tpu.layers import create_layer, lm
 from cxxnet_tpu.ops import attention as ops_attn
 from cxxnet_tpu.ops import pallas_attention as pa
 from cxxnet_tpu.utils.config import parse_config_string
-from test_kimi_linear import (ROOT, _step_eqns, batch_of, build, first_step,
-                              program_against_reference, tokens)
+from test_kimi_linear import (ROOT, _step_eqns, adam_steps_against_reference,
+                              build, first_step, program_against_reference,
+                              tokens)
 
 CONF = os.path.join(ROOT, "examples", "LongSeq", "smallthinker_8l.conf")
 TINY = {
@@ -72,22 +73,7 @@ def _stack_against_reference():
     tok = tokens()
     trainer, ref, params = program_against_reference(
         conf_text(), TINY, tok, ref_mod)
-    mom = jax.tree.map(lambda a: {"m1": jnp.zeros_like(a),
-                                  "m2": jnp.zeros_like(a)}, params)
-    p = params
-    for k in range(3):
-        _, g = ref.grads(p, tok[:, 0, :, 0])
-        p, mom = ref.update(p, mom, g, k)
-    for _ in range(2):
-        trainer.update(batch_of(tok))
-    got = jax.device_get(trainer.state["params"])
-    for lk, d in p.items():
-        for pn, w in d.items():
-            dr = np.asarray(w) - np.asarray(params[lk][pn])
-            dp = got[lk][pn] - np.asarray(params[lk][pn])
-            room = 5e-3 * np.linalg.norm(dr) + np.sqrt(dr.size) * np.spacing(
-                np.abs(np.asarray(w)).max())
-            assert np.linalg.norm(dp - dr) <= room, (lk, pn)
+    adam_steps_against_reference(trainer, ref, params, tok)
     return trainer
 
 
