@@ -263,6 +263,9 @@ class NetTrainer:
         # executable-registry registration per compiled program shape;
         # steady-state dispatches pay a dict hit
         self._flight_fps: Dict[Any, str] = {}
+        # records of this trainer's set-up spans (spans.SETUP_*), in the
+        # order they opened: fetch_setup()
+        self._setup_spans: List[Dict[str, Any]] = []
         # TVM-style tuning cache (nnet/tuning.py, tools/autotune.py):
         # tuned knob values are DEFAULTS - explicitly-set config keys
         # always win (tracked per key at set_param time)
@@ -513,12 +516,16 @@ class NetTrainer:
         # param_server=dist -> join the multi-controller job before any
         # device is touched (replaces InitParamServer,
         # nnet_impl-inl.hpp:376-390)
-        distributed.init_from_config(self.cfg_pairs)
-        self.net_cfg.configure(self.cfg_pairs)
-        self._build_net()
-        key = jax.random.PRNGKey(self.seed)
-        params = self.net.init_params(key)
-        self._init_state(params)
+        self._setup_spans = []
+        with self._setup_span(spans.SETUP_INIT):
+            distributed.init_from_config(self.cfg_pairs)
+            with self._setup_span(spans.SETUP_BUILD):
+                self.net_cfg.configure(self.cfg_pairs)
+                self._build_net()
+            with self._setup_span(spans.SETUP_PARAMS):
+                params = self.net.init_params(jax.random.PRNGKey(self.seed))
+            with self._setup_span(spans.SETUP_STATE):
+                self._init_state(params)
         self.epoch = 0
         self._epoch_base = 0
         self._step_counter = 0
@@ -1453,6 +1460,21 @@ class NetTrainer:
         return fp
 
     @contextlib.contextmanager
+    def _setup_span(self, name: str, **fields):
+        """A set-up span (telemetry.span) whose record fetch_setup()
+        returns."""
+        with telemetry.span(name, **fields) as rec:
+            self._setup_spans.append(rec)
+            yield rec
+
+    def _stamp_compile(self, key, rec) -> None:
+        """Give the `/executables` entry of a train-step shape (which
+        _flight_record registered) the compile or cache-load seconds of
+        its `setup.step` span."""
+        telemetry.get().executables.stamp_compile(
+            self._flight_fps[key], rec["compile_s"] + rec["cache_load_s"])
+
+    @contextlib.contextmanager
     def _flight_record(self, site: str, key, kind: str, name: str,
                        shape, nbytes: int, donated: int = 0,
                        bucket: Optional[int] = None, fields=None):
@@ -1627,6 +1649,18 @@ class NetTrainer:
             self.state, batch.data, batch.extras, batch.labels,
             batch.mask, jax.random.PRNGKey(0)).compile().as_text()
 
+    def fetch_setup(self) -> List[Dict[str, Any]]:
+        """This trainer's set-up span records, in the order they opened:
+        `setup.init` with its children `setup.build`, `setup.params`,
+        `setup.state`, then one `setup.step` a train-step shape. Each a
+        dict of `name`, `parent` (the enclosing span's path or None),
+        `start`, `end` (`time.perf_counter()`), and what JAX did
+        meanwhile (telemetry/jax_watch.py): `trace_s`, `lower_s`,
+        `compile_s`, `cache_load_s`, `traces`, `cache_hits`,
+        `cache_misses`. A span still open has `end` None. Waits for
+        nothing on the device."""
+        return [dict(r) for r in self._setup_spans]
+
     def fetch_counters(self) -> Dict[str, float]:
         """What the layers counted in the last step dispatched
         (`<layer key>.<name>`, layers/moe.py), fetched from the step's
@@ -1750,14 +1784,27 @@ class NetTrainer:
             # readback (the sync a hung backend wedges in) so a stall dump
             # names this exact executable.
             ok = None
+            key = ("train_step", tuple(gdata.shape))
+            first = key not in self._flight_fps
             with self._flight_record(
-                    "train_step", ("train_step", tuple(gdata.shape)),
+                    "train_step", key,
                     kind="train", name=f"train_step@b{gdata.shape[0]}",
                     shape=gdata.shape, nbytes=gdata.nbytes, donated=1,
                     fields={"step": step_idx}):
                 with TraceAnnotation(spans.TRAIN_CALL):
-                    out = self._train_step(
-                        self.state, gdata, gextras, glabels, gmask, rng)
+                    if first:
+                        # trace, lowering, compile or cache load, enqueue;
+                        # called from this frame, as every later step is
+                        with self._setup_span(
+                                spans.SETUP_STEP,
+                                shape=str(tuple(gdata.shape))) as rec:
+                            out = self._train_step(
+                                self.state, gdata, gextras, glabels, gmask,
+                                rng)
+                        self._stamp_compile(key, rec)
+                    else:
+                        out = self._train_step(
+                            self.state, gdata, gextras, glabels, gmask, rng)
                 if self._check_nan_built:
                     # divergence guard: the per-step finite flag must be
                     # read back (a device sync - the cost of check_nan=1;
@@ -1848,18 +1895,29 @@ class NetTrainer:
             # flight-recorder entry: one per K-step chunk dispatch, same
             # contract as update()'s (in-flight across the guard readback)
             fin = None
+            key = ("train_chunk", k, tuple(chunk.data.shape))
+            first = key not in self._flight_fps
             with self._flight_record(
-                    "train_chunk",
-                    ("train_chunk", k, tuple(chunk.data.shape)),
+                    "train_chunk", key,
                     kind="train",
                     name=f"train_chunk@K{k}b{chunk.data.shape[1]}",
                     shape=chunk.data.shape, nbytes=chunk.data.nbytes,
                     donated=1, bucket=chunk.data.shape[1],
                     fields={"step": first_step, "steps": k}):
                 with TraceAnnotation(spans.TRAIN_CALL):
-                    self.state, losses, finites = self._train_chunk(
-                        self.state, chunk.data, chunk.extras,
-                        chunk.labels, chunk.mask, step_idx, base_rng)
+                    if first:
+                        with self._setup_span(
+                                spans.SETUP_STEP,
+                                shape=str(tuple(chunk.data.shape))) as rec:
+                            out = self._train_chunk(
+                                self.state, chunk.data, chunk.extras,
+                                chunk.labels, chunk.mask, step_idx, base_rng)
+                        self._stamp_compile(key, rec)
+                    else:
+                        out = self._train_chunk(
+                            self.state, chunk.data, chunk.extras,
+                            chunk.labels, chunk.mask, step_idx, base_rng)
+                    self.state, losses, finites = out
                 if self._check_nan_built:
                     # ONE readback per chunk (vs one per step streamed) -
                     # the whole point of the fused dispatch; the guard then

@@ -9,12 +9,14 @@ is a wall-clock round print, cxxnet_main.cpp:376-387). Three pieces:
   in-process; per-step/per-batch instruments (train.*, io.prefetch.*)
   are recorded only while a sink is armed - their timing costs a
   device sync the disabled path must not pay;
-- **span timers**: ``with span("train.step"): ...`` observes the
-  duration into a histogram of the same name and, when an event sink
+- **span timers**: ``with span("setup.init") as rec: ...`` observes
+  the duration into a histogram of the same name, holds a
+  ``jax.profiler.TraceAnnotation`` of it open and, when an event sink
   is configured, emits a ``span`` event. Spans nest - the recorded
   name is the "/"-joined path of the enclosing spans on this thread.
-  With no sink configured ``span()`` returns a shared no-op context,
-  so the disabled path costs one attribute check;
+  ``rec`` is the span's record: start, end, parent and what JAX traced,
+  lowered, compiled or loaded meanwhile (jax_watch.py). For work done
+  once a process or a round; per-step sites use bare annotations;
 - a **central logger** with JSONL event/metric sinks (``log_file=`` /
   ``metrics_file=`` config keys, ``log_format=json|text``, periodic
   ``heartbeat_secs=`` snapshots). ``stdout()`` / ``stderr()`` write
@@ -38,6 +40,7 @@ import threading
 import time
 from typing import Dict, Optional, Tuple
 
+from cxxnet_tpu.telemetry import jax_watch
 from cxxnet_tpu.telemetry.flight import (
     ExecutableRegistry, FlightRecorder)
 from cxxnet_tpu.telemetry.health import HealthState
@@ -60,51 +63,61 @@ __all__ = [
 RECENT_SPANS = 64
 
 
-class _NullSpan:
-    """Reusable no-op context manager: the disabled span path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class _Span:
-    """Timed span: pushes its name on the thread's span stack so
-    nested spans record "outer/inner" paths."""
+    """Timed span: pushes its name on the thread's span stack so nested
+    spans record "outer/inner" paths, and holds a
+    `jax.profiler.TraceAnnotation` of that path open, so a profiler
+    trace running meanwhile shows it on the device trace's clock. The
+    context's value is the span's record, a dict completed on exit:
+    `name`, `parent` (the enclosing span's path, None at the top),
+    `start` and `end` (`time.perf_counter()`), and the deltas over the
+    span of JAX's build counters (jax_watch.py: `trace_s`, `lower_s`,
+    `compile_s`, `cache_load_s`, `traces`, `cache_hits`,
+    `cache_misses`). The counters are process-wide: work another thread
+    has JAX build meanwhile is counted too."""
 
-    __slots__ = ("_tel", "_name", "_fields", "_path", "_t0")
+    __slots__ = ("_tel", "_name", "_fields", "_path", "_ann", "_c0",
+                 "_rec")
 
     def __init__(self, tel: "Telemetry", name: str, fields: Dict):
         self._tel = tel
         self._name = name
         self._fields = fields
         self._path = name
-        self._t0 = 0.0
+        self._ann = None
+        self._c0: Dict[str, float] = {}
+        self._rec: Dict[str, object] = {}
 
-    def __enter__(self):
+    def __enter__(self) -> Dict[str, object]:
+        from jax.profiler import TraceAnnotation
+        jax_watch.watch(lambda: _TEL.registry)
         stack = self._tel._span_stack()
-        self._path = ("/".join(stack) + "/" + self._name if stack
-                      else self._name)
+        parent = "/".join(stack) or None
+        self._path = f"{parent}/{self._name}" if parent else self._name
         stack.append(self._name)
-        self._t0 = time.perf_counter()
-        return self
+        self._c0 = jax_watch.totals(_TEL.registry)
+        self._ann = TraceAnnotation(self._path, **self._fields)
+        self._ann.__enter__()
+        self._rec = {"name": self._name, "parent": parent,
+                     "start": time.perf_counter(), "end": None}
+        return self._rec
 
     def __exit__(self, exc_type, exc, tb):
-        secs = time.perf_counter() - self._t0
+        end = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
+        c1 = jax_watch.totals(_TEL.registry)
+        rec = self._rec
+        rec["end"] = end
+        rec.update((k, c1[k] - self._c0[k]) for k in c1)
         stack = self._tel._span_stack()
         if stack:
             stack.pop()
+        secs = end - rec["start"]
         self._tel.observe(self._path, secs)
-        # event() also records the span into the recent-span ring
-        self._tel.event("span", name=self._path, secs=secs,
-                        **self._fields)
+        if self._tel.enabled:
+            # event() also records the span into the recent-span ring
+            self._tel.event("span", name=self._path, secs=secs,
+                            **self._fields)
         return False
 
 
@@ -343,10 +356,10 @@ class Telemetry:
             stack = self._local.spans = []
         return stack
 
-    def span(self, name: str, **fields):
-        """Timed context manager; no-op singleton when disabled."""
-        if not self.enabled:
-            return _NULL_SPAN
+    def span(self, name: str, **fields) -> _Span:
+        """Timed context manager whose value is the span's record (see
+        _Span). For work done once a process or once a round: per-step
+        sites keep bare `TraceAnnotation`s (spans.py)."""
         return _Span(self, name, fields)
 
     # -- events ------------------------------------------------------------

@@ -288,6 +288,15 @@ class ExecutableRegistry:
                 "last_used_ts": None,
             }
 
+    def stamp_compile(self, fp: str, compile_s: float) -> None:
+        """Stamp an entry's compile_s where its site learns it after
+        registering (the trainer's first dispatch); the first stamp
+        wins, as in register()."""
+        with self._lock:
+            e = self._entries.get(fp)
+            if e is not None and e.get("compile_s") is None:
+                e["compile_s"] = round(compile_s, 6)
+
     def count_dispatch(self, fp: str,
                        secs: Optional[float] = None) -> None:
         with self._lock:
