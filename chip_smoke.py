@@ -503,7 +503,7 @@ def kernel_leg(dry: bool) -> None:
     # the `gqa` layer's kernels: 7 query heads on each key/value head
     # (dk and dv sum over their group), without a window and with one a
     # quarter of the sequence long, so that whole tiles lie left of it
-    # and are never walked (flash_win_fwd / flash_win_dq / flash_win_dkv)
+    # and are never walked (flash_win_fwd / flash_win_dqkv)
     for window in (0, 8 if dry else 1024):
         flash_case(*((2, 14, 32, 16) if dry else (1, 14, 4096, 128)),
                    True, hkv=2, window=window)

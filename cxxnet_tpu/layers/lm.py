@@ -46,7 +46,9 @@ gqa       grouped-query causal attention: `nhead` query heads read the
           its band, the shared heads are read through the index map -
           else through `blockwise_attention`, which masks. Counter
           `tiles`: the score tiles the core computes over those of a
-          full causal layer (1 on the XLA route, which skips none).
+          full causal layer (1 on the XLA route, which skips none);
+          counter `fused_bwd`: 1 where the kernels' backward is one
+          kernel (`pallas_attention.fused_backward`), else 0.
           Keys: nhead, nkvhead, head_dim, window (0), rope_theta (0),
           qk_norm (0), eps (1e-5).
 lm_head   inputs: the final hidden node and the token node. Output: the
@@ -495,7 +497,7 @@ class GQALayer(_TableLayer):
     #: read 0.1-0.2%, and the cell's limit lies between: PERF.md
     #: section 6, PR 37, has the readings and what was ruled out
     remat_worthy = True
-    stat_names = ("tiles", "masked")
+    stat_names = ("tiles", "masked", "fused_bwd")
 
     def __init__(self, name: str = ""):
         super().__init__(name)
@@ -580,17 +582,20 @@ class GQALayer(_TableLayer):
                     self.window)
                 tiles = pallas_attention.tile_share(q, self.window)
                 masked = pallas_attention.masked_share(q, self.window)
+                fused = float(pallas_attention.fused_backward(q, k))
             else:
                 o = ops_attn.blockwise_attention(
                     q, k, v, causal=True, kv_block=self.kv_block,
                     window=self.window)
                 tiles = masked = 1.0
+                fused = 0.0
         with jax.named_scope("out"):
             out = jnp.einsum("bhtd,hde->bte", o, p["wo"].astype(o.dtype)
                              .reshape(self.nhead, d, e))
         return ([out.reshape(b, 1, t, e)], None,
                 {"tiles": jnp.asarray(tiles, jnp.float32),
-                 "masked": jnp.asarray(masked, jnp.float32)})
+                 "masked": jnp.asarray(masked, jnp.float32),
+                 "fused_bwd": jnp.asarray(fused, jnp.float32)})
 
     def apply(self, params, inputs, *, train, rng=None):
         return self.apply_with_stats(params, inputs, train=train,

@@ -16,10 +16,15 @@ Design (the standard flash-attention schedule on TPU):
 - backward: recompute p = exp(q.k*scale - lse) per tile from the saved
   lse (no S x S residuals). With delta = rowsum(do * o):
       ds = p * (do . v^T - delta)
-      dq += ds . k * scale     (grid (B, H, nQ, nKV), KV innermost)
-      dk += ds^T . q * scale   (grid (B, H, nKV, nQ), Q innermost)
+      dq += ds . k * scale
+      dk += ds^T . q * scale
       dv += p^T . do
-  exposed as one jax.custom_vjp around the forward.
+  in one kernel (`flash_dqkv`, grid (B, Hkv, group, nKV, nQ), Q
+  innermost) that computes a tile's p and ds once for all three and
+  keeps dq, dk and dv of the whole sequence in f32 VMEM; where they do
+  not fit (`fused_backward`), in two that each compute them: dq over
+  (B, H, nQ, nKV), KV innermost, and dk/dv over (B, Hkv, nKV, group *
+  nQ). Exposed as one jax.custom_vjp around the forward.
 - causal masking is done in global coordinates from program ids;
   fully-future tiles are skipped with @pl.when so the causal schedule
   does ~half the work, and only a tile that crosses the diagonal or a
@@ -310,6 +315,20 @@ def _specs(q, k, causal: bool, window: int, tiles: Tuple[int, int]):
     return bq, bk, nq, nkv, group, steps, qspec, kspec, rspec
 
 
+def _q_block_at(ki, j, bq: int, bk: int, nq: int, causal: bool,
+                window: int):
+    """The query block that step j of key tile ki asks for: its own, or
+    for a step that runs no tile that of the live step beside it."""
+    qi = _q_tile(ki, j, bq, bk, window)
+    if window:
+        return jnp.minimum(qi, nq - 1)
+    if causal:
+        # a step wholly before the key tile: the first query tile that
+        # sees it
+        return jnp.maximum(qi, ki * bk // bq)
+    return qi
+
+
 def _key_specs(q, k, causal: bool, window: int, tiles: Tuple[int, int]):
     """The steps and block specs of the dk/dv pass's swapped grid (b,
     hk, nkv, group * qsteps): a key/value head's key tiles outer, the
@@ -322,13 +341,8 @@ def _key_specs(q, k, causal: bool, window: int, tiles: Tuple[int, int]):
     qsteps = _q_steps(nq, nkv, bq, bk, window)
 
     def q_at(b, hk, ki, t):
-        qi = _q_tile(ki, t % qsteps if group > 1 else t, bq, bk, window)
-        if window:
-            qi = jnp.minimum(qi, nq - 1)
-        elif causal:
-            # a step wholly before the key tile: the first query tile
-            # that sees it
-            qi = jnp.maximum(qi, ki * bk // bq)
+        qi = _q_block_at(ki, t % qsteps if group > 1 else t, bq, bk, nq,
+                         causal, window)
         return b, hk * group + t // qsteps if group > 1 else hk, qi
 
     def stat_at(*at):
@@ -466,13 +480,190 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0, 0] = accv[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=_STATIC)
-def _bwd_impl(q, k, v, o, lse, do, scale, causal, interpret, window, tiles):
+def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                 dq_ref, dk_ref, dv_ref, accq, acck, accv, *, scale, causal,
+                 bq, bk, nq, nkv, steps, group, window):
+    """dq, dk and dv in one pass over the live tiles: a key/value head's
+    query heads (g) outer, its key tiles (ki), then the query tiles of
+    the key tile's band (t). Each tile's scores, p, dp and ds are
+    computed once, transposed (bk, bq) as in `_dkv_kernel`, and feed
+    the five products. The accumulators hold the whole sequence in
+    VMEM, float32: dk and dv by key tile, summed over the group's heads
+    and the band; dq transposed, (d, bq) a query tile, summed over the
+    key tiles in ascending order and written out when its last key tile
+    (`_dq_done`) has run."""
+    g, ki, t = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    qi = _q_tile(ki, t, bq, bk, window)
+    q_off, kv_off = qi * bq, ki * bk
+
+    @pl.when((ki == 0) & (t == 0))
+    def _init_dq():
+        accq[:] = jnp.zeros_like(accq)
+
+    @pl.when((g == 0) & (t == 0))
+    def _init_dkv():
+        acck[ki] = jnp.zeros(acck.shape[1:], jnp.float32)
+        accv[ki] = jnp.zeros(accv.shape[1:], jnp.float32)
+
+    def _tile(masked):
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        s = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (bk, bq)
+        if masked:
+            s = _mask(s, q_off, kv_off, bq, bk, window, by_key=True)
+        p = jnp.exp(s - lse_ref[0, 0])
+        do = do_ref[0, 0]
+        accv[ki] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (bk, d)
+        dov = jax.lax.dot_general(
+            v_ref[0, 0], do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = (p * (dov - delta_ref[0, 0])).astype(q.dtype)  # (bk, bq)
+        acck[ki] += scale * jax.lax.dot_general(
+            ds, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (bk, d)
+        accq[qi] += scale * jax.lax.dot_general(
+            k, ds, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (d, bq)
+
+    _run_live(_tile, causal, window, qi < nq, q_off, kv_off, bq, bk)
+
+    first, after = _dq_done(ki, causal, bq, bk, nq, nkv)
+
+    @pl.when((qi >= first) & (qi < after))
+    def _dq_out():
+        dq_ref[0, 0] = accq[qi].T.astype(dq_ref.dtype)
+
+    @pl.when((g == group - 1) & (t == steps - 1))
+    def _dkv_out():
+        dk_ref[0, 0] = acck[ki].astype(dk_ref.dtype)
+        dv_ref[0, 0] = accv[ki].astype(dv_ref.dtype)
+
+
+def _dq_done(ki, causal: bool, bq: int, bk: int, nq: int, nkv: int):
+    """The query tiles [first, after) whose dq is complete once key tile
+    ki has run: under causal attention those whose last live key tile
+    (the diagonal's) it is, else all of them after the last key tile."""
+    last = ki == nkv - 1
+    if not causal:
+        return 0, jnp.where(last, nq, 0)
+    return ki * bk // bq, jnp.where(last, nq, (ki + 1) * bk // bq)
+
+
+def _fused_specs(q, k, causal: bool, window: int, tiles: Tuple[int, int]):
+    """The tile counts and block specs of the fused backward's grid (b,
+    hk, group, nkv, qsteps). Query-side blocks follow the band's query
+    tile as in `_key_specs`. An output block is written back when its
+    index changes, so each index stands still until the block is
+    complete: dk/dv's at key tile 0 until the group's last head, dq's at
+    the next query tile to complete (`_dq_done`)."""
+    d = q.shape[3]
+    bq, bk = tiles
+    nq, nkv = q.shape[2] // bq, k.shape[2] // bk
+    group = q.shape[1] // k.shape[1]
+    qsteps = _q_steps(nq, nkv, bq, bk, window)
+
+    def q_at(b, hk, g, ki, t):
+        return (b, hk * group + g,
+                _q_block_at(ki, t, bq, bk, nq, causal, window))
+
+    def dq_at(b, hk, g, ki, t):
+        first, after = _dq_done(ki, causal, bq, bk, nq, nkv)
+        qi = jnp.minimum(jnp.maximum(_q_tile(ki, t, bq, bk, window), first),
+                         after)
+        return b, hk * group + g, jnp.minimum(qi, nq - 1), 0
+
+    def stat_at(*at):
+        b, h, qi = q_at(*at)
+        return b, h, 0, qi
+
+    qspec = pl.BlockSpec((1, 1, bq, d), lambda *at: q_at(*at) + (0,))
+    kspec = pl.BlockSpec((1, 1, bk, d),
+                         lambda b, hk, g, ki, t: (b, hk, ki, 0))
+    rspec = pl.BlockSpec((1, 1, 1, bq), stat_at)
+    dqspec = pl.BlockSpec((1, 1, bq, d), dq_at)
+    dkvspec = pl.BlockSpec((1, 1, bk, d), lambda b, hk, g, ki, t: (
+        b, hk, jnp.where(g == group - 1, ki, 0), 0))
+    return (nq, nkv, group, qsteps), (qspec, kspec, rspec, dqspec, dkvspec)
+
+
+def _up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _resident_bytes(q, k, tiles: Tuple[int, int]) -> int:
+    """The VMEM the fused backward's accumulators hold, as the chip lays
+    them out ((8, 128) tiles of float32): dq transposed, (nq, d, bq),
+    and dk, dv as (nkv, bk, d), whose lanes a head under 128 wide pads."""
+    bq, bk = tiles
+    d = q.shape[3]
+    dq = q.shape[2] // bq * _up(d, 8) * _up(bq, _LANE)
+    dkv = 2 * (k.shape[2] // bk) * _up(bk, 8) * _up(d, _LANE)
+    return 4 * (dq + dkv)
+
+
+# what the fused backward's accumulators may hold, of v5e's 128 MiB of
+# VMEM: 40 MiB at the LFM2 cell's 32,768 positions of 64-wide heads, 24
+# at SmallThinker's 16,384 of 128, 22 at Kimi-Linear's 8,192 of 192; a
+# longer sequence takes the two kernels. A tile's blocks and temporaries
+# take 5.3-7.3 MiB more at these shapes (the chip's compiler), so the
+# kernel's limit leaves `_TILE_VMEM` over the accumulators
+_FUSED_VMEM = 48 << 20
+_TILE_VMEM = 16 << 20
+
+
+def fused_backward(q, k) -> bool:
+    """Whether the flash backward of `q` (b, h, sq, d) over `k` (b, hk,
+    sk, d) runs as the one kernel `flash_dqkv`, which keeps dq, dk and
+    dv for the whole sequence in VMEM, or as `flash_dq` and
+    `flash_dkv`. Static: what a layer's `fused_bwd` counter says
+    (layers/lm.py)."""
+    return (_resident_bytes(q, k, _tiles_of(q, k.shape[2]))
+            <= _FUSED_VMEM)
+
+
+def _fused_bwd(q, k, v, do, lse, delta, scale, causal, interpret, window,
+               tiles):
+    d = q.shape[3]
+    bq, bk = tiles
+    (nq, nkv, group, qsteps), (qspec, kspec, rspec, dqspec, dkvspec) = (
+        _fused_specs(q, k, causal, window, tiles))
+    return pl.pallas_call(
+        functools.partial(_dqkv_kernel, scale=scale, causal=causal,
+                          bq=bq, bk=bk, nq=nq, nkv=nkv, steps=qsteps,
+                          group=group, window=window),
+        grid=(q.shape[0], k.shape[1], group, nkv, qsteps),
+        in_specs=[qspec, kspec, kspec, qspec, rspec, rspec],
+        out_specs=[dqspec, dkvspec, dkvspec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((nq, d, bq), jnp.float32),
+                        pltpu.VMEM((nkv, bk, d), jnp.float32),
+                        pltpu.VMEM((nkv, bk, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_resident_bytes(q, k, tiles) + _TILE_VMEM),
+        interpret=interpret,
+        name=_name("dqkv", window),
+    )(q, k, v, do, lse, delta)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC + ("fused",))
+def _bwd_impl(q, k, v, o, lse, do, scale, causal, interpret, window, tiles,
+              fused):
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1)[:, :, None, :]  # (b, h, 1, s), as lse
+    if fused:
+        return _fused_bwd(q, k, v, do, lse, delta, scale, causal, interpret,
+                          window, tiles)
     b, h, s, d = q.shape
     bq, bk, nq, nkv, group, steps, qspec, kspec, rspec = _specs(
         q, k, causal, window, tiles)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)[:, :, None, :]  # (b, h, 1, s), as lse
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -545,7 +736,8 @@ def _vjp_fwd(q, k, v, causal, scale, interpret, window):
 def _vjp_bwd(causal, scale, interpret, window, res, do):
     q, k, v, o, lse = res
     return _bwd_impl(q, k, v, o, lse, do, _scale(q, scale), causal,
-                     interpret, window, _tiles_of(q, k.shape[2]))
+                     interpret, window, _tiles_of(q, k.shape[2]),
+                     fused_backward(q, k))
 
 
 flash_attention.defvjp(_vjp_fwd, _vjp_bwd)

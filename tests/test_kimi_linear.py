@@ -445,7 +445,7 @@ def _step_eqns(trainer, tok):
 def test_remat_runs_the_attention_core_once(remat, monkeypatch):
     """One `mla` layer, the flash kernel in interpret mode: the step
     calls `flash_fwd` once, in the layer's forward, and the backward
-    kernels once each on what that call left (q, k, v, o, lse), with
+    kernel `flash_dqkv` once on what that call left (q, k, v, o, lse), with
     `remat` as without. A checkpoint round the layer would call it a
     second time under `rematted_computation`."""
     from cxxnet_tpu.ops import pallas_attention
@@ -457,7 +457,7 @@ def test_remat_runs_the_attention_core_once(remat, monkeypatch):
     # stacks start there), called once from each of the layer's scopes
     assert sorted(stack for prim, stack in eqns
                   if prim == "pallas_call") == [
-        "flash_dkv", "flash_dq", "flash_fwd"]
+        "flash_dqkv", "flash_fwd"]
     assert sorted(stack for prim, stack in eqns
                   if prim == "jit" and stack.endswith("/scores")) == [
         "jvp(mla.m1)/scores", "transpose(jvp(mla.m1))/scores"]

@@ -76,8 +76,9 @@ def test_five_layer_stack_matches_the_reference_on_the_xla_route(
     assert {"qnorm", "knorm"} <= set(ref.hyper()["l2_gqa"])
     counted = trainer.fetch_counters()
     assert {k.split(".")[1] for k in counted} == {
-        "tiles", "masked", "held", "load", "dropped"}
+        "tiles", "masked", "fused_bwd", "held", "load", "dropped"}
     assert counted["l2_gqa.tiles"] == counted["l2_gqa.masked"] == 1.0
+    assert counted["l2_gqa.fused_bwd"] == 0.0
     assert all(v == 0 for k, v in counted.items() if k.endswith("dropped"))
 
 
@@ -121,7 +122,7 @@ def test_the_step_carries_the_scopes_a_reader_finds_the_layers_by(
                   "jvp(gconv.l5_gconv)/conv", "jvp(gqa.l2_gqa)/proj",
                   "jvp(gqa.l2_gqa)/qknorm", "jvp(gqa.l2_gqa)/rope",
                   "jvp(gqa.l2_gqa)/scores", "jvp(gqa.l2_gqa)/out",
-                  "flash_fwd", "flash_dq", "flash_dkv",
+                  "flash_fwd", "flash_dqkv",
                   "jvp(moe.l2_moe)/route", "jvp(glu_ffn.l1_ffn)"):
         # (an einsum puts its own name under the scope it runs in)
         assert any(s.startswith(scope) for s in stacks), (scope, sorted(

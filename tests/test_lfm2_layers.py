@@ -118,10 +118,11 @@ def test_gqa_qk_norm_is_naive_attention_over_normed_and_turned_heads():
     np.testing.assert_allclose(same, got, rtol=1e-4, atol=1e-5)
 
 
-# PR 40: the layer's second static stat, `masked`, is one more constant
-# output (`in (dy, 1.0:f32[], 1.0:f32[])`); nothing else of the text moved
+# the layer's static stats `masked` and `fused_bwd` are two more
+# constant outputs (`in (dy, 0.0:f32[], 1.0:f32[], 1.0:f32[])`); nothing
+# else of the text moved
 PARENT_JAXPR_SHA256 = (
-    "1df49a0042b0281dc02884208eb6926c40cf8d22f43e69df7c3b4d7987720342")
+    "c03bcc34dea254caa6815be39a980d156532705b1c97c17dbbaca60a9c90d6e2")
 
 
 def _traced(pairs) -> str:
@@ -136,9 +137,10 @@ def test_gqa_without_qk_norm_traces_what_it_traced_before_the_key():
     35), so a `gqa` layer that does not ask for the norm keeps its four
     leaves and its text: no `rsqrt`, and letter for letter the jaxpr the
     layer gave at the commit before `qk_norm` existed (PR 37's tree,
-    jax 0.9.0), but for PR 40's `masked` stat; a newer JAX that prints
-    jaxprs otherwise needs the digest taken again from PR 39's tree with
-    that one output added, not from this file's layer."""
+    jax 0.9.0), but for the `masked` and `fused_bwd` stats; a newer JAX
+    that prints jaxprs otherwise needs the digest taken again from the
+    commit that added `qk_norm`, with those two outputs added, not from
+    this file's layer."""
     lay, p = _layer("gqa", GQA)
     assert sorted(p) == ["wk", "wo", "wq", "wv"]
     text = _traced(GQA)
@@ -199,6 +201,7 @@ def test_five_layer_stack_matches_the_reference_through_the_kernels(
     # the five diagonal tiles of 15 are masked
     assert counted["l2_gqa.tiles"] == 1.0
     assert counted["l2_gqa.masked"] == pytest.approx(5 / 15)
+    assert counted["l2_gqa.fused_bwd"] == 1.0
 
 
 def test_the_cells_shape_takes_whole_tiles():

@@ -29,6 +29,34 @@ def small_blocks(monkeypatch):
     monkeypatch.setattr(PA, "BLOCK_K", 8)
 
 
+def _backward_both_ways(q, k, v, causal, window, monkeypatch):
+    """dq, dk, dv from the fused kernel and from the two kernels (forced
+    by a budget of 0), as the custom vjp's backward calls them."""
+    _, res = PA._vjp_fwd(q, k, v, causal, None, True, window)
+    do = jnp.asarray(np.random.RandomState(2).randn(*q.shape), q.dtype)
+
+    def backward():
+        return PA._vjp_bwd(causal, None, True, window, res, do)
+
+    assert PA.fused_backward(q, k)
+    fused = backward()
+    budget = PA._FUSED_VMEM
+    monkeypatch.setattr(PA, "_FUSED_VMEM", 0)
+    assert not PA.fused_backward(q, k)
+    two = backward()
+    monkeypatch.setattr(PA, "_FUSED_VMEM", budget)
+    return fused, two
+
+
+def _check_against_two_kernels(fused, two):
+    """dk and dv bit for bit (the same products summed in the same
+    order), dq to float32 rounding (its scores are computed transposed)."""
+    np.testing.assert_array_equal(fused[1], two[1], err_msg="dk")
+    np.testing.assert_array_equal(fused[2], two[2], err_msg="dv")
+    np.testing.assert_allclose(fused[0], two[0], rtol=1e-5, atol=1e-6,
+                               err_msg="dq")
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_forward_matches_naive(causal, small_blocks):
     q, k, v = _qkv()
@@ -47,9 +75,24 @@ def test_single_tile_and_uneven_blocks(small_blocks):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_gradients_match_naive(causal, small_blocks):
-    q, k, v = _qkv(b=1, h=2, s=16, d=8)
+@pytest.mark.parametrize("causal,h,hkv,s,d,bq,bk", [
+    pytest.param(False, 2, 2, 16, 8, 8, 8, id="False"),
+    pytest.param(True, 2, 2, 16, 8, 8, 8, id="True"),
+    # 4:1 groups, 16-wide heads, query tiles wider than key tiles
+    pytest.param(False, 8, 2, 32, 16, 16, 8, id="noncausal-g4-d16"),
+    pytest.param(True, 4, 1, 32, 16, 8, 16, id="causal-g4-d16"),
+    # one query tile: every dq tile completes at the last key tile
+    pytest.param(True, 7, 1, 16, 8, 16, 8, id="causal-g7-one-query-tile"),
+    pytest.param(False, 3, 3, 16, 16, 16, 8, id="noncausal-one-query-tile"),
+])
+def test_gradients_match_naive(causal, h, hkv, s, d, bq, bk, monkeypatch):
+    """The gradients through the custom vjp against `naive_attention`,
+    and the fused backward's against the two kernels'."""
+    monkeypatch.setattr(PA, "BLOCK_Q", bq)
+    monkeypatch.setattr(PA, "BLOCK_K", bk)
+    q, _, _ = _qkv(b=1, h=h, s=s, d=d)
+    _, k, v = _qkv(b=1, h=hkv, s=s, d=d, seed=3)
+    assert PA._tiles_of(q, s) == (bq, bk)
 
     def loss_ref(q, k, v):
         return jnp.sum(jnp.cos(A.naive_attention(q, k, v, causal=causal)))
@@ -64,6 +107,8 @@ def test_gradients_match_naive(causal, small_blocks):
         np.testing.assert_allclose(
             np.asarray(b), np.asarray(a), rtol=1e-4, atol=1e-5,
             err_msg=f"d{name} mismatch")
+    _check_against_two_kernels(
+        *_backward_both_ways(q, k, v, causal, 0, monkeypatch))
 
 
 @pytest.mark.parametrize("h,hkv,s,window,bq,bk", [
@@ -76,6 +121,8 @@ def test_gradients_match_naive(causal, small_blocks):
     (7, 1, 64, 20, 8, 8),       # a window off the tiling: left edges
     (7, 1, 64, 0, 16, 8),       # query tiles wider than key tiles
     (14, 2, 64, 32, 16, 8),     # ... under a window
+    (8, 2, 64, 28, 8, 16),      # 4:1 groups, key tiles wider, a window
+    (4, 1, 32, 0, 8, 8),        # 4:1 groups, full causal
 ])
 def test_kernels_with_statistics_on_lanes_match_naive(
         h, hkv, s, window, bq, bk, small_blocks, monkeypatch):
@@ -83,7 +130,8 @@ def test_kernels_with_statistics_on_lanes_match_naive(
     custom vjp keeps for the backward: the row statistic is (b, h, 1,
     s), the positions last (on the chip: on lanes), never a trailing
     dim of 8 that the chip's tiling would pad to 128. Every case runs
-    interior tiles, unmasked, beside masked edge tiles."""
+    interior tiles, unmasked, beside masked edge tiles. The fused
+    backward gives the two kernels' dk and dv bit for bit."""
     monkeypatch.setattr(PA, "BLOCK_Q", bq)
     monkeypatch.setattr(PA, "BLOCK_K", bk)
     r = np.random.RandomState(1)
@@ -117,6 +165,8 @@ def test_kernels_with_statistics_on_lanes_match_naive(
     for name, a, b in zip("qkv", gk, gn):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5,
                                    err_msg=f"d{name}")
+    _check_against_two_kernels(
+        *_backward_both_ways(q, k, v, True, window, monkeypatch))
 
 
 @pytest.mark.parametrize("s,bq,bk,window", [
@@ -166,11 +216,15 @@ def _copies(blocks):
 ])
 def test_a_dead_step_asks_for_its_live_neighbours_block(h, hkv, bq, bk,
                                                         window):
-    """In the index maps of all three kernels a step that runs no tile
+    """In the index maps of all four kernels a step that runs no tile
     asks for the block of the live step beside it, so along a row of
     `flash_fwd` / `flash_dq` (key blocks) and a head's column of
-    `flash_dkv` (query blocks and their statistics) the block changes
-    only at a live step: the pipeline copies nothing for a dead one."""
+    `flash_dkv` and `flash_dqkv` (query blocks and their statistics) the
+    block changes only at a live step: the pipeline copies nothing for a
+    dead one. `flash_dqkv`'s outputs are written back when their index
+    changes: each output block is asked for in one run of steps, in
+    order, and the kernel writes it once, inside that run, at the step
+    that completes it."""
     s = 64
     q = jax.ShapeDtypeStruct((1, h, s, 8), jnp.float32)
     k = jax.ShapeDtypeStruct((1, hkv, s, 8), jnp.float32)
@@ -198,6 +252,106 @@ def test_a_dead_step_asks_for_its_live_neighbours_block(h, hkv, bq, bk,
                     (0, hq, qi, 0) for qi in live]
                 assert _copies(_blocks_asked(rspec, sweep)) == [
                     (0, hq, 0, qi) for qi in live]
+    (_, _, _, qsteps), (qspec, _, rspec, dqspec, dkvspec) = (
+        PA._fused_specs(q, k, True, window, (bq, bk)))
+    for hk in range(hkv):
+        dkv_asked, dkv_written = [], []
+        for g in range(group):
+            hq = hk * group + g
+            sweep = [(0, hk, g, ki, j) for ki in range(nkv)
+                     for j in range(qsteps)]
+            live, dq_asked, dq_written = [], [], []
+            for at in sweep:
+                ki, qi = at[3], PA._q_tile(at[3], at[4], bq, bk, window)
+                if qi < nq and PA._tile_live(qi * bq, ki * bk, bq, bk,
+                                             window):
+                    live.append(qi)
+                first, after = PA._dq_done(ki, True, bq, bk, nq, nkv)
+                asked = tuple(int(x) for x in dqspec.index_map(*at))
+                dq_asked.append(asked)
+                if first <= qi < int(after):     # the kernel writes dq
+                    assert asked == (0, hq, qi, 0)
+                    # ... after the query tile's last live key tile
+                    assert ki == min((qi * bq + bq - 1) // bk, nkv - 1)
+                    dq_written.append(qi)
+                dkv_asked.append(
+                    tuple(int(x) for x in dkvspec.index_map(*at)))
+                if g == group - 1 and at[4] == qsteps - 1:
+                    dkv_written.append(len(dkv_asked) - 1)
+            # query blocks as the dk/dv pass asks for them
+            assert _copies(_blocks_asked(qspec, sweep)) == [
+                (0, hq, qi, 0) for qi in _copies(live)]
+            assert _copies(_blocks_asked(rspec, sweep)) == [
+                (0, hq, 0, qi) for qi in _copies(live)]
+            # each dq block once, in order, written once
+            assert _copies(dq_asked) == [(0, hq, qi, 0) for qi in range(nq)]
+            assert dq_written == list(range(nq))
+        # a key tile's dk/dv block is asked for from the group's first
+        # head on, and written at the last head's last step
+        assert _copies(dkv_asked) == [(0, hk, ki, 0) for ki in range(nkv)]
+        for ki, at in enumerate(dkv_written):
+            assert dkv_asked[at] == (0, hk, ki, 0)
+            assert at == len(dkv_asked) - 1 or dkv_asked[at + 1] != (
+                0, hk, ki, 0)
+
+
+@pytest.mark.parametrize("h,hkv,s,d,fused", [
+    (32, 8, 32768, 64, True),       # the LFM2 cell's `gqa` layer: 40 MiB
+    (28, 4, 16384, 128, True),      # SmallThinker's, full and window: 24
+    (32, 32, 8192, 192, True),      # Kimi-Linear's `mla`: 22
+    (32, 8, 65536, 64, False),      # twice LFM2's row: 80 MiB
+    (28, 4, 131072, 128, False),
+])
+def test_the_backward_is_one_kernel_where_its_accumulators_fit(
+        h, hkv, s, d, fused):
+    """`fused_backward` by shape: the three cells' attention takes the
+    one kernel, whose float32 dq, dk and dv of the whole sequence fit
+    the VMEM budget; a longer row takes the two kernels."""
+    q = jax.ShapeDtypeStruct((1, h, s, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, hkv, s, d), jnp.bfloat16)
+    assert PA._tile_ok(q, s)
+    assert PA.fused_backward(q, k) == fused
+    assert (PA._resident_bytes(q, k, PA._tiles_of(q, s))
+            <= PA._FUSED_VMEM) == fused
+
+
+def test_a_gqa_layer_counts_the_fused_backward(monkeypatch):
+    """`<layer>.fused_bwd` reads 1 where the kernels' backward is one
+    kernel, 0 where it is two and on the XLA route."""
+    from cxxnet_tpu.layers import lm
+    layer = lm.GQALayer("l0")
+    for key, val in (("nhead", "4"), ("nkvhead", "1"), ("head_dim", "8")):
+        layer.set_param(key, val)
+    x = jnp.asarray(np.random.RandomState(0).randn(1, 1, 16, 8),
+                    jnp.float32)
+    params = {"wq": jnp.ones((8, 32)) * 0.1, "wk": jnp.ones((8, 8)) * 0.1,
+              "wv": jnp.ones((8, 8)) * 0.1, "wo": jnp.ones((32, 8)) * 0.1}
+
+    def counted():
+        return float(layer.apply_with_stats(params, [x], train=True)[2][
+            "fused_bwd"])
+
+    assert counted() == 0.0                  # the XLA route
+    monkeypatch.setattr(PA, "_FORCE_INTERPRET", True)
+    assert counted() == 1.0
+    monkeypatch.setattr(PA, "_FUSED_VMEM", 0)
+    assert counted() == 0.0
+
+
+def test_the_roofline_readers_count_the_fused_kernel_once():
+    """`flash_d64_roofline` and `flash_gqa_roofline` find the kernels'
+    device events by substring (`trace_reduce.named`): `flash_dq` is in
+    `flash_dqkv` and `flash_win_dq` in `flash_win_dqkv`, `flash_dkv` in
+    neither, so the fused kernel's time is counted once, and a window
+    kernel's only by the reader of the window layers."""
+    from benchmark import trace_reduce
+    from benchmark.layer_metrics import flash_d64_roofline as d64
+    from benchmark.layer_metrics import flash_gqa_roofline as gqa
+    for name, in_d64 in (("flash_dqkv.3", True), ("jvp_flash_dqkv_", True),
+                         ("flash_win_dqkv.1", False)):
+        event = [(name, 0.0, 1.0)]
+        assert trace_reduce.kernel_ns(event, d64.KERNELS) == float(in_d64)
+        assert trace_reduce.kernel_ns(event, gqa.KERNELS) == 1.0
 
 
 def test_query_tile_is_the_statistics_lane_dim():

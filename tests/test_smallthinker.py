@@ -82,10 +82,12 @@ def test_eight_layer_stack_matches_the_reference_on_the_xla_route(
     trainer = _stack_against_reference()
     counted = trainer.fetch_counters()
     assert {k.split(".")[1] for k in counted} == {
-        "tiles", "masked", "held", "load", "dropped"}
+        "tiles", "masked", "fused_bwd", "held", "load", "dropped"}
     # the XLA route masks the window and skips nothing, and says so
     assert all(v == 1.0 for k, v in counted.items()
                if k.endswith((".tiles", ".masked")))
+    assert all(v == 0.0 for k, v in counted.items()
+               if k.endswith(".fused_bwd"))
     assert all(v == 0 for k, v in counted.items() if k.endswith("dropped"))
 
 
@@ -102,8 +104,9 @@ def test_eight_layer_stack_matches_the_reference_through_the_kernels(
     want = {f"l{i}_gqa.tiles": (12 / 15 if i % 4 else 1.0) for i in range(8)}
     want.update({f"l{i}_gqa.masked": (8 / 12 if i % 4 else 5 / 15)
                  for i in range(8)})
+    want.update({f"l{i}_gqa.fused_bwd": 1.0 for i in range(8)})
     got = {k: v for k, v in counted.items()
-           if k.endswith((".tiles", ".masked"))}
+           if k.endswith((".tiles", ".masked", ".fused_bwd"))}
     assert got == pytest.approx(want)
 
 
@@ -321,11 +324,12 @@ def test_eight_layers_lower_one_full_and_one_window_set_of_kernels(
         t.state, staged.data, staged.extras, staged.labels, staged.mask,
         jax.random.PRNGKey(0)).lower(lowering_platforms=("tpu",)).as_text()
     bodies = {n: len(re.findall(rf'kernel_name = "{n}"', text))
-              for n in ("flash_fwd", "flash_dq", "flash_dkv",
-                        "flash_win_fwd", "flash_win_dq", "flash_win_dkv")}
-    assert bodies == {"flash_fwd": 2, "flash_dq": 1, "flash_dkv": 1,
-                      "flash_win_fwd": 2, "flash_win_dq": 1,
-                      "flash_win_dkv": 1}
+              for n in ("flash_fwd", "flash_dq", "flash_dkv", "flash_dqkv",
+                        "flash_win_fwd", "flash_win_dq", "flash_win_dkv",
+                        "flash_win_dqkv")}
+    assert bodies == {"flash_fwd": 2, "flash_dq": 0, "flash_dkv": 0,
+                      "flash_dqkv": 1, "flash_win_fwd": 2, "flash_win_dq": 0,
+                      "flash_win_dkv": 0, "flash_win_dqkv": 1}
     # the kernels' row statistics: positions last, one row a head
     assert "2x4x1x64xf32" in text and "2x4x64x8xf32" not in text
     # and the scopes a reader of the trace finds the layers' parts by
@@ -335,7 +339,7 @@ def test_eight_layers_lower_one_full_and_one_window_set_of_kernels(
                   "jvp(gqa.l1_gqa)/out", "jvp(gqa.l0_gqa)/proj",
                   "jvp(gqa.l1_gqa)/scores", "jvp(gqa.l0_gqa)/scores",
                   # (inside the jitted functions the kernels' own names)
-                  "flash_fwd", "flash_win_fwd", "flash_win_dkv"):
+                  "flash_fwd", "flash_win_fwd", "flash_win_dqkv"):
         assert scope in stacks, (scope, sorted(
             s for s in stacks if "gqa.l1" in s))
     assert "jvp(gqa.l0_gqa)/rope" not in stacks   # no positional encoding

@@ -7,6 +7,7 @@ The topology is described inside a fixture: only the worker that is
 given this file loads the TPU's library, and it compiles in its own
 process. Keep such tests in this one file."""
 
+import functools
 import os
 
 import pytest
@@ -72,7 +73,7 @@ def test_flash_kernels_compile_for_the_chip_at_mla_head_size(one_chip):
     """The `mla` layer's core: 32 heads, 8,192 positions, queries and
     keys 192 wide (values padded to it). At 1024 x 1024 tiles the dk/dv
     kernel asked for 17 MB of VMEM; `_block_limits` gives such heads
-    512."""
+    512. The backward is the one kernel, its accumulators 22 MiB."""
     from cxxnet_tpu.ops import pallas_attention as pa
     assert pa._block_limits(192) == (512, 512)
     assert pa._block_limits(128) == (pa.BLOCK_Q, pa.BLOCK_K)
@@ -84,8 +85,10 @@ def test_flash_kernels_compile_for_the_chip_at_mla_head_size(one_chip):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile().as_text()
-    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+    assert pa.fused_backward(x, x)
+    for name in ("flash_fwd", "flash_dqkv"):
         assert name in text
+    assert "flash_dkv" not in text and "flash_dq." not in text
 
 
 @pytest.mark.parametrize("h,hkv,s,d,window", [
@@ -96,10 +99,12 @@ def test_gqa_flash_kernels_compile_for_the_chip_at_the_cells_size(
         one_chip, h, hkv, s, d, window):
     """The `gqa` layer's core in the SmallThinker cell: 28 query heads
     on 4 key/value heads, 128 wide, 16,384 positions, 1,024 x 1,024
-    tiles; with the window the three kernels carry their own names and
-    a band row is 5 tiles. In the LFM2 cell 32 on 8 heads of 64 at
-    32,768 positions. Each kernel body holds an edge and an interior
-    tile, masked and not."""
+    tiles; with the window the kernels carry their own names and a band
+    row is 5 tiles. In the LFM2 cell 32 on 8 heads of 64 at 32,768
+    positions. Each kernel body holds an edge and an interior tile,
+    masked and not. The backward is the one kernel: its float32 dq, dk
+    and dv of the whole sequence (24 and 40 MiB) and a tile's work fit
+    the VMEM limit it asks for."""
     from cxxnet_tpu.ops import pallas_attention as pa
     q = jax.ShapeDtypeStruct((1, h, s, d), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((1, hkv, s, d), jnp.bfloat16,
@@ -112,9 +117,11 @@ def test_gqa_flash_kernels_compile_for_the_chip_at_the_cells_size(
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
-    for kernel in ("fwd", "dq", "dkv"):
+    assert pa.fused_backward(q, kv)
+    for kernel in ("fwd", "dqkv"):
         assert ("flash_win_" + kernel in text) == bool(window)
         assert ("flash_" + kernel in text) == (not window)
+    assert "dkv." not in text.replace("dqkv.", "")
 
 
 @pytest.mark.parametrize("checkpointed", [8, 0])
@@ -129,7 +136,9 @@ def test_smallthinker_step_fits_the_chip(one_chip, monkeypatch,
     checkpointed: without (`checkpointed = 0`: `GQALayer.remat_worthy`
     turned off here) the compiler refused the step, `Used 16.12G of
     15.75G hbm`. Now it fits either way; why the layers are checkpointed
-    all the same is in PERF.md section 6, PR 37."""
+    all the same is in PERF.md section 6, PR 37. The one backward
+    kernel a layer leaves arguments plus temporaries no larger than the
+    two kernels do (`_FUSED_VMEM = 0` forces those)."""
     from benchmark import run as bench
     from cxxnet_tpu.layers import lm
     from cxxnet_tpu.nnet.trainer import NetTrainer
@@ -165,17 +174,27 @@ def test_smallthinker_step_fits_the_chip(one_chip, monkeypatch,
         jax.ShapeDtypeStruct((1,), jnp.float32),
         jax.ShapeDtypeStruct((2,), jnp.uint32)))
     t.state = None                       # (tracers, left by eval_shape)
-    compiled = jax.jit(t._train_step.__wrapped__,
-                       donate_argnums=(0,)).lower(*args).compile()
-    text = compiled.as_text()
-    mem = compiled.memory_analysis()
-    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-            < 15.75 * 2 ** 30)
+
+    def compile_step():
+        # a function jitted afresh: traced again, not found in the cache
+        step = functools.partial(t._train_step.__wrapped__)
+        compiled = jax.jit(step, donate_argnums=(0,)).lower(*args).compile()
+        mem = compiled.memory_analysis()
+        return (compiled.as_text(),
+                mem.argument_size_in_bytes + mem.temp_size_in_bytes)
+
+    text, used = compile_step()
+    assert used < 15.75 * 2 ** 30
+    monkeypatch.setattr(pa, "_FUSED_VMEM", 0)
+    two_text, two_used = compile_step()
+    assert "/flash_dkv/" in two_text and used <= two_used
     assert "f32[1,28,16384,8]" not in text
     assert "f32[1,28,1,16384]" in text
-    for kernel in ("flash_fwd", "flash_dq", "flash_dkv", "flash_win_fwd",
-                   "flash_win_dq", "flash_win_dkv"):
+    for kernel in ("flash_fwd", "flash_dqkv", "flash_win_fwd",
+                   "flash_win_dqkv"):
         assert f"/{kernel}/" in text
+    for kernel in ("flash_dq", "flash_dkv", "flash_win_dq", "flash_win_dkv"):
+        assert f"/{kernel}/" not in text
     assert ("rematted_computation/scores" in text) == bool(checkpointed)
 
 
