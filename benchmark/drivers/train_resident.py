@@ -300,13 +300,17 @@ def _leaf_gaps(prog: Dict[str, float], ref: Dict[str, float],
     return gaps
 
 
-def compare(prog: Dict[str, Any], ref: Dict[str, Any]
+def compare(prog: Dict[str, Any], ref: Dict[str, Any],
+            groups: Optional[Dict[str, Tuple[str, List[str]]]] = None
             ) -> Tuple[Dict[str, float], Dict[str, str]]:
     """The numbers `correct` can be decided by (the cell's limits name
-    those that are), and for the two taken by the worst leaf, which leaf
+    those that are), and for those taken by the worst leaf, which leaf
     it was. `grad1`, `dparam`: the worst leaf's gap; `grad1_med`,
     `dparam_med`: the median leaf's, steady where small leaves are
-    noisy."""
+    noisy. `groups` (name -> (`grad1` or `dparam`, leaf names)): the
+    worst gap among those leaves alone, each gap as it is taken for all
+    of them, so that a fault in a few small leaves stands out of the
+    noise of the others."""
     nums: Dict[str, float] = {}
     where: Dict[str, str] = {}
     for k, (lp, lr) in enumerate(zip(prog["loss"], ref["loss"])):
@@ -317,9 +321,16 @@ def compare(prog: Dict[str, Any], ref: Dict[str, Any]
     # by round-off alone: it is left out of the change, by this rule
     med = statistics.median(ref["grad1"].values())
     moving = [n for n in leaves if ref["grad1"][n] >= 1e-3 * med]
+    gaps_of = {}
     for key, names in (("grad1", leaves), ("dparam", moving)):
-        gaps = _leaf_gaps(prog[key], ref[key], names)
+        gaps = gaps_of[key] = _leaf_gaps(prog[key], ref[key], names)
         where[key] = max(gaps, key=gaps.get)
         nums[key] = gaps[where[key]]
         nums[key + "_med"] = statistics.median(gaps.values())
+    for name, (key, names) in (groups or {}).items():
+        inside = {n: gaps_of[key][n] for n in names if n in gaps_of[key]}
+        if not inside:
+            raise ValueError(f"{name}: no leaf of {sorted(names)} is compared")
+        where[name] = max(inside, key=inside.get)
+        nums[name] = inside[where[name]]
     return nums, where

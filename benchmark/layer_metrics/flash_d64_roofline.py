@@ -1,10 +1,13 @@
 """The flash attention kernels' share of the chip's bf16 peak at a
 64-wide head, half a lane tile (the score product contracts over 64 of
-the MXU's 128 rows). Time: the device events named `flash_fwd` /
-`flash_dq` / `flash_dkv`, summed, the second forward that `remat` runs
-among them. Work: what the conf's `gqa` layers of `head_dim = 64`
-require (`kernel_work_attention.causal_attention_flop`: the pairs a
-query sees and no other, two products forward and five backward, nothing
+the MXU's 128 rows). Time: the device events named `flash_fwd` and
+`flash_dqkv` (the one backward kernel where its accumulators fit VMEM;
+`flash_dq` / `flash_dkv` on a longer row), summed, the second forward
+that `remat` runs among them; `KERNELS` matches a name by substring, so
+`flash_dq` finds `flash_dqkv` too, each event once. Work: what the
+conf's `gqa` layers of `head_dim = 64` require
+(`kernel_work_attention.causal_attention_flop`: the pairs a query sees
+and no other, two products forward and five backward, nothing
 recomputed). Bound: compute. Silent on a net without such a layer, on
 one whose other layers share those kernels (`mla`, a `gqa` layer of
 another width: their time would be counted against this work) and where

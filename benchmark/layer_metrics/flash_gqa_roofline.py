@@ -1,8 +1,11 @@
 """The flash attention kernels' share of the chip's bf16 peak under the
-`gqa` layers. Time: the device events named `flash_fwd` / `flash_dq` /
-`flash_dkv` (full layers) and `flash_win_fwd` / `flash_win_dq` /
-`flash_win_dkv` (window layers), summed, the second forward that
-`remat` runs among them. Work: what the conf's `gqa` layers require
+`gqa` layers. Time: the device events named `flash_fwd` / `flash_dqkv`
+(full layers) and `flash_win_fwd` / `flash_win_dqkv` (window layers;
+the backward is one kernel where its accumulators fit VMEM, and
+`flash_dq` / `flash_dkv`, `flash_win_dq` / `flash_win_dkv` on a longer
+row), summed, the second forward that `remat` runs among them;
+`KERNELS` matches a name by substring, so `flash_dq` finds `flash_dqkv`
+too, each event once. Work: what the conf's `gqa` layers require
 (`kernel_work_attention.causal_attention_flop`: the pairs a query sees
 and no other, two products forward and five backward, nothing
 recomputed). Bound: compute. Silent on a net without `gqa` layers and

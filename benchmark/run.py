@@ -86,9 +86,20 @@ def load_cell(name: str) -> SimpleNamespace:
 
     return SimpleNamespace(
         name=name, chips=int(cell["chips"]), cfg=cfg, traffic=traffic,
-        limits=limits["limits"],
+        limits=limits["limits"], groups=limits.get("groups", {}),
         end_to_end=[m for m in spec["end_to_end"] if listed(m)],
         per_layer=[m for m in spec["per_layer"] if listed(m)])
+
+
+def leaf_groups(cell, net) -> Dict[str, Any]:
+    """The cell's numbers over a group of leaves, as its limits file
+    names them (`groups`: name -> the reading, `grad1` or `dparam`, and
+    a layer type and parameter, such as `mla` and `wq`), for the
+    driver's `compare`: name -> (reading, the leaves of the conf's
+    layers of that type)."""
+    return {name: (g["of"], [f"{l.name}.{g['param']}" for l in net.layers
+                             if l.type == g["layer_type"]])
+            for name, g in cell.groups.items()}
 
 
 def find_devices(chips: int, dry_run: bool):
@@ -197,13 +208,17 @@ def main(argv: Optional[List[str]] = None) -> int:
     log(f"window: {win.attempted} operations in {win.wall_s:.3f} s; "
         f"peak {device['memory_peak_bytes']} bytes")
     program = prep.readings
+    # the set-up span records, fetched here, outside set-up and the
+    # window: they describe set-up and do not change after it
+    fetch_setup = getattr(prep.trainer, "fetch_setup", None)
+    setup = fetch_setup() if fetch_setup else None
     driver.free(prep)
 
     breakdown = None
     obs = SimpleNamespace(
         cell=cell, net=ref.net, rows=int(overrides["batch_size"]),
         window=win, device_kind=devs[0].device_kind, trace=None,
-        span=None, device_events=[])
+        span=None, device_events=[], setup=setup)
     if args.trace:
         trace = trace_reduce.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -226,7 +241,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     reference = driver.reference_readings(ref, cell.cfg, cell.traffic,
                                           args.seed, batches)
     log(f"reference done in {time.perf_counter() - t_ref:.1f} s")
-    numbers, where = driver.compare(program, reference)
+    numbers, where = driver.compare(program, reference,
+                                    leaf_groups(cell, ref.net))
     numbers["compiles_in_window"] = float(len(compiles))
     # a number the cell's limits do not name has no upper reading (see
     # PERF.md): it is printed as a reading and decides nothing

@@ -8,19 +8,12 @@ import pytest
 
 from benchmark import confnet
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
-def spec():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
-
-
-def test_names_units_and_sources():
+def test_names_units_and_sources(spec):
     s = spec()
     metrics = s["end_to_end"] + s["per_layer"]
     names = [m["name"] for m in metrics]
@@ -41,14 +34,15 @@ def test_names_units_and_sources():
         assert NAME.match(w["traffic"]) and w["chips"] in (1, 4)
 
 
-def test_every_name_finds_its_files():
+def test_every_name_finds_its_files(spec):
     s = spec()
+    root = spec.root
     e2e = {m["name"] for m in s["end_to_end"]}
     cells = {w["name"] for w in s["workloads"]}
     configs = {c["name"]: c for c in s["configs"]}
     for w in s["workloads"]:
         assert w["config"] in configs
-        bench = os.path.join(ROOT, "benchmark")
+        bench = os.path.join(root, "benchmark")
         traffic = os.path.join(bench, "traffic", w["traffic"] + ".json")
         with open(traffic) as f:
             driver = json.load(f)["driver"]
@@ -59,26 +53,41 @@ def test_every_name_finds_its_files():
         assert m["moves"] in e2e
         assert set(m.get("workloads", [])) <= cells
         assert os.path.exists(os.path.join(
-            ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
+            root, "benchmark", "layer_metrics", m["name"] + ".py"))
     for c in s["configs"]:
         assert c["file"].startswith("benchmark/")
-        with open(os.path.join(ROOT, c["file"])) as f:
+        with open(os.path.join(root, c["file"])) as f:
             cfg = json.load(f)
         assert cfg["reduced"] == c["reduced"]
         assert os.path.exists(os.path.join(
-            ROOT, "benchmark", "reference", cfg["reference"]["module"] + ".py"))
+            root, "benchmark", "reference", cfg["reference"]["module"] + ".py"))
+
+
+def test_limit_groups_name_compared_numbers(spec):
+    """A number over a group of leaves (a limits file's `groups`) has a
+    limit in that file, and names its reading, a layer type and one
+    parameter of it."""
+    for w in spec()["workloads"]:
+        with open(os.path.join(spec.root, "benchmark", "limits",
+                               w["name"] + ".json")) as f:
+            limits = json.load(f)
+        for name, g in limits.get("groups", {}).items():
+            assert name in limits["limits"]
+            assert set(g) == {"of", "layer_type", "param"}
+            assert g["of"] in ("grad1", "dparam")
 
 
 @pytest.mark.parametrize("name", ["alexnet", "resnet18"])
-def test_conf_copy_is_the_committed_conf(name):
+def test_conf_copy_is_the_committed_conf(spec, name):
     """The cell runs the example conf as committed: the copy under
     benchmark/ holds the same pairs in the same order, the data and
     eval iterator blocks apart."""
-    with open(os.path.join(ROOT, "benchmark", "configs", name + ".json")) as f:
+    root = spec.root
+    with open(os.path.join(root, "benchmark", "configs", name + ".json")) as f:
         cfg = json.load(f)
-    with open(os.path.join(ROOT, cfg["conf"])) as f:
+    with open(os.path.join(root, cfg["conf"])) as f:
         copy = confnet.parse_pairs(f.read())
-    with open(os.path.join(ROOT, cfg["copied_from"])) as f:
+    with open(os.path.join(root, cfg["copied_from"])) as f:
         original = confnet.parse_pairs(f.read())
     kept, skip = [], False
     for k, v in original:

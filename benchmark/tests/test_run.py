@@ -32,7 +32,21 @@ def driver():
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_result_line_has_the_contracts_keys(capsys, cell):
+def test_cell_is_the_one_the_issue_names(spec, cell):
+    w = spec.entry("workloads", cell)
+    assert (w["config"], w["traffic"], w["chips"]) == (
+        cell.split(".")[0], "train_resident", 1)
+    loaded = bench.load_cell(cell)
+    assert loaded.traffic["driver"] == "train_resident"
+    listed = {m["name"] for m in loaded.per_layer}
+    assert {"fwd_ms", "bwd_ms", "update_ms", "conv_bwd_ms", "scoped_pct",
+            "step_mfu", "dispatch_ms.train", "device_idle_pct"} <= listed
+    # the token cells' metrics do not list it
+    assert not {"moe_ms", "masked_tile_share", "gqa_d64_ms"} & listed
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_result_line_has_the_contracts_keys(capsys, cell, rehearsed):
     assert bench.main(args_for(cell) + ["--trace", "1"]) == 0
     res, err = last_line(capsys)
     assert list(res) == ["correct", "attempted", "failed", "metrics",
@@ -48,6 +62,7 @@ def test_result_line_has_the_contracts_keys(capsys, cell):
     tail = err.strip().splitlines()[-len(limits) - 1:]
     assert tail[-1] == "correct = True"
     assert all(l.startswith("compared ok") for l in tail[:-1])
+    rehearsed(err, cell)
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -115,3 +130,20 @@ def test_control_in_a_lower_precision_is_not_correct(driver, name):
                if k in numbers)
     same, _ = driver.compare(want, want)
     assert all(v == 0 for v in same.values())
+
+
+def test_a_group_number_is_the_worst_gap_among_its_leaves(driver):
+    """Each gap taken as for all leaves (against the leaf's norm or the
+    median leaf's), the worst among the group's alone."""
+    ref = {"loss": [2.0], "grad1": dict.fromkeys(
+        ["l1_mla.wq", "l1_mla.wo", "l2_kda.wq", "l3_moe.w1"], 1.0)}
+    ref["dparam"] = dict(ref["grad1"])
+    prog = dict(ref, grad1={"l1_mla.wq": 1.01, "l1_mla.wo": 1.3,
+                            "l2_kda.wq": 1.2, "l3_moe.w1": 1.5})
+    nums, where = driver.compare(prog, ref, {"g": ("grad1", ["l1_mla.wq"])})
+    assert nums["g"] == pytest.approx(0.01) and where["g"] == "l1_mla.wq"
+    assert nums["grad1"] == pytest.approx(0.5) and where["grad1"] == "l3_moe.w1"
+    assert driver.compare(prog, ref)[0] == {
+        k: v for k, v in nums.items() if k != "g"}
+    with pytest.raises(ValueError, match="no leaf"):
+        driver.compare(prog, ref, {"g": ("grad1", ["l9_mla.wq"])})

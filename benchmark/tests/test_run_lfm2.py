@@ -29,7 +29,7 @@ def driver():
         bench.HERE, "drivers", "train_tokens.py"))
 
 
-def test_cell_is_the_one_the_issue_names():
+def test_cell_is_the_one_the_issue_names(spec):
     cell = bench.load_cell(CELL)
     assert cell.chips == 1 and cell.traffic["driver"] == "train_tokens"
     assert {k: cell.traffic[k] for k in (
@@ -40,18 +40,20 @@ def test_cell_is_the_one_the_issue_names():
     listed = [m["name"] for m in cell.per_layer]
     assert set(NEW) <= set(listed)
     assert {"step_mfu", "dispatch_ms.train", "device_idle_pct"} <= set(listed)
-    # the other token cells' metrics keep their lists
-    assert not {"moe_ms", "lm_head_ms", "moe_held_load", "gqa_full_ms",
-                "flash_gqa_roofline"} & set(listed)
-    with open(os.path.join(bench.ROOT, "BENCHMARK.json")) as f:
-        spec = json.load(f)
-    assert len(spec["workloads"]) == 5 and spec["workloads"][-1]["name"] == CELL
-    assert [m["name"] for m in spec["per_layer"][-3:]] == list(NEW)
-    for m in spec["per_layer"][-3:]:
+    # the expert and head metrics read this cell's layers too; the
+    # SmallThinker cell's attention metrics do not list it
+    assert {"moe_ms", "lm_head_ms", "moe_held_load",
+            "masked_tile_share"} <= set(listed)
+    assert not {"gqa_full_ms", "flash_gqa_roofline"} & set(listed)
+    w = spec.entry("workloads", CELL)
+    assert (w["config"], w["traffic"], w["chips"]) == (
+        "lfm2_24b_a2b", "train_seq32k", 1)
+    for name in NEW:
+        m = spec.entry("per_layer", name)
         assert m["workloads"] == [CELL] and m["moves"] == "train_img_s"
 
 
-def test_result_line_and_counters(capsys, driver, monkeypatch):
+def test_result_line_and_counters(capsys, driver, monkeypatch, rehearsed):
     seen = {}
     window = driver.window
 
@@ -70,6 +72,10 @@ def test_result_line_and_counters(capsys, driver, monkeypatch):
     assert list(res["compared"]) == list(limits)
     assert "compiles_in_window" in limits and "loss3" in limits
     assert err.strip().splitlines()[-1] == "correct = True"
+    # what the CPU can read: set-up's records and the counters (the XLA
+    # route masks every tile it runs: 1)
+    got = rehearsed(err, CELL)
+    assert got["masked_tile_share"] == 1.0 and got["moe_held_load"] >= 1.0
     # the counters the driver fetches after the window: the attention
     # layer's tiles and the four expert layers' held / load / dropped
     counters = seen["win"].counters
@@ -154,3 +160,21 @@ def test_readers_are_silent_on_the_other_cells_nets(cell):
     o = obs(CELL)
     o.net = net_of(cell)
     assert [reader(n).read(o) for n in NEW] == [None] * 3
+
+
+def test_masked_tile_share_reads_the_window_counters():
+    """The forward kernel masks 32 of the 528 live tiles of the one
+    `gqa` layer at 32,768 positions (the diagonal's); silent without the
+    counter and on a net without `gqa` layers."""
+    share = reader("masked_tile_share")
+    o = obs(CELL)
+    o.window.counters["l2_gqa.masked"] = 32 / 528
+    assert share.read(o) == pytest.approx(0.0606, abs=5e-5)
+    o.window.counters = {"l2_moe.load": 1.1}
+    assert share.read(o) is None
+    o.window = SimpleNamespace(steps=STEPS)
+    assert share.read(o) is None
+    o = obs(CELL)
+    o.window.counters["l2_gqa.masked"] = 0.5
+    o.net = net_of("kimi_linear_48b_a3b.train_seq8k")
+    assert share.read(o) is None

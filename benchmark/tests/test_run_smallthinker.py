@@ -30,7 +30,7 @@ def driver():
         bench.HERE, "drivers", "train_tokens.py"))
 
 
-def test_cell_is_the_one_the_issue_names():
+def test_cell_is_the_one_the_issue_names(spec):
     cell = bench.load_cell(CELL)
     assert cell.chips == 1 and cell.traffic["driver"] == "train_tokens"
     assert {k: cell.traffic[k] for k in (
@@ -40,12 +40,20 @@ def test_cell_is_the_one_the_issue_names():
     listed = [m["name"] for m in cell.per_layer]
     assert set(NEW) <= set(listed)
     assert {"step_mfu", "dispatch_ms.train", "device_idle_pct"} <= set(listed)
-    # the Kimi metrics keep their lists
-    assert not {"moe_ms", "lm_head_ms", "moe_held_load", "mla_ms"} & \
-        set(listed)
+    # the expert and head metrics read this cell's layers too; the Kimi
+    # cell's attention metric does not list it
+    assert {"moe_ms", "lm_head_ms", "moe_held_load",
+            "masked_tile_share"} <= set(listed)
+    assert "mla_ms" not in listed
+    w = spec.entry("workloads", CELL)
+    assert (w["config"], w["traffic"], w["chips"]) == (
+        "smallthinker_21b_a3b", "train_seq16k", 1)
+    for name in NEW:
+        m = spec.entry("per_layer", name)
+        assert m["workloads"] == [CELL] and m["moves"] == "train_img_s"
 
 
-def test_result_line_and_counters(capsys):
+def test_result_line_and_counters(capsys, rehearsed):
     assert bench.main(ARGS + ["--trace", "1"]) == 0
     res, err = last_line(capsys)
     assert list(res) == ["correct", "attempted", "failed", "metrics",
@@ -56,9 +64,11 @@ def test_result_line_and_counters(capsys):
     assert list(res["compared"]) == list(limits)
     assert "compiles_in_window" in limits and "loss3" in limits
     assert err.strip().splitlines()[-1] == "correct = True"
-    # the counter's reader found the layers' `tiles` (the CPU's XLA
-    # route masks and skips nothing: 1)
-    assert '"window_tile_share": {"value": 1.0' in err
+    # the counters' readers found the layers' `tiles` and `masked` (the
+    # CPU's XLA route masks and skips nothing: 1) and the experts' load
+    got = rehearsed(err, CELL)
+    assert got["window_tile_share"] == 1.0
+    assert got["masked_tile_share"] == 1.0 and got["moe_held_load"] >= 1.0
 
 
 @pytest.mark.parametrize("variant", ["float8_e4m3fn", "no_window",
@@ -169,3 +179,19 @@ def test_readers_are_silent_on_a_net_without_gqa_layers(cell):
     # (the Kimi step runs `flash_fwd` under its `mla` layer: the
     # roofline's work is the `gqa` layers', so it stays silent there)
     assert [reader(n).read(o) for n in NEW] == [None] * 4
+
+
+def test_masked_tile_share_reads_the_window_counters():
+    """The mean over all eight `gqa` layers: a full layer masks 16 of
+    its 136 live tiles, a window layer 28 of 70; silent without the
+    counters."""
+    share = reader("masked_tile_share")
+    o = obs(CELL)
+    o.window.counters.update({f"l{i}_gqa.masked": (28 / 70 if i % 4 else
+                                                   16 / 136)
+                              for i in range(8)})
+    assert share.read(o) == pytest.approx(
+        (2 * 16 / 136 + 6 * 28 / 70) / 8)
+    assert share.read(o) == pytest.approx(0.3294, abs=5e-5)
+    o.window.counters = {"l1_moe.load": 1.2, "l1_gqa.tiles": 0.5}
+    assert share.read(o) is None

@@ -1,8 +1,13 @@
 """`tools/setup_phases.py` on made-up set-up span records: the rows add
-up to the harness's `setup_s`, and the span sums count a child once."""
+up to the harness's `setup_s`, and the span sums count a child once; the
+five set-up readers give the same sums, and nothing without records."""
+
+import os
+from types import SimpleNamespace
 
 import pytest
 
+from benchmark import run as bench, setup_spans
 from benchmark.tools import setup_phases as sp
 
 
@@ -42,3 +47,51 @@ def test_span_sums_take_the_top_level_spans_only():
         "setup_init_s": 8.0, "setup_step_s": 4.0,
         "setup_trace_s": 1.5 + 2.8, "setup_compile_s": 2.2 + 1.0,
         "setup_traces": 1000})
+
+
+# ---------------------------------------------------------------------------
+# the five per-layer readers: the same sums, from what the harness fetched
+# ---------------------------------------------------------------------------
+READERS = ("setup_init_s", "setup_step_s", "setup_trace_s",
+           "setup_compile_s", "setup_traces")
+# a `setup.step` still open, as the program records one: no end, no counts
+OPEN = RECORDS[:4] + [{"name": "setup.step", "parent": None,
+                       "start": 19.0, "end": None}]
+CASES = {
+    "nested": (RECORDS, {"setup_init_s": 8.0, "setup_step_s": 4.0,
+                         "setup_trace_s": 4.3, "setup_compile_s": 3.2,
+                         "setup_traces": 1000}),
+    "open_step": (OPEN, {"setup_init_s": 8.0, "setup_step_s": None,
+                         "setup_trace_s": 1.5, "setup_compile_s": 2.2,
+                         "setup_traces": 300}),
+    "no_records": ([], dict.fromkeys(READERS)),
+}
+
+
+def reader(name):
+    return bench.load_module(os.path.join(bench.HERE, "layer_metrics",
+                                          name + ".py"))
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+@pytest.mark.parametrize("name", READERS)
+def test_each_reader_is_the_tools_sum(name, case):
+    records, want = CASES[case]
+    got = reader(name).read(SimpleNamespace(setup=records))
+    assert got == sp.span_metrics(records).get(name)
+    if want[name] is None:
+        assert got is None
+    else:
+        assert got == pytest.approx(want[name])
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_a_reader_is_silent_without_the_programs_records(name):
+    """A program without `fetch_setup()` (the harness then sets None), and
+    an observation made before the harness fetched anything."""
+    assert reader(name).read(SimpleNamespace(setup=None)) is None
+    assert reader(name).read(SimpleNamespace()) is None
+
+
+def test_one_definition_of_the_sums():
+    assert sp.span_metrics is setup_spans.span_metrics

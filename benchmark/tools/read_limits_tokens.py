@@ -15,8 +15,10 @@ mechanism broken (`no_decay`: alpha = 1; `no_routed`: the held experts'
 part zeroed; `rotary_on`: a rotary embedding this model does not have),
 and `half`: the program fed rows whose second half of positions repeats
 the first. (`unchanged`, a step that returns its state, reads `grad1` =
-`dparam` = 1 by construction and needs no run.) One JSON line a seed.
-Needs the chip unless `--dry-run`.
+`dparam` = 1 by construction and needs no run.) One JSON line a seed;
+`norms` holds each side's first-gradient norm by leaf, so that a number
+over a group of leaves can be read again without a chip. Needs the chip
+unless `--dry-run`.
 """
 
 from __future__ import annotations
@@ -67,6 +69,7 @@ def main() -> int:
     if args.dry_run:
         overrides.update(cell.cfg["dry_run_overrides"])
     ref = driver.make_reference(cell.cfg, overrides)
+    groups = bench.leaf_groups(cell, ref.net)
     variants = [v for v in args.variants.split(",") if v]
     make = driver.make_batches
     for n, seed in enumerate(int(s) for s in args.seeds.split(",")):
@@ -81,7 +84,9 @@ def main() -> int:
                "loss": reference["loss"], "program_loss": program["loss"],
                "program_s": t1 - t0, "reference_s": time.perf_counter() - t1}
         row["program"], row["program_leaf"] = driver.compare(
-            program, reference)
+            program, reference, groups)
+        row["norms"] = {"reference": reference["grad1"],
+                        "program": program["grad1"]}
         for v in (variants if n < args.variant_seeds else []):
             if v == "half":
                 driver.make_batches = lambda *a, **k: half_rows(make(*a, **k))
@@ -96,7 +101,8 @@ def main() -> int:
                 got = driver.reference_readings(
                     driver.make_reference(cell.cfg, overrides, v),
                     cell.cfg, cell.traffic, seed, batches)
-            row[v], row[v + "_leaf"] = driver.compare(got, reference)
+            row[v], row[v + "_leaf"] = driver.compare(got, reference, groups)
+            row["norms"][v] = got["grad1"]
         row["seconds"] = time.perf_counter() - t0
         line = json.dumps(row)
         print(line, flush=True)

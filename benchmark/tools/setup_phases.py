@@ -16,8 +16,9 @@ prints one JSON line:
   rest, the two further steps and the harness's norms, and what lies
   between the end of set-up and the window;
 - `spans`: what the program's set-up spans read, summed over the
-  top-level ones as a per-layer reader would (`setup_init_s`,
-  `setup_step_s`, `setup_trace_s`, `setup_compile_s`, `setup_traces`);
+  top-level ones by the per-layer readers' own code
+  (`benchmark/setup_spans.py`: `setup_init_s`, `setup_step_s`,
+  `setup_trace_s`, `setup_compile_s`, `setup_traces`);
 - `jax`: the program's `jax.*` counters over the whole process;
 - `dispatch_ms`: the median host time of one window step.
 
@@ -46,6 +47,8 @@ import json                            # noqa: E402
 import statistics                      # noqa: E402
 from typing import Any, Dict, List, Optional, Tuple   # noqa: E402
 
+from benchmark.setup_spans import span_metrics   # noqa: E402
+
 
 def phases(t_start: float, runtime_s: float, prep_end: float,
            win_t0: float, records: List[Dict[str, Any]]
@@ -71,22 +74,6 @@ def phases(t_start: float, runtime_s: float, prep_end: float,
     rows.append(("further_steps_and_norms", prep_end - step["end"]))
     rows.append(("to_window", win_t0 - prep_end))
     return rows
-
-
-def span_metrics(records: List[Dict[str, Any]]) -> Dict[str, float]:
-    """The five numbers a per-layer reader of the set-up spans would
-    give: over the top-level spans, so a child is not counted twice."""
-    top = [r for r in records if r["parent"] is None]
-    init = [r for r in top if r["name"] == "setup.init"]
-    steps = [r for r in top if r["name"] == "setup.step"]
-    return {
-        "setup_init_s": sum(r["end"] - r["start"] for r in init),
-        "setup_step_s": sum(r["end"] - r["start"] for r in steps),
-        "setup_trace_s": sum(r["trace_s"] + r["lower_s"] for r in top),
-        "setup_compile_s": sum(r["compile_s"] + r["cache_load_s"]
-                               for r in top),
-        "setup_traces": sum(r["traces"] for r in top),
-    }
 
 
 def main(argv: Optional[List[str]] = None) -> int:
