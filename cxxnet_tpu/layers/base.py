@@ -19,6 +19,7 @@ Shapes are full NCHW tuples (batch, channel, y, x); "matrix" nodes are
 
 from __future__ import annotations
 
+import copy
 import math
 from typing import Dict, List, Optional, Sequence, Tuple, Type
 
@@ -153,9 +154,27 @@ class Layer:
         self.name = name
         self.param = LayerParam()
 
+    #: keys a shared connection (`share[tag]`) may set for itself: it
+    #: then runs a copy of its primary with those keys set, over the
+    #: primary's weights (nnet/net_config.py); none for most types
+    connection_keys: Tuple[str, ...] = ()
+
     # --- configuration ---------------------------------------------------
     def set_param(self, name: str, val: str) -> None:
         self.param.set_param(name, val)
+
+    def for_connection(self, pairs: List[Tuple[str, str]]) -> "Layer":
+        """A copy of this layer with a shared connection's own keys
+        set; the weights stay the primary's."""
+        bad = [k for k, _ in pairs if k not in self.connection_keys]
+        if bad:
+            raise ValueError(f"a shared {self.type_name} connection may "
+                             f"set only {self.connection_keys}, not {bad}")
+        twin = copy.copy(self)
+        twin.param = copy.copy(self.param)
+        for k, v in pairs:
+            twin.set_param(k, v)
+        return twin
 
     # --- structure -------------------------------------------------------
     def infer_shapes(self, in_shapes: List[Shape]) -> List[Shape]:

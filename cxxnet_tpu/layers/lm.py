@@ -2,7 +2,9 @@
 token embedding, RMS norm, gated FFN, the two token mixers of the
 Kimi-Linear family (`kda`, `mla`), the double-gated short convolution
 of the LFM2 family (`gconv`), grouped-query attention with rotary, a
-window and QK-norm (`gqa`) and the untied head with its next-token loss.
+window and QK-norm (`gqa`), the Mamba-2 mixer and the
+multi-token-prediction entry of the Nemotron-H family (`mamba2`, `mtp`)
+and the untied head with its next-token loss.
 
 embed     node of token ids (batch, 1, seq, 1), INTEGER, -> (batch, 1,
           seq, nhidden). Keys: nvocab, nhidden. Ids stay integers from
@@ -51,15 +53,33 @@ gqa       grouped-query causal attention: `nhead` query heads read the
           kernel (`pallas_attention.fused_backward`), else 0.
           Keys: nhead, nkvhead, head_dim, window (0), rope_theta (0),
           qk_norm (0), eps (1e-5).
+mamba2    the Mamba-2 mixer: [z | xBC | dt] = x Win; xBC = SiLU(causal
+          depthwise conv of `conv_size` taps + bias), split into X
+          (nhead heads of head_dim), B and C (ngroups groups of
+          ssm_state; head h reads group h // (nhead / ngroups));
+          dt = softplus(dt + dt_bias), A = -exp(A_log);
+          S_t = exp(dt_t A) S_{t-1} + dt_t X_t B_t^T, Y_t = S_t C_t
+          + D X_t in chunks (ops/ssd.py); Y = RMSNorm over groups of
+          nhead head_dim / ngroups channels of (Y * SiLU(z)), the gate
+          before the norm; out = Y Wout. Keys: nhead, head_dim,
+          ngroups (1), ssm_state, conv_size (4), ssd_chunk (128), eps.
+mtp       a multi-token-prediction depth's entry: inputs the trunk's
+          hidden node (before its final norm) and the embedding node;
+          out = [RMSNorm(embedding of token t+1) ; RMSNorm(h_t)] Weh.
+          Key: eps.
 lm_head   inputs: the final hidden node and the token node. Output: the
           logits (batch, 1, seq, nvocab) - what `task = pred` and
           `extract` read. In training its loss term is the mean over
-          positions t < seq-1 of the cross-entropy of position t's
-          logits against token t+1, summed over rows (the trainer
+          positions t < seq-k of the cross-entropy of position t's
+          logits against token t+k (k = `target_offset`, 1), times
+          `loss_weight` (1), summed over rows (the trainer
           divides by the batch), computed `loss_block` positions at a
           time under `jax.checkpoint`, so that the float32 logits of a
-          long sequence and their gradient are never all live.
-          Keys: nvocab, loss_block (1024).
+          long sequence and their gradient are never all live. A second
+          pass of one head is a shared connection that sets those two
+          keys (`share[lm_head]:mtp_head`); it counts its unweighted
+          loss (`loss`). Keys: nvocab, loss_block (1024),
+          target_offset (1), loss_weight (1).
 
 Every leaf starts from `init_sigma x N(0, 1)` unless said otherwise in
 `_start`; the benchmark's reference (benchmark/reference/kimi_linear.py)
@@ -77,13 +97,14 @@ import jax.numpy as jnp
 from cxxnet_tpu.layers.base import Layer, Params, Shape, register_layer
 from cxxnet_tpu.ops import attention as ops_attn
 from cxxnet_tpu.ops import kda as ops_kda
+from cxxnet_tpu.ops import ssd as ops_ssd
 
 Table = List[Tuple[str, Tuple[int, ...], str]]
 
 
 def _start(key: jax.Array, table: Table, sigma: float) -> Params:
     """One key a leaf, in the table's order. `normal`: sigma x N(0, 1);
-    `ones`; `conv`: U(+-1/sqrt(width)); `a_log`: log U(1, 16);
+    `ones`; `zeros`; `conv`: U(+-1/sqrt(width)); `a_log`: log U(1, 16);
     `dt_bias`: the inverse softplus of a step drawn log-uniformly from
     (1e-3, 1e-1)."""
     out: Params = {}
@@ -93,6 +114,8 @@ def _start(key: jax.Array, table: Table, sigma: float) -> Params:
             out[name] = sigma * jax.random.normal(k, shape, jnp.float32)
         elif how == "ones":
             out[name] = jnp.ones(shape, jnp.float32)
+        elif how == "zeros":
+            out[name] = jnp.zeros(shape, jnp.float32)
         elif how == "conv":
             a = 1.0 / math.sqrt(shape[0])
             out[name] = jax.random.uniform(k, shape, jnp.float32, -a, a)
@@ -382,6 +405,105 @@ class GConvLayer(_TableLayer):
 
 
 @register_layer
+class Mamba2Layer(_TableLayer):
+    type_name = "mamba2"
+    #: [z | xBC | dt], the conv's output, every chunk's pair matrices
+    #: and the gated norm's input. At 8,192 positions of 16 heads the
+    #: five layers of the one conf that runs them and its two `gqa`
+    #: layers left 0.7 GB unsaved checkpointed (12.7 GB for 13.4) at
+    #: a cost of 2.5% of the rate on one TPU v5e: the conf says
+    #: `remat = 0`
+    remat_worthy = True
+
+    def __init__(self, name: str = ""):
+        super().__init__(name)
+        self.nhead = 0
+        self.head_dim = 0
+        self.ngroups = 1
+        self.state = 0
+        self.conv_size = 4
+        self.chunk = 128
+        self.eps = 1e-5
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == "nhead":
+            self.nhead = int(val)
+        if name == "head_dim":
+            self.head_dim = int(val)
+        if name == "ngroups":
+            self.ngroups = int(val)
+        if name == "ssm_state":
+            self.state = int(val)
+        if name == "conv_size":
+            self.conv_size = int(val)
+        if name == "ssd_chunk":
+            self.chunk = int(val)
+        if name == "eps":
+            self.eps = float(val)
+
+    def shapes(self, in_shapes):
+        self.check_one_to_one(in_shapes)
+        _seq(in_shapes[0], "mamba2")
+        if min(self.nhead, self.head_dim, self.ngroups, self.state,
+               self.conv_size, self.chunk) <= 0:
+            raise ValueError("mamba2: must set nhead, head_dim, ngroups "
+                             "and ssm_state")
+        if self.nhead % self.ngroups:
+            raise ValueError("mamba2: nhead must be a multiple of ngroups")
+        return [in_shapes[0]]
+
+    def _widths(self) -> Tuple[int, int]:
+        """(d_in, the conv's channels: x, B and C)."""
+        d_in = self.nhead * self.head_dim
+        return d_in, d_in + 2 * self.ngroups * self.state
+
+    def table(self, in_shapes):
+        e = in_shapes[0][3]
+        d_in, conv = self._widths()
+        h = self.nhead
+        return [("win", (e, d_in + conv + h), "normal"),
+                ("conv", (self.conv_size, conv), "conv"),
+                ("conv_b", (conv,), "zeros"),
+                ("dt_bias", (h,), "dt_bias"), ("a_log", (h,), "a_log"),
+                ("d", (h,), "ones"), ("norm", (d_in,), "ones"),
+                ("wout", (d_in, e), "normal")]
+
+    def apply(self, params, inputs, *, train, rng=None):
+        p = params
+        b, _, t, e = inputs[0].shape
+        x = inputs[0].reshape(b, t, e)
+        h, hd, g, n = self.nhead, self.head_dim, self.ngroups, self.state
+        d_in, conv = self._widths()
+        f32 = jnp.float32
+        with jax.named_scope("proj"):
+            zxd = _lin(x, p["win"])
+            z, xbc, dt = (zxd[..., :d_in], zxd[..., d_in:d_in + conv],
+                          zxd[..., d_in + conv:])
+        with jax.named_scope("conv"):
+            xbc = jax.nn.silu(short_conv(xbc, p["conv"])
+                              + p["conv_b"].astype(xbc.dtype))
+            xs = xbc[..., :d_in].reshape(b, t, h, hd)
+            bb = xbc[..., d_in:d_in + g * n].reshape(b, t, g, n)
+            cc = xbc[..., d_in + g * n:].reshape(b, t, g, n)
+        with jax.named_scope("ssd"):
+            # the step and the decay in float32 inside a bf16 step
+            dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+            a = -jnp.exp(p["a_log"].astype(f32))
+            y = ops_ssd.ssd_chunked(xs, dt, a, bb, cc, self.chunk)
+            y = (y.astype(f32) + p["d"].astype(f32)[:, None]
+                 * xs.astype(f32)).reshape(b, t, d_in)
+        with jax.named_scope("norm"):
+            # gated, then normed over groups of d_in / ngroups channels
+            y = (y * jax.nn.silu(z.astype(f32))).reshape(b, t, g, d_in // g)
+            y = rms_norm(y, p["norm"].reshape(g, d_in // g), self.eps)
+            y = y.reshape(b, t, d_in).astype(x.dtype)
+        with jax.named_scope("out"):
+            out = _lin(y, p["wout"])
+        return [out.reshape(b, 1, t, e)]
+
+
+@register_layer
 class MLALayer(_TableLayer):
     type_name = "mla"
     #: q, k, padded v, o, the latents and `lse`: 0.55 GB unsaved would
@@ -603,14 +725,60 @@ class GQALayer(_TableLayer):
 
 
 @register_layer
+class MTPLayer(_TableLayer):
+    type_name = "mtp"
+
+    def __init__(self, name: str = ""):
+        super().__init__(name)
+        self.eps = 1e-5
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == "eps":
+            self.eps = float(val)
+
+    def shapes(self, in_shapes):
+        if len(in_shapes) != 2 or tuple(in_shapes[0]) != tuple(in_shapes[1]):
+            raise ValueError("mtp: inputs are the trunk's hidden node and "
+                             "the embedding node, of one shape")
+        _seq(in_shapes[0], "mtp")
+        return [in_shapes[0]]
+
+    def table(self, in_shapes):
+        e = in_shapes[0][3]
+        return [("enorm", (e,), "ones"), ("hnorm", (e,), "ones"),
+                ("weh", (2 * e, e), "normal")]
+
+    def apply(self, params, inputs, *, train, rng=None):
+        p = params
+        h, emb = inputs
+        b, _, t, e = h.shape
+        with jax.named_scope("shift"):
+            # position t reads the embedding of token t+1; the last
+            # position, whose target t+2 does not exist, reads zeros
+            nxt = jnp.concatenate(
+                [emb[:, :, 1:], jnp.zeros_like(emb[:, :, :1])], axis=2)
+        with jax.named_scope("proj"):
+            cat = jnp.concatenate([rms_norm(nxt, p["enorm"], self.eps),
+                                   rms_norm(h, p["hnorm"], self.eps)], -1)
+            out = _lin(cat, p["weh"])
+        return [out]
+
+
+@register_layer
 class LMHeadLayer(_TableLayer):
     type_name = "lm_head"
     has_aux = True
+    #: a second pass of one head (`share[lm_head]`, the multi-token
+    #: prediction's) sets its own target and weight
+    connection_keys = ("target_offset", "loss_weight")
 
     def __init__(self, name: str = ""):
         super().__init__(name)
         self.nvocab = 0
         self.loss_block = 1024
+        self.offset = 1
+        self.weight = 1.0
 
     def set_param(self, name, val):
         super().set_param(name, val)
@@ -618,6 +786,16 @@ class LMHeadLayer(_TableLayer):
             self.nvocab = int(val)
         if name == "loss_block":
             self.loss_block = int(val)
+        if name == "target_offset":
+            self.offset = int(val)
+        if name == "loss_weight":
+            self.weight = float(val)
+
+    @property
+    def stat_names(self) -> Tuple[str, ...]:
+        """`loss`, the unweighted loss, where the head is not the plain
+        next-token one; nothing else counts."""
+        return ("loss",) if (self.offset, self.weight) != (1, 1.0) else ()
 
     def shapes(self, in_shapes):
         if len(in_shapes) != 2:
@@ -629,20 +807,23 @@ class LMHeadLayer(_TableLayer):
                              f"node (batch, 1, seq, 1), got {in_shapes[1]}")
         if self.nvocab <= 0:
             raise ValueError("lm_head: must set nvocab")
+        if self.offset < 1:
+            raise ValueError("lm_head: target_offset is 1 or more")
         return [(b, 1, s, self.nvocab)]
 
     def table(self, in_shapes):
         return [("wmat", (in_shapes[0][3], self.nvocab), "normal")]
 
     def _loss(self, w, y, ids, mask):
-        """Sum over rows of the mean over t < T-1 of CE(logits_t,
-        id_{t+1}). y (b, T, e), ids (b, T)."""
+        """Sum over rows of the mean over t < T-k of CE(logits_t,
+        id_{t+k}), k = `target_offset`. y (b, T, e), ids (b, T)."""
         b, t, e = y.shape
+        k = self.offset
         blk = min(self.loss_block, t)
         pad = (-t) % blk
-        target = jnp.concatenate([ids[:, 1:], ids[:, :1]], axis=1)
+        target = jnp.concatenate([ids[:, k:], ids[:, :k]], axis=1)
         weight = jnp.broadcast_to(
-            (jnp.arange(t) < t - 1).astype(jnp.float32), (b, t))
+            (jnp.arange(t) < t - k).astype(jnp.float32), (b, t))
         if mask is not None:
             weight = weight * mask.astype(jnp.float32)[:, None]
         if pad:
@@ -669,9 +850,10 @@ class LMHeadLayer(_TableLayer):
 
         total, _ = jax.lax.scan(part, jnp.zeros((), jnp.float32),
                                 (blocks(y), blocks(target), blocks(weight)))
-        return total / max(t - 1, 1)
+        return total / max(t - k, 1)
 
-    def apply_with_aux(self, params, inputs, *, train, rng=None, mask=None):
+    def apply_with_stats(self, params, inputs, *, train, rng=None,
+                         mask=None):
         b, _, t, e = inputs[0].shape
         y = inputs[0].reshape(b, t, e)
         ids = inputs[1].reshape(b, t)
@@ -681,7 +863,14 @@ class LMHeadLayer(_TableLayer):
             logits = _lin(y, params["wmat"]).reshape(b, 1, t, self.nvocab)
         loss = (self._loss(params["wmat"], y, ids, mask) if train
                 else jnp.zeros((), jnp.float32))
-        return [logits], loss
+        stats = {"loss": loss} if self.stat_names else {}
+        if self.weight != 1.0:
+            loss = self.weight * loss
+        return [logits], loss, stats
+
+    def apply_with_aux(self, params, inputs, *, train, rng=None, mask=None):
+        return self.apply_with_stats(params, inputs, train=train, rng=rng,
+                                     mask=mask)[:2]
 
     def apply(self, params, inputs, *, train, rng=None):
         return self.apply_with_aux(params, inputs, train=train, rng=rng)[0]

@@ -24,7 +24,14 @@ top-k routed FFN over (batch, 1, seq, embed) sequence nodes.
   node, the router the second (a model whose router reads the token
   mixer's input, not the FFN's). One input is both.
 - `moe_shared = n`: a shared expert of n x nhidden beside the routed
-  ones (s1, s3, s2; gated as `moe_glu` says), run on every token.
+  ones (s1, s3, s2; gated as `moe_glu` says), run on every token;
+  `moe_shared_width = c` gives it c columns instead (a chip's share of
+  a shared expert split by columns need not be a multiple of nhidden).
+- `moe_latent = l`: the routed experts work in a latent width l: u =
+  x lat_in^T, routed = (sum of the weighted experts on u) lat_out^T
+  (LatentMoE). The router and the shared expert read x itself.
+- `moe_act = relu2`: an expert without a gate is relu(x w1^T)^2 w2^T
+  (squared ReLU; with `no_bias = 1` there is no bias).
 - `moe_held = first,count`: this device holds experts
   [first, first + count) of the `nexpert` the router scores (the chip's
   share of a deployment whose experts are spread over chips). The
@@ -60,8 +67,8 @@ top-k routed FFN over (batch, 1, seq, embed) sequence nodes.
 
 Config keys: nexpert, nhidden (per-expert FFN hidden), moe_top_k
 (default 1), moe_aux (default 0.01), moe_score, moe_norm_topk, moe_scale,
-moe_norm_eps (default 0), moe_glu, moe_act, moe_shared, moe_held,
-moe_bias_sigma, no_bias.
+moe_norm_eps (default 0), moe_glu, moe_act, moe_shared,
+moe_shared_width, moe_latent, moe_held, moe_bias_sigma, no_bias.
 """
 
 from __future__ import annotations
@@ -244,6 +251,8 @@ class MoELayer(Layer):
         self.act = "silu"         # the gate of a `moe_glu` expert
         self.norm_topk = 0
         self.shared = 0
+        self.shared_width = 0     # columns of the shared expert, 0 = by `shared`
+        self.latent = 0           # the routed experts' width, 0 = the input's
         self.held = None          # (first, count), None = all
         self.bias_sigma = 0.0
 
@@ -275,11 +284,15 @@ class MoELayer(Layer):
         if name == "moe_glu":
             self.glu = int(val)
         if name == "moe_act":
-            if val not in ("silu", "relu"):
-                raise ValueError("moe_act must be silu or relu")
+            if val not in ("silu", "relu", "relu2"):
+                raise ValueError("moe_act must be silu, relu or relu2")
             self.act = val
         if name == "moe_shared":
             self.shared = int(val)
+        if name == "moe_shared_width":
+            self.shared_width = int(val)
+        if name == "moe_latent":
+            self.latent = int(val)
         if name == "moe_held":
             first, _, count = val.partition(",")
             self.held = (int(first), int(count))
@@ -308,10 +321,24 @@ class MoELayer(Layer):
         if first < 0 or count < 1 or first + count > self.nexpert:
             raise ValueError("moe: moe_held = first,count must lie inside "
                              f"the {self.nexpert} experts")
+        if min(self.latent, self.shared_width) < 0:
+            raise ValueError("moe: moe_latent and moe_shared_width are 0 "
+                             "or more")
+        if self.glu and self.act == "relu2":
+            raise ValueError("moe: moe_act = relu2 is for experts without "
+                             "a gate (moe_glu = 0)")
         return [in_shapes[0]]
 
+    @property
+    def _shared_hidden(self) -> int:
+        """The shared expert's columns: `moe_shared_width`, else
+        `moe_shared` x nhidden (0: none)."""
+        return self.shared_width or self.shared * self.param.num_hidden
+
     def init_params(self, key: jax.Array, in_shapes: List[Shape]) -> Params:
-        e = in_shapes[0][3]
+        ex = in_shapes[0][3]
+        # the routed experts' width: the latent's, where there is one
+        e = self.latent or ex
         h, g = self.param.num_hidden, self._first_count[1]
         kg, k1, k2 = jax.random.split(key, 3)
         rand = self.param.rand_init_weight
@@ -328,24 +355,32 @@ class MoELayer(Layer):
         elif self.param.no_bias == 0:
             params["b1"] = jnp.zeros((g, h), jnp.float32)
             params["b2"] = jnp.zeros((g, e), jnp.float32)
-        if self.shared:
-            hs = h * self.shared
-            params["s1"] = rand(jax.random.fold_in(key, 4), (hs, e),
-                                in_num=e, out_num=hs)
+        hs = self._shared_hidden
+        if hs:
+            params["s1"] = rand(jax.random.fold_in(key, 4), (hs, ex),
+                                in_num=ex, out_num=hs)
             if self.glu:
-                params["s3"] = rand(jax.random.fold_in(key, 5), (hs, e),
-                                    in_num=e, out_num=hs)
-            params["s2"] = rand(jax.random.fold_in(key, 6), (e, hs),
-                                in_num=hs, out_num=e)
+                params["s3"] = rand(jax.random.fold_in(key, 5), (hs, ex),
+                                    in_num=ex, out_num=hs)
+            params["s2"] = rand(jax.random.fold_in(key, 6), (ex, hs),
+                                in_num=hs, out_num=ex)
         if self.score == "sigmoid":
             params["sbias"] = self.bias_sigma * jax.random.normal(
                 jax.random.fold_in(key, 7), (self.nexpert,), jnp.float32)
+        if self.latent:
+            params["lat_in"] = rand(jax.random.fold_in(key, 8),
+                                    (self.latent, ex), in_num=ex,
+                                    out_num=self.latent)
+            params["lat_out"] = rand(jax.random.fold_in(key, 9),
+                                     (ex, self.latent), in_num=self.latent,
+                                     out_num=ex)
         return params
 
     def param_tags(self) -> Dict[str, str]:
         # `sbias` has no tag: it gets no updater and takes no step
         return {"gate": "wmat", "w1": "wmat", "w2": "wmat", "w3": "wmat",
                 "s1": "wmat", "s2": "wmat", "s3": "wmat",
+                "lat_in": "wmat", "lat_out": "wmat",
                 "b1": "bias", "b2": "bias"}
 
     def expert_shard_dims(self) -> Dict[str, int]:
@@ -413,6 +448,8 @@ class MoELayer(Layer):
             if "b1" in w:
                 a = a + w["b1"].astype(dt)
             h1 = jnp.maximum(a, 0.0)
+            if self.act == "relu2":
+                h1 = h1 * h1
         y = jnp.einsum("nh,eh->ne", h1, w["w2"].astype(dt))
         if "b2" in w:
             y = y + w["b2"].astype(dt)
@@ -484,16 +521,26 @@ class MoELayer(Layer):
             weights, chosen, aux = self._route(
                 params, xs if len(inputs) == 1
                 else inputs[1].reshape(b, s, -1), mask)
+        u = xs
+        if self.latent:
+            # the routed experts work in the latent width
+            with jax.named_scope("latent_in"):
+                u = jnp.einsum("bse,le->bsl", xs,
+                               params["lat_in"].astype(xs.dtype))
         if self._dropless():
-            out, stats = self._dropless_compute(params, xs, weights, chosen)
+            out, stats = self._dropless_compute(params, u, weights, chosen)
         else:
             with jax.named_scope("experts"):
-                out = self._dense_compute(params, xs, weights, chosen)
+                out = self._dense_compute(params, u, weights, chosen)
             first, count = self._first_count
             stats = self._stats(jnp.sum(jax.nn.one_hot(
                 chosen - first, count, dtype=jnp.int32),
                 axis=(0, 1, 2)), 0)
-        if self.shared:
+        if self.latent:
+            with jax.named_scope("latent_out"):
+                out = jnp.einsum("bsl,el->bse", out,
+                                 params["lat_out"].astype(out.dtype))
+        if self._shared_hidden:
             with jax.named_scope("shared"):
                 w = {"w1": params["s1"], "w2": params["s2"]}
                 if self.glu:

@@ -15,7 +15,10 @@ Behavioral parity with src/nnet/nnet_config.h:26-411:
   Node names may be arbitrary strings; node "0"/"in" is the data input.
   Input nodes must already exist; output nodes are allocated on first use.
 - `share[tag]` layers reuse the params of the primary layer named `tag`
-  (weight sharing; kSharedLayer).
+  (weight sharing; kSharedLayer). `share[tag]:name` names the
+  connection; it may set the few keys its primary's class lists in
+  `connection_keys` (lm_head: `target_offset`, `loss_weight`), and no
+  other.
 - Global params captured here: `updater`, `sync`, `label_vec[a,b) = name`
   (label column slicing), `input_shape = c,h,w`, `extra_data_num`,
   `extra_data_shape[i] = c,h,w`.
@@ -54,6 +57,14 @@ class LayerInfo:
                 and self.name == other.name
                 and self.nindex_in == other.nindex_in
                 and self.nindex_out == other.nindex_out)
+
+
+def _connection_keys(type_name: str) -> Tuple[str, ...]:
+    """The keys a shared connection of a layer of this type may set for
+    itself (`Layer.connection_keys`): none for most types."""
+    from cxxnet_tpu.layers import LAYER_REGISTRY
+    cls = LAYER_REGISTRY.get(type_name)
+    return getattr(cls, "connection_keys", ())
 
 
 class NetConfig:
@@ -148,7 +159,9 @@ class NetConfig:
                 cfg_layer_index += 1
                 continue
             if netcfg_mode == 2:
-                if self.layers[cfg_layer_index - 1].is_shared:
+                info = self.layers[cfg_layer_index - 1]
+                if info.is_shared and name not in _connection_keys(
+                        self.layers[info.primary_layer_index].type_name):
                     raise ValueError(
                         "please do not set parameters in shared layer, "
                         "set them in primary layer")
@@ -231,15 +244,17 @@ class NetConfig:
             info.primary_layer_index = self.layer_name_map[tag]
         else:
             info.type_name = ltype
-            if layer_name:
-                if layer_name in self.layer_name_map:
-                    if self.layer_name_map[layer_name] != cfg_layer_index:
-                        raise ValueError(
-                            "ConfigError: layer name in the configuration "
-                            "file does not match the name stored in model")
-                else:
-                    self.layer_name_map[layer_name] = cfg_layer_index
-                info.name = layer_name
+        if layer_name:
+            # a shared connection may be named too: its scope and its
+            # counters then carry that name (`share[tag]:name`)
+            if layer_name in self.layer_name_map:
+                if self.layer_name_map[layer_name] != cfg_layer_index:
+                    raise ValueError(
+                        "ConfigError: layer name in the configuration "
+                        "file does not match the name stored in model")
+            else:
+                self.layer_name_map[layer_name] = cfg_layer_index
+            info.name = layer_name
         return info
 
     # ------------------------------------------------------------------

@@ -76,6 +76,10 @@ class Network:
         for idx, info in enumerate(cfg.layers):
             if info.is_shared:
                 layer = self.layer_objs[info.primary_layer_index]
+                if cfg.layercfg[idx]:
+                    # a connection with keys of its own (lm_head's
+                    # `target_offset`): a copy over the same weights
+                    layer = layer.for_connection(cfg.layercfg[idx])
             else:
                 layer = create_layer(info.type_name, info.name)
                 for k, v in cfg.defcfg:
@@ -242,8 +246,10 @@ class Network:
                     # (contract on Layer.has_aux, layers/base.py)
                     total_loss = total_loss + aux
                 if counters is not None:
-                    counters.update(
-                        {f"{pkey}.{k}": v for k, v in stats.items()})
+                    # under the connection's own key: two uses of one
+                    # set of weights count apart
+                    counters.update({f"{param_key(cfg, idx)}.{k}": v
+                                     for k, v in stats.items()})
                 for j, o in zip(info.nindex_out, outs):
                     values[j] = o
 

@@ -680,7 +680,6 @@ class NetTrainer:
                     (), jnp.float32)
                 for i, l in enumerate(self.net.layer_objs)
                 if hasattr(l, "apply_with_stats")
-                and not self.net_cfg.layers[i].is_shared
                 for n in l.stat_names}
         if self._loaded_opt is not None:
             state["ustate"] = jax.tree.map(
@@ -1001,7 +1000,7 @@ class NetTrainer:
         # counters ride in the step's state, the last step's values,
         # and are fetched after it (`fetch_counters`), never inside
         count_layers = zrun < 2 and any(
-            hasattr(l, "apply_with_stats") for l in net.layer_objs)
+            getattr(l, "stat_names", ()) for l in net.layer_objs)
         self._count_layers = count_layers
 
         def loss_fn(params, data, extras, labels, mask, rng, step):

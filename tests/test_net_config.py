@@ -123,6 +123,40 @@ netconfig=end
 """)
 
 
+def test_a_named_shared_head_sets_its_own_target():
+    """`share[lm_head]:mtp_head` may set the keys its primary's class
+    lists (`target_offset`, `loss_weight`): it runs a copy over the
+    primary's one set of weights, named by its own key, and counts its
+    own loss under that key; any other key stays refused."""
+    text = """
+netconfig=start
+layer[0->x0] = embed:embed
+  nvocab = 16
+  nhidden = 8
+layer[x0,0->logits] = lm_head:lm_head
+  nvocab = 16
+layer[x0,0->second] = share[lm_head]:mtp_head
+  target_offset = 2
+  loss_weight = 0.5
+netconfig=end
+input_shape = 1,6,1
+"""
+    cfg = build(text)
+    assert cfg.layers[2].is_shared and cfg.layers[2].name == "mtp_head"
+    net = Network(cfg, batch_size=2)
+    head, second = net.layer_objs[1], net.layer_objs[2]
+    assert (head.offset, head.weight) == (1, 1.0)
+    assert (second.offset, second.weight) == (2, 0.5)
+    params = net.init_params(jax.random.PRNGKey(0))
+    assert sorted(params) == ["embed", "lm_head"]
+    counters = {}
+    ids = jnp.asarray(np.arange(12).reshape(2, 1, 6, 1) % 16, jnp.int32)
+    _, loss = net.forward(params, {0: ids}, train=True, counters=counters)
+    assert list(counters) == ["mtp_head.loss"]
+    with pytest.raises(ValueError, match="shared layer"):
+        build(text.replace("loss_weight = 0.5", "nvocab = 8"))
+
+
 def test_label_vec_slicing():
     cfg = build("""
 label_vec[0,1) = label
