@@ -74,8 +74,11 @@ lm_head   inputs: the final hidden node and the token node. Output: the
           logits against token t+k (k = `target_offset`, 1), times
           `loss_weight` (1), summed over rows (the trainer
           divides by the batch), computed `loss_block` positions at a
-          time under `jax.checkpoint`, so that the float32 logits of a
-          long sequence and their gradient are never all live. A second
+          time, so that the float32 logits of a long sequence and their
+          gradient are never all live (`head_loss`). The loss is the
+          step's sink, so its gradient is made in the forward: one scan
+          computes a block's logits, its loss, its dlogits and from them
+          dy and dW, and the backward only scales what it kept. A second
           pass of one head is a shared connection that sets those two
           keys (`share[lm_head]:mtp_head`); it counts its unweighted
           loss (`loss`). Keys: nvocab, loss_block (1024),
@@ -88,6 +91,7 @@ mirrors the order the keys are split in.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, List, Tuple
 
@@ -765,10 +769,121 @@ class MTPLayer(_TableLayer):
         return [out]
 
 
+def _head_blocks(y, ids, mask, k, blk):
+    """y (b, T, e), ids (b, T), mask (b,) or None -> the blocks (n, b,
+    blk, ...) of y, of the targets id_{t+k} and of the row weights
+    ([t < T-k] x the row's mask), padded with zeros to whole blocks."""
+    b, t, _ = y.shape
+    pad = (-t) % blk
+    target = jnp.concatenate([ids[:, k:], ids[:, :k]], axis=1)
+    weight = jnp.broadcast_to(
+        (jnp.arange(t) < t - k).astype(jnp.float32), (b, t))
+    if mask is not None:
+        weight = weight * mask.astype(jnp.float32)[:, None]
+    if pad:
+        y = jnp.pad(y, ((0, 0), (0, pad), (0, 0)))
+        target = jnp.pad(target, ((0, 0), (0, pad)))
+        weight = jnp.pad(weight, ((0, 0), (0, pad)))
+    n = (t + pad) // blk
+
+    def blocks(a):
+        return jnp.moveaxis(a.reshape((b, n, blk) + a.shape[2:]), 1, 0)
+
+    return blocks(y), blocks(target), blocks(weight)
+
+
+def _block_loss(w, yb, tb, wb):
+    """One block's float32 logits, their log-normaliser and the block's
+    summed weighted cross-entropy."""
+    with jax.named_scope("logits"):
+        logits = jnp.einsum("bte,ev->btv", yb, w.astype(yb.dtype),
+                            preferred_element_type=jnp.float32)
+    with jax.named_scope("loss"):
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        got = jnp.take_along_axis(logits, tb[..., None], axis=-1)[..., 0]
+        return logits, logz, jnp.sum((logz - got) * wb)
+
+
+def _means(total, t, k, weight):
+    mean = total / max(t - k, 1)
+    return weight * mean, mean
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def head_loss(w, y, ids, mask, k: int, blk: int, weight: float):
+    """(weight x mean, mean): mean is the sum over rows of the mean over
+    t < T-k of CE(y_t w, id_{t+k}), T = y.shape[1], computed `blk`
+    positions at a time, so that the float32 logits of a long sequence
+    are never all live. w (e, V), y (b, T, e), ids (b, T), mask (b,) or
+    None. Here, where nothing differentiates it: one product a block."""
+    yb, tb, wb = _head_blocks(y, ids, mask, k, blk)
+
+    def part(total, xs):
+        return total + _block_loss(w, *xs)[2], None
+
+    total, _ = jax.lax.scan(part, jnp.zeros((), jnp.float32), (yb, tb, wb))
+    return _means(total, y.shape[1], k, weight)
+
+
+def _head_loss_fwd(w, y, ids, mask, k, blk, weight):
+    """The loss is the step's sink, so its gradient is known here: each
+    block's dlogits = (softmax - onehot(target)) x the row weight x
+    weight / (T-k) (1 / (T-k) where weight is 0) feeds dy and dW in the
+    same scan, and no logits are computed again in the backward."""
+    b, t, e = y.shape
+    fold = (weight or 1.0) / max(t - k, 1)
+    yb, tb, wb = _head_blocks(y, ids, mask, k, blk)
+
+    def part(carry, xs):
+        total, dw = carry
+        y_blk, t_blk, w_blk = xs
+        logits, logz, ce = _block_loss(w, y_blk, t_blk, w_blk)
+        with jax.named_scope("loss_grad"):
+            hit = (jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+                   == t_blk[..., None])
+            dlogits = ((jnp.exp(logits - logz[..., None]) - hit)
+                       * (w_blk * fold)[..., None])
+            dy_blk = jax.lax.dot_general(
+                dlogits, w.astype(y.dtype), (((2,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(y.dtype)
+            dw = dw + jax.lax.dot_general(
+                y_blk, dlogits, (((0, 1), (0, 1)), ((), ())),
+                preferred_element_type=jnp.float32)
+        return (total + ce, dw), dy_blk
+
+    (total, dw), dy = jax.lax.scan(
+        part, (jnp.zeros((), jnp.float32), jnp.zeros(w.shape, jnp.float32)),
+        (yb, tb, wb))
+    dy = jnp.moveaxis(dy, 0, 1).reshape(b, -1, e)[:, :t]
+    return _means(total, t, k, weight), (dy, dw.astype(w.dtype))
+
+
+def _head_loss_bwd(k, blk, weight, res, g):
+    """Only scales: dlogits carried weight / (T-k), so the weighted
+    loss's cotangent multiplies dy and dW as it comes (the trainer's
+    1 / (batch x update_period), exactly 1 at one row a step), the
+    mean's after a division by weight. ids and mask take none."""
+    dy, dw = res
+    g_loss, g_mean = g
+    s = g_loss + g_mean / weight if weight else g_mean
+
+    def scaled(a):
+        return (a.astype(jnp.float32) * s).astype(a.dtype)
+
+    return scaled(dw), scaled(dy), None, None
+
+
+head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
 @register_layer
 class LMHeadLayer(_TableLayer):
     type_name = "lm_head"
     has_aux = True
+    #: never under a checkpoint (`remat = 1`, nnet/network.py): the loss's
+    #: forward rule already runs all three products of the gradient
+    #: (`head_loss`), and a checkpoint would run them twice
+    remat_worthy = False
     #: a second pass of one head (`share[lm_head]`, the multi-token
     #: prediction's) sets its own target and weight
     connection_keys = ("target_offset", "loss_weight")
@@ -814,44 +929,6 @@ class LMHeadLayer(_TableLayer):
     def table(self, in_shapes):
         return [("wmat", (in_shapes[0][3], self.nvocab), "normal")]
 
-    def _loss(self, w, y, ids, mask):
-        """Sum over rows of the mean over t < T-k of CE(logits_t,
-        id_{t+k}), k = `target_offset`. y (b, T, e), ids (b, T)."""
-        b, t, e = y.shape
-        k = self.offset
-        blk = min(self.loss_block, t)
-        pad = (-t) % blk
-        target = jnp.concatenate([ids[:, k:], ids[:, :k]], axis=1)
-        weight = jnp.broadcast_to(
-            (jnp.arange(t) < t - k).astype(jnp.float32), (b, t))
-        if mask is not None:
-            weight = weight * mask.astype(jnp.float32)[:, None]
-        if pad:
-            y = jnp.pad(y, ((0, 0), (0, pad), (0, 0)))
-            target = jnp.pad(target, ((0, 0), (0, pad)))
-            weight = jnp.pad(weight, ((0, 0), (0, pad)))
-        n = (t + pad) // blk
-
-        @jax.checkpoint
-        def part(total, xs):
-            yb, tb, wb = xs
-            with jax.named_scope("logits"):
-                logits = jnp.einsum(
-                    "bte,ev->btv", yb, w.astype(yb.dtype),
-                    preferred_element_type=jnp.float32)
-            with jax.named_scope("loss"):
-                logz = jax.nn.logsumexp(logits, axis=-1)
-                got = jnp.take_along_axis(logits, tb[..., None],
-                                          axis=-1)[..., 0]
-                return total + jnp.sum((logz - got) * wb), None
-
-        def blocks(a):
-            return jnp.moveaxis(a.reshape((b, n, blk) + a.shape[2:]), 1, 0)
-
-        total, _ = jax.lax.scan(part, jnp.zeros((), jnp.float32),
-                                (blocks(y), blocks(target), blocks(weight)))
-        return total / max(t - k, 1)
-
     def apply_with_stats(self, params, inputs, *, train, rng=None,
                          mask=None):
         b, _, t, e = inputs[0].shape
@@ -861,11 +938,12 @@ class LMHeadLayer(_TableLayer):
         # reads no metric from them never computes them
         with jax.named_scope("logits"):
             logits = _lin(y, params["wmat"]).reshape(b, 1, t, self.nvocab)
-        loss = (self._loss(params["wmat"], y, ids, mask) if train
-                else jnp.zeros((), jnp.float32))
-        stats = {"loss": loss} if self.stat_names else {}
-        if self.weight != 1.0:
-            loss = self.weight * loss
+        if train:
+            loss, mean = head_loss(params["wmat"], y, ids, mask, self.offset,
+                                   min(self.loss_block, t), self.weight)
+        else:
+            loss = mean = jnp.zeros((), jnp.float32)
+        stats = {"loss": mean} if self.stat_names else {}
         return [logits], loss, stats
 
     def apply_with_aux(self, params, inputs, *, train, rng=None, mask=None):
